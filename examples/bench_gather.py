@@ -26,10 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-from pcg_mpi_solver_tpu.utils.backend_probe import probe_or_exit  # noqa: E402
-
-probe_or_exit()
-
 
 def _sync(y):
     float(jnp.asarray(jax.tree.leaves(y)[0]).ravel()[0])

@@ -18,10 +18,6 @@ from pcg_mpi_solver_tpu.bench import cached_model
 from pcg_mpi_solver_tpu.parallel.hybrid import (
     HybridOps, device_data_hybrid, partition_hybrid)
 
-from pcg_mpi_solver_tpu.utils.backend_probe import probe_or_exit  # noqa: E402
-
-probe_or_exit()
-
 
 
 def _sync(y):

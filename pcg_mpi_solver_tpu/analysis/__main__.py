@@ -15,37 +15,26 @@ import sys
 
 def setup_cpu_env() -> None:
     """Pin the lint to the CPU backend BEFORE jax initializes: static
-    analysis must never touch (or wait on) an accelerator grant, and the
-    traced matrix needs a multi-device host platform.  No-ops when the
-    operator already configured the env (or jax is loaded — pytest's
-    conftest rig).  Also drops any inherited persistent-compile-cache
-    dir: jax 0.4.x CPU executables crash on cache round-trips
-    (cache/aot.py documents the same gate)."""
-    from pcg_mpi_solver_tpu.utils.backend_probe import (
-        backend_live, pin_cpu_backend_if_requested)
+    analysis must never take an accelerator, and the traced matrix
+    needs a multi-device host platform.  No-op once a backend is live
+    (pytest's conftest rig has configured it already)."""
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
 
-    if backend_live():
-        return   # too late to (and no need to) reconfigure: test rig
+        if xla_bridge._backends:
+            return
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("JAX_ENABLE_X64", "1")
-    if "cpu" in os.environ["JAX_PLATFORMS"]:
-        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    pin_cpu_backend_if_requested()
     if "jax" in sys.modules:
-        # jax may already be imported (the package pin does so under
-        # JAX_PLATFORMS=cpu); x64 and the compile-cache dir are config
-        # flags, not import-frozen — jax binds the env vars at import,
-        # so clearing the environment alone would not stick (the same
-        # authoritative-config move bench.py makes, in reverse)
+        # jax binds these env vars at import: apply them as config
         import jax
 
+        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
         jax.config.update("jax_enable_x64", True)
-        if "cpu" in os.environ["JAX_PLATFORMS"]:
-            jax.config.update("jax_compilation_cache_dir", None)
 
 
 def add_lint_args(ap) -> None:

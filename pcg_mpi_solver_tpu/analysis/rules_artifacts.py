@@ -25,8 +25,11 @@ from pcg_mpi_solver_tpu.analysis.engine import REPO, Finding, rule
 
 
 def default_paths() -> list:
-    """The committed artifacts the tier-1 check covers."""
-    return sorted(glob.glob(os.path.join(REPO, "BENCH_*.json")))
+    """The committed artifacts the tier-1 check covers: round artifacts
+    at the repo root, and the fixtures of each shape under tests/data."""
+    return sorted(glob.glob(os.path.join(REPO, "BENCH_*.json"))
+                  + glob.glob(os.path.join(REPO, "tests", "data",
+                                           "bench_rounds", "BENCH_*.json")))
 
 
 def check_file(path: str) -> list:

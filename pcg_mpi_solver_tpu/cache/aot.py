@@ -1,19 +1,15 @@
 """Ahead-of-time compile path: persistent XLA cache + jax.export'd steps.
 
-Two layers, both keyed/invalidated by ``cache/keys.py``:
-
-* ``enable_persistent_compilation_cache`` points jax's persistent
-  compilation cache at ``<cache_dir>/xla`` on accelerator backends (CPU
-  executables don't round-trip through it on jax 0.4.x — see the
-  function docstring) — a re-compile of an identical program becomes a
-  cache read.  This alone cuts the 400+ s flagship octree compiles
-  (docs/BENCH_LOG.md) to a load on re-runs.
+* ``use_compilation_cache`` places jax's persistent compilation cache:
+  ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it (jax reads
+  it itself; nothing is set in code), otherwise ``<repo>/.jax_cache``.
+  A fixed path, because the path is part of what a later run must find.
 * ``export_step``/``store_step``/``load_step`` serialize the jitted PCG
   step via ``jax.export`` keyed by its ABSTRACT signature (shapes /
-  dtypes / shardings): a warm session deserializes StableHLO instead of
-  re-tracing the solver's Python, so a same-shape-class re-run skips
-  tracing entirely and its (deserialized-module) compile hits the
-  persistent XLA cache.  Critical when a hardware window is 9 minutes.
+  dtypes / shardings) under ``RunConfig.cache_dir``: a warm session
+  deserializes StableHLO instead of re-tracing the solver's Python, so a
+  same-shape-class re-run skips tracing entirely and its
+  (deserialized-module) compile hits the persistent XLA cache.
 
 Import contract: jax is imported lazily inside functions — this module
 may be imported before the accelerator environment is configured.
@@ -25,27 +21,21 @@ import os
 import time
 from typing import Optional
 
+_REPO_DIR = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
-def enable_persistent_compilation_cache(cache_dir: str) -> str:
-    """Wire jax's persistent compilation cache to ``<cache_dir>/xla``.
-    Safe to call repeatedly; returns the XLA cache dir.
 
-    ACCELERATOR BACKENDS ONLY: on jax 0.4.x CPU, executables written to
-    the persistent cache do not deserialize reliably — a later
-    same-signature compile loads the entry and crashes the process
-    (segfault, flaky) at dispatch.  Empirically reproduced on the
-    8-device virtual CPU mesh; the cache module is also sticky (a later
-    ``jax_compilation_cache_dir`` config change does not re-point an
-    initialized cache), so one enable poisons every later solve in the
-    process.  CPU compiles are seconds, not the 400+ s flagship pain
-    this exists for — the partition + AOT layers alone already give CPU
-    the warm path."""
+def use_compilation_cache() -> str:
+    """Place jax's persistent compilation cache; returns its directory.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured by jax itself and
+    nothing is set here; otherwise the cache goes to ``<repo>/.jax_cache``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
     import jax
 
-    d = os.path.join(cache_dir, "xla")
-    os.makedirs(d, exist_ok=True)
-    if jax.default_backend() != "cpu":
-        jax.config.update("jax_compilation_cache_dir", d)
+    d = os.path.join(_REPO_DIR, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", d)
     return d
 
 

@@ -1,7 +1,7 @@
 """On-disk partition cache: content-addressed serialized partitions.
 
-A cache hit turns the 58.5 s flagship partition (BENCH_r05.json
-``partition_s``) into a multi-second zlib-pickle load.  Entries are
+A cache hit turns the ~minute-long flagship partition into a
+multi-second zlib-pickle load.  Entries are
 written atomically (unique tmp + ``os.replace``, the same publish
 discipline as bench.py's model cache) so concurrent solvers — e.g. a
 warmup queue racing the bench — can share one directory; corrupt or
@@ -11,7 +11,6 @@ Layout under a cache dir (shared with ``cache/aot.py``)::
 
     <cache_dir>/partition/<key>.zpkl    serialized partitions (this module)
     <cache_dir>/aot/<key>.jaxexport     AOT-exported step programs
-    <cache_dir>/xla/...                 persistent XLA compilation cache
 
 Import contract: jax-free at module load (utils/io.py only imports jax
 lazily inside ``is_primary``).
@@ -25,7 +24,7 @@ from typing import Any, Callable, Dict, Optional
 
 from pcg_mpi_solver_tpu.utils import io as uio
 
-SUBDIRS = ("partition", "aot", "xla")
+SUBDIRS = ("partition", "aot")
 
 
 def _entry_path(cache_dir: str, key: str) -> str:

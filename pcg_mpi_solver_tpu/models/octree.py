@@ -529,8 +529,7 @@ def reconstruct_lattice_meta(model: ModelData) -> bool:
     """Rebuild ``Octree.npz``-equivalent lattice metadata from the
     reference schema's OWN fields, so a genuine reference MDF bundle
     (which has no fast-path sidecars) routes to the hybrid level-grid
-    backend instead of the general gather/scatter path (VERDICT r03
-    weakness 3).
+    backend instead of the general gather/scatter path.
 
     Fully geometric — per-element bounding boxes from connectivity +
     node coords (schema-independent: does not trust ``Level``'s unit

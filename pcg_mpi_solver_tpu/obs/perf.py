@@ -106,9 +106,7 @@ class PhaseCost:
 
 @dataclasses.dataclass(frozen=True)
 class HwProfile:
-    """Roofline constants of the execution platform.  Deliberately
-    conservative EFFECTIVE rates (the matvec's d x d einsums and
-    gather/scatter never hit datasheet peaks), overridable per run via
+    """Roofline constants of one device kind, overridable per run via
     PCG_TPU_ROOFLINE_{FLOPS,HBM_GBS,ICI_GBS,COLL_LAT_US}."""
 
     name: str
@@ -118,23 +116,35 @@ class HwProfile:
     coll_latency_s: float
 
 
-#: baked-in profiles; "tpu" is calibrated loosely against the round-5
-#: flagship (13.741 ms/matvec at 10.33M dofs ~ 0.9 TB/s effective HBM on
-#: the matvec's ~12 GB of gather/scatter traffic), "cpu" against the
-#: 1-core container this repo's golden models run on.
+_V5E = HwProfile(
+    "TPU v5e",
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s (bf16), 16 GB
+    # of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect.
+    # The collective latency is not published: an assumed 8 us.
+    flops_per_s=1.97e14, hbm_bytes_per_s=8.19e11,
+    ici_bytes_per_s=2.0e11, coll_latency_s=8e-6)
+
+#: roofline profiles keyed by ``jax.Device.device_kind`` as the chip
+#: reports it (the v5e says "TPU v5 lite").  The CPU entry
+#: is rough effective rates of a small container, used by the CPU tests
+#: and by serve admission pricing on CPU runs.
 HW_PROFILES: Dict[str, HwProfile] = {
-    "tpu": HwProfile("tpu", flops_per_s=2.0e13, hbm_bytes_per_s=9.0e11,
-                     ici_bytes_per_s=9.0e10, coll_latency_s=8e-6),
     "cpu": HwProfile("cpu", flops_per_s=6.0e9, hbm_bytes_per_s=1.5e10,
                      ici_bytes_per_s=1.5e10, coll_latency_s=2e-6),
+    "TPU v5 lite": _V5E,
 }
 
 
-def resolve_profile(platform: str) -> HwProfile:
-    """The HwProfile for a platform string ("cpu", "tpu",
-    "TPU v4" ... — anything not starting with "cpu" is the accelerator),
-    with the PCG_TPU_ROOFLINE_* env overrides applied."""
-    key = "cpu" if str(platform).lower().startswith("cpu") else "tpu"
+def resolve_profile(device_kind: str) -> HwProfile:
+    """The HwProfile for a ``device_kind`` (any kind starting with "cpu"
+    is the CPU), with the PCG_TPU_ROOFLINE_* env overrides applied.  An
+    accelerator kind with no entry raises ValueError: no rate is
+    invented for a device nobody has looked up."""
+    kind = str(device_kind)
+    key = "cpu" if kind.lower().startswith("cpu") else kind
+    if key not in HW_PROFILES:
+        raise ValueError(f"no roofline profile for device kind {kind!r} "
+                         f"(known: {sorted(HW_PROFILES)})")
     p = HW_PROFILES[key]
 
     def env(name, default, scale=1.0):

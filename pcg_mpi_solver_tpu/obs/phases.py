@@ -71,9 +71,6 @@ class PhaseProbe:
         from pcg_mpi_solver_tpu.ops.precond import make_prec
         from pcg_mpi_solver_tpu.ops.matvec import (
             PCG_SCALAR_PSUMS, PCG_VECTOR_AXPYS)
-        from pcg_mpi_solver_tpu.utils.compat import ensure_shard_map
-
-        ensure_shard_map()
         s = self.solver
         ops = s.ops
         mesh = s.mesh

@@ -15,7 +15,7 @@ Two families:
 
 * **Bench result lines** (`BENCH_SCHEMA`): the one-line JSON contract of
   ``bench.py`` (`{"metric", "value", "unit", "vs_baseline", ...}`).  The
-  ``schema`` key is new; committed pre-schema artifacts (BENCH_r0*.json)
+  ``schema`` key is new; pre-schema artifacts
   stay valid as *legacy* lines — required keys are checked either way.
 
 This module must stay import-light (no jax, no numpy): bench.py imports it
@@ -202,7 +202,7 @@ EVENT_KINDS: Dict[str, tuple] = {
 BENCH_REQUIRED = ("metric", "value", "unit", "vs_baseline")
 
 # Optional ``detail`` fields with a typed contract WHEN present (absent in
-# pre-warm-path lines — committed BENCH_r0*.json stay valid).  Numeric-or-
+# pre-warm-path lines, which stay valid).  Numeric-or-
 # null: ``time_to_first_iter_s`` is null when no device dispatch happened
 # (e.g. a solve that failed before its first jitted call).  ``nrhs`` /
 # ``dof_iter_rhs_per_s`` are the batched multi-RHS A/B fields
@@ -360,8 +360,8 @@ def _find_bench_payload(doc: Any) -> Any:
 def validate_bench_text(text: str) -> List[str]:
     """Validate a BENCH_*.json artifact (raw line or round wrapper).
 
-    A round wrapper whose bench run failed (``rc`` != 0, ``parsed`` null —
-    BENCH_r01..r03 are committed examples) is a legitimate artifact: the
+    A round wrapper whose bench run failed (``rc`` != 0, ``parsed`` null;
+    tests/data/bench_rounds/ holds an example) is a legitimate artifact: the
     driver captured a crash, not a malformed metric.  Only a wrapper that
     CLAIMS success (rc == 0) must carry a valid payload."""
     try:

@@ -108,8 +108,8 @@ def iter_bench_lines(path: str) -> List[dict]:
 def leg_key(line: dict) -> Tuple:
     """The cross-round matching identity of one bench line: shape +
     configuration.  Pre-schema lines (no variant/precond/nrhs fields)
-    match under the historical defaults — BENCH_r01..r05 predate those
-    knobs and all measured classic/jacobi/nrhs=1."""
+    match under the historical defaults — lines from before those knobs
+    all measured classic/jacobi/nrhs=1."""
     d = line.get("detail") or {}
     return (
         str(line.get("metric", "?")),

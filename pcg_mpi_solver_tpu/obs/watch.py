@@ -10,7 +10,7 @@ it, and when will it finish.
   single silent shard is a per-shard warning; a **stall** is flagged
   only when ALL shards have gone silent past the threshold — on a
   multi-controller run one slow host is skew (obs/fleet.py's job), but
-  everyone silent means the run is wedged (dead tunnel, hung
+  everyone silent means the run is wedged (lost device, hung
   collective, SIGSTOP'd process).
 * **progress** — per-dispatch counters and the completed-step residual
   table from ``step`` / ``dispatch`` / ``resid_trace`` events, plus the

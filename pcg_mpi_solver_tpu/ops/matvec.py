@@ -34,12 +34,6 @@ import numpy as np
 
 from pcg_mpi_solver_tpu.config import PCG_VARIANTS
 from pcg_mpi_solver_tpu.parallel.partition import PartitionedModel
-from pcg_mpi_solver_tpu.utils.compat import ensure_shard_map
-
-# jax < 0.5 compat: every jax-importing root module of the package
-# installs the jax.shard_map alias (the package __init__ must stay
-# jax-free for bench.py's env-ordering contract).
-ensure_shard_map()
 
 # ---------------------------------------------------------------------------
 # Declared per-iteration collective contract of the PCG loop formulations
@@ -780,8 +774,8 @@ class Ops:
 # pattern type; at the reference's deep-graded octrees that is 200+ types
 # (/root/reference/src/solver/partition_mesh.py:1074 allows <=144 per rank,
 # multi-part models exceed it globally), and measured chipless compile cost
-# tracks the emitted structure COUNT, not FLOPs (docs/BENCH_LOG.md
-# 2026-08-01: 227 type blocks -> 1343 s f64).  Here types are STACKED into
+# tracks the emitted structure COUNT, not FLOPs (227 type blocks -> 1343 s
+# f64).  Here types are STACKED into
 # a few buckets by element-count SIZE CLASS only (power-of-16 boundaries;
 # ~5 buckets at the flagship), with element arity (d, nn) zero-padded to
 # each bucket's max: one batched einsum per bucket.  Element-count slots

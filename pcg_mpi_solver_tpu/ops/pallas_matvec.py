@@ -37,6 +37,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from pcg_mpi_solver_tpu.models.element import HEX_CORNERS
 
+#: precision of every f32 MXU contraction in these kernels.  Mosaic's
+#: default is a single bf16 pass: on the v5e it put v6's product 2.5e-3
+#: (relative) off the XLA form at every shape and stalled the flagship's
+#: mixed solve at relres 1.0 (tools/pallas_parity.py, PR 21).
+F32_DOT = jax.lax.Precision.HIGHEST
+
 _CORNERS = HEX_CORNERS.astype(np.int64)  # (8, 3) offsets in {0,1}^3
 
 
@@ -162,14 +168,13 @@ def selected_variant():
     at trace time: toggling the knob after a solver compiled does not
     retrace (build a new Solver to switch).
 
-    PROVISIONAL DEFAULT: v6 passes the build-host chipless compile at
-    the 150^3 flagship but the DEPLOYED terminal Mosaic rejects its u
-    stack (concat of lane-offset-mismatched rows, HW_SESSION.log
-    2026-08-01) — under pallas='auto' the probe burns one failed remote
-    compile (~70 s) and degrades to the XLA path.  v9 removes the
-    rejected construct class entirely and is the engage candidate; the
-    default flips only after a hardware-measured v9 win (a mid-queue
-    flip would confound the wave A/B arms)."""
+    Default v6.  On the v5e its dots ran at Mosaic's default single
+    bf16 pass (2.5e-3 off the XLA form, which stalled the flagship's
+    mixed solve); with :data:`F32_DOT` its product matches XLA on the
+    chip at small shapes, but its Mosaic compile at a 150x150 cell
+    plane grew from ~50 s to over 14 min (chipless), so no device kind
+    has it verified at the flagship (solver/driver.PALLAS_VERIFIED).
+    v9 has never run on the chip."""
     import os
 
     v = os.environ.get("PCG_TPU_PALLAS_V", "6")
@@ -196,18 +201,20 @@ def selected_variant():
 
 
 def probe_shapes(shapes, dtype=jnp.float32) -> None:
-    """AOT-compile the kernel for each (node-grid, cell-grid) shape pair;
-    raises if any fails.  Used by the driver's pallas='auto' resolution so
-    a shape-dependent Mosaic lowering failure degrades to the XLA path at
-    init instead of crashing the first jitted step.  Probes the SAME
-    variant batched_structured_matvec dispatches to."""
+    """Lower the kernel for each (node-grid, cell-grid) shape pair; raises
+    if any fails.  Used by the driver's pallas='auto' resolution so a
+    shape-dependent Pallas lowering failure raises at init, in well under
+    a second.  Mosaic's own compile is not repeated here (~70 s at a
+    150x150 cell plane): it runs once, inside the solve program, and a
+    failure there raises too.  Probes the SAME variant
+    batched_structured_matvec dispatches to."""
     fn = selected_variant()[1]
     fn = fn if hasattr(fn, "lower") else jax.jit(fn)
     for xg_shape, ck_shape in shapes:
         fn.lower(
             jax.ShapeDtypeStruct(xg_shape, dtype),
             jax.ShapeDtypeStruct(ck_shape, dtype),
-            jax.ShapeDtypeStruct((24, 24), dtype)).compile()
+            jax.ShapeDtypeStruct((24, 24), dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -298,6 +305,7 @@ def _matvec_kernel_v2(ke_ref, x_hbm, ck_hbm, y_ref,
         u = jnp.stack(rows)                             # (24, m)
         v = jax.lax.dot_general(
             ke_ref[...], u, (((1,), (0,)), ((), ())),
+            precision=F32_DOT,
             preferred_element_type=jnp.float32)         # (24, m) on the MXU
         # corner placement as zero-padded adds (pads with static widths
         # are pure concatenates — Mosaic has no scatter-add lowering)
@@ -434,7 +442,7 @@ def _matvec_kernel_v3(ke_ref, x_hbm, ck_hbm, y_ref,
         t = ck * xb[:, off:off + cm]                    # (3, cm)
         pa = jax.lax.dot_general(
             ke_ref[:, 3 * a:3 * a + 3], t, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=F32_DOT, preferred_element_type=jnp.float32)
         v = pa if v is None else v + pa                 # (24, cm)
     # scatter: out[q + off_e] += v_e[q] as 8 zero-padded adds (Mosaic has
     # no scatter-add lowering; pads with static widths are pure
@@ -593,6 +601,7 @@ def _matvec_kernel_v4(ke_ref, x_hbm, ck_hbm, y_ref,
         u = jnp.stack(rows)                             # (24, m)
         v = jax.lax.dot_general(
             ke_ref[...], u, (((1,), (0,)), ((), ())),
+            precision=F32_DOT,
             preferred_element_type=jnp.float32)         # (24, m) on the MXU
         # corner placement as zero-padded lane adds (Mosaic has no
         # scatter-add lowering); dx routes to this output plane (lo) or
@@ -711,6 +720,7 @@ def _matvec_kernel_v5(ke_ref, x_hbm, ck_hbm, y_ref,
             off = dy * sy + dz
             blk = jax.lax.dot_general(
                 ke[3 * b:3 * b + 3], u, (((1,), (0,)), ((), ())),
+                precision=F32_DOT,
                 preferred_element_type=jnp.float32)     # (3, m128), {0,0}
             vp = jnp.pad(blk, ((0, 0), (0, mt128 - m128)))  # aligned concat
             if off:
@@ -920,6 +930,7 @@ def _matvec_kernel_v6(ke_ref, x_hbm, ck_hbm, y_ref,
             off = dy * sy + dz
             blk = jax.lax.dot_general(
                 ke[3 * b:3 * b + 3], u, (((1,), (0,)), ((), ())),
+                precision=F32_DOT,
                 preferred_element_type=jnp.float32)     # (3, m128), {0,0}
             blk = ck * blk                              # hoisted ck scale
             vp = jnp.pad(blk, ((0, 0), (0, mt128 - m128)))  # aligned concat
@@ -1076,6 +1087,7 @@ def _matvec_kernel_v7(ke_ref, x_hbm, ck_hbm, y_ref,
             off = int(dy * sy + dz)
             blk = jax.lax.dot_general(
                 ke[3 * b:3 * b + 3], u, (((1,), (0,)), ((), ())),
+                precision=F32_DOT,
                 preferred_element_type=jnp.float32)     # (3, mt128), {0,0}
             if off:
                 blk = pltpu.roll(blk, off, 1)           # lane placement
@@ -1208,6 +1220,7 @@ def _matvec_kernel_v8(ke_ref, x_hbm, ck_hbm, y_ref,
         off = dy * sy + dz
         blk = jax.lax.dot_general(
             ke[3 * b:3 * b + 3], u, (((1,), (0,)), ((), ())),
+            precision=F32_DOT,
             preferred_element_type=jnp.float32)         # (3, m128), {0,0}
         blk = ck * blk                                  # hoisted ck scale
         vp = jnp.pad(blk, ((0, 0), (0, mt128 - m128)))  # aligned concat
@@ -1383,6 +1396,7 @@ def _matvec_kernel_v9(ket_ref, sel_ref, x_hbm, ck_hbm, y_ref,
             ket = ket_ref[a]                            # (4, 24) {0,0}
             d = jax.lax.dot_general(
                 ket, xr, (((0,), (0,)), ((), ())),
+                precision=F32_DOT,
                 preferred_element_type=jnp.float32)     # (24, mt128) {0,0}
             v = d if v is None else v + d
         w = ck * v                                      # hoisted ck scale
@@ -1393,6 +1407,7 @@ def _matvec_kernel_v9(ket_ref, sel_ref, x_hbm, ck_hbm, y_ref,
             sel = sel_ref[b]                            # (4, 24) one-hot
             blk = jax.lax.dot_general(
                 sel, w, (((1,), (0,)), ((), ())),
+                precision=F32_DOT,
                 preferred_element_type=jnp.float32)     # (4, mt128) {0,0}
             if off:
                 blk = pltpu.roll(blk, off, 1)           # lane placement

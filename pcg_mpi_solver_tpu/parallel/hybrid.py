@@ -83,9 +83,9 @@ class LevelGrid:
 class CombineMaps:
     """Slot->node gather-combine maps (the scatter-free level combine).
 
-    The 2026-07-30 hardware session measured the duplicate-row scatter at
-    88.7 ns/row against 5.9 ns/row gathers (docs/BENCH_LOG.md "hybrid row
-    traffic") — so the combine is transposed: all levels' lattice slots
+    A 2026-07-30 chip session measured the duplicate-row scatter at
+    88.7 ns/row against 5.9 ns/row gathers — so the combine is
+    transposed: all levels' lattice slots
     are sorted by target node at PARTITION time and composed into direct
     per-node source-slot indices.  At solve time the element->node
     accumulation (reference pcg_solver.py:300's bincount) becomes KD
@@ -152,7 +152,8 @@ def hybrid_pallas_enabled(hp: "HybridPartition", pallas_mode: str,
     the one shared probe call for every hybrid consumer (quasi-static
     driver, dynamics).  Only levels whose part*block batch fits the
     per-launch cap are probed (the others always run the XLA stencil);
-    if no level qualifies the kernel is declined outright."""
+    if no level qualifies, "auto" declines the kernel and an explicit
+    request raises."""
     from pcg_mpi_solver_tpu.solver.driver import _pallas_enabled
 
     lp = local_parts(hp.pm.n_parts, mesh)
@@ -161,12 +162,10 @@ def hybrid_pallas_enabled(hp: "HybridPartition", pallas_mode: str,
         for lv in hp.levels if lp * lv.nb <= PALLAS_BATCH_CAP)))
     if not shapes:
         if pallas_mode in ("on", "interpret"):
-            import warnings
-
-            warnings.warn(
-                f"pallas={pallas_mode!r} but every hybrid level's "
-                f"part*block batch exceeds the {PALLAS_BATCH_CAP}-launch "
-                "cap; using the XLA stencils")
+            raise ValueError(
+                f"pallas={pallas_mode!r} cannot engage: every hybrid "
+                f"level's part*block batch exceeds the "
+                f"{PALLAS_BATCH_CAP}-launch cap")
         return False
     return _pallas_enabled(pallas_mode, mesh, shapes=shapes)
 
@@ -551,8 +550,8 @@ class HybridOps(Ops):
     def _combined_gather_add(self, y, rows_levels, data, Pn):
         """Scatter-free combine: add every level's lattice-slot rows into
         y (P, n_loc) through the partition-composed slot->node maps
-        (CombineMaps; measured rationale in docs/BENCH_LOG.md "hybrid row
-        traffic").  ``rows_levels``: per-level (P, n_slots_l, w) arrays in
+        (CombineMaps; rationale in the CombineMaps docstring).
+        ``rows_levels``: per-level (P, n_slots_l, w) arrays in
         level order; w is the row width (3 for matvec/diag)."""
         cm = data["combine"]
         w = rows_levels[0].shape[-1]

@@ -131,7 +131,7 @@ class SimulatedKill(BaseException):
 
 class InjectedDispatchError(RuntimeError):
     """Synthetic device-loss exception (stands in for XlaRuntimeError/
-    UNAVAILABLE from a dropped tunnel or preempted device)."""
+    UNAVAILABLE from a lost or preempted device)."""
 
 
 def _parse(spec: str):

@@ -3,7 +3,7 @@ resilience context threaded through the chunked budget loop.
 
 Division of labor (one failure taxonomy, three handlers):
 
-* **Device loss** (XLA runtime errors, dropped tunnels, injected
+* **Device loss** (XLA runtime errors, injected
   ``exc`` faults): the :class:`DispatchGuard` retries the dispatch with
   backoff, re-dispatching from the last mid-Krylov snapshot — losing at
   most one snapshot interval of iterations instead of the whole step.
@@ -108,7 +108,7 @@ class DispatchGuard:
     """Retry-with-backoff + deadline budget for device dispatches.
 
     One instance per solve step: the retry budget is a per-step total
-    (a flapping tunnel must not retry forever), the deadline an absolute
+    (a flapping device must not retry forever), the deadline an absolute
     wall clamp.  Backoff is exponential from
     ``PCG_TPU_RETRY_BACKOFF_S`` (default 0.5 s; tests set it near 0).
     """

@@ -1,7 +1,7 @@
 """Dispatch-chunked PCG execution, shared by solvers.
 
 A single device dispatch that runs for minutes can trip execution
-watchdogs on remote/tunneled TPUs (docs/RUNBOOK.md); above ~4M dofs the
+watchdogs, and a lost device loses all of it; above ~4M dofs the
 solvers split a solve into host-driven dispatches of at most ``cap``
 Krylov iterations, with all state resident on device between calls.  The
 Krylov recurrence is resumable (solver/pcg.py ``carry_in``), so N capped
@@ -57,8 +57,8 @@ class ChunkedEngine:
         ``(data, v) -> eff * K.v`` backed by ONE separately-jitted
         program the caller shares across all its out-of-loop f64 matvec
         uses (Dirichlet lifting, r0, refine) — at octree-flagship scale
-        every stencil INSTANTIATION costs minutes of compile
-        (docs/BENCH_LOG.md 2026-07-31), so the refine step is then
+        every stencil INSTANTIATION costs minutes of compile, so the
+        refine step is then
         composed from two tiny elementwise programs around it instead of
         instantiating the stencil a second time in its own program.
 

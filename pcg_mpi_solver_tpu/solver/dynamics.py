@@ -83,7 +83,7 @@ class DynamicsSolver:
         self._rec = self.recorder
         # Flight recorder (obs/flight.py): the same crash-durable
         # dispatch brackets the quasi-static Solver gets — a long
-        # explicit time history is exactly the run a tunnel death
+        # explicit time history is exactly the run a device loss
         # orphans mid-chunk.
         from pcg_mpi_solver_tpu.obs.flight import attach_flight
 

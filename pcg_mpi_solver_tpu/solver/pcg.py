@@ -369,8 +369,8 @@ def pcg(
     near its f32 floor), the remaining grind is worth less than one f64
     restart.  The min-gain gate is what the plateau knob lacked: CG's
     residual is non-monotone and plateaus pre-asymptotically, so a bare
-    no-improvement window false-triggers at small scale
-    (docs/BENCH_LOG.md 2026-07-31: window 30 DIVERGED at iter 31/255);
+    no-improvement window false-triggers at small scale (window 30
+    DIVERGED at iter 31/255);
     requiring 30x achieved contraction first makes early plateaus
     unreachable.  Keep OFF (0) for direct/f64 solves — the reference's
     iteration-parity contract has no such exit.
@@ -379,8 +379,8 @@ def pcg(
     initial-residual matvec: r0 = fext - A.0 = fext exactly, and
     ||r0|| = ||fext|| = n2b (the same reduction).  One fewer stencil
     instantiation in the compiled program — the hybrid octree stencil
-    costs minutes of compile time PER INSTANTIATION (docs/BENCH_LOG.md
-    2026-07-31) — and one fewer matvec execution at runtime.
+    costs minutes of compile time PER INSTANTIATION — and one fewer
+    matvec execution at runtime.
 
     ``plateau_window`` > 0 adds a plateau exit beyond MATLAB pcg's
     stagnation test: if no meaningfully (0.1%) better minimal residual
@@ -654,7 +654,7 @@ def pcg(
         exactly).  The matvec operand is selected BEFORE the single
         ``amul`` below — the stencil is instantiated ONCE in the whole
         loop body, which at octree-flagship scale is minutes of compile
-        time per instantiation (docs/BENCH_LOG.md 2026-07-31)."""
+        time per instantiation."""
         i = c["i"]
         is_check = c["mode"] == 1
 
@@ -1266,8 +1266,8 @@ def pcg_mixed(
     # CURRENT x) instead of pre-loop + bottom: the numerical sequence
     # r0, inner, r1, inner, ..., rN is identical, but the f64 stencil is
     # instantiated ONCE in the whole program instead of twice — at octree
-    # flagship scale each instantiation is minutes of compile time
-    # (docs/BENCH_LOG.md 2026-07-31).  Internal flag -1 = still running
+    # flagship scale each instantiation is minutes of compile time.
+    # Internal flag -1 = still running
     # (the final residual evaluation happens in-body, so the loop cond
     # only tests the flag).
     carry0 = dict(

@@ -654,9 +654,6 @@ def test_consensus_coverage_seeded_violations():
 
     rel = "pcg_mpi_solver_tpu/solver/driver.py"
     src = (
-        "def _pallas_enabled():\n"
-        "    # consensus-exempt: test stub\n"
-        "    return process_allgather(x)\n"
         "class Solver:\n"
         "    def __init__(self):\n"
         "        ok = agree_flag(comm, ok)\n"

@@ -93,7 +93,7 @@ def test_fingerprint_mismatch_raises(tmp_path, model):
 def test_model_content_mismatch_raises(tmp_path, model):
     """Resuming against a model of identical shapes but different content
     (here: a perturbed stiffness field) must be rejected — shape-only
-    fingerprints would silently produce garbage (VERDICT round 1)."""
+    fingerprints would silently produce garbage."""
     import dataclasses
 
     cfg = _cfg(tmp_path, run_id="cm", every=1)

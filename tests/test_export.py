@@ -79,7 +79,7 @@ def test_solve_with_export_roundtrip(tmp_path):
 def test_boundary_mode_differs_from_full_on_octree(tmp_path):
     """Real Boundary mode (face-incidence counting, export_vtk.py:105-113):
     the octree model stores EVERY element face, so Full includes interior
-    faces and Boundary must be a strict subset (VERDICT round 1, missing #2)."""
+    faces and Boundary must be a strict subset."""
     from pcg_mpi_solver_tpu.models.octree import make_octree_model
     from pcg_mpi_solver_tpu.vtk.export import _select_faces
 
@@ -204,16 +204,20 @@ def test_frame_shard_gap_detected(tmp_path):
         store.read_frame("U", 1)
 
 
-def test_backend_probe_skips():
+def test_backend_probe_skips(monkeypatch):
     """The probe must not spawn subprocesses when it cannot add info."""
     import os
 
-    from pcg_mpi_solver_tpu.utils.backend_probe import (backend_live,
-                                                        probe_backend)
+    import jax
+
+    from pcg_mpi_solver_tpu.utils.backend_probe import probe_backend
 
     # conftest pins JAX_PLATFORMS=cpu for the test session
     assert os.environ.get("JAX_PLATFORMS", "").lower() == "cpu"
     ok, detail = probe_backend(timeout_s=1.0)
     assert ok and "skipped" in detail
-    # jax is live in the test process by now
-    assert backend_live()
+    # with a live backend in this process the probe is skipped too
+    jax.devices()
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    ok, detail = probe_backend(timeout_s=1.0)
+    assert ok and "already live" in detail

@@ -558,7 +558,7 @@ def test_summary_salvages_truncated_final_heartbeat(tmp_path, capsys):
     f = FlightRecorder(str(p), heartbeat_s=3600)
     f.begin("dispatch:cycle")
     f.close()
-    # append the dead-tunnel signature: a heartbeat cut mid-write with a
+    # append the killed-process signature: a heartbeat cut mid-write with a
     # NEWER timestamp than any complete record
     with open(p, "a", encoding="utf-8") as fh:
         fh.write('{"schema": "%s", "t": 9e9, "kind": "flight", '

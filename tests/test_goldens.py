@@ -1,7 +1,7 @@
-"""Pinned golden numbers for fixed flagship models (VERDICT round 1,
-missing #5): the reference's committed notebook outputs play this role
-(solver_demo.ipynb cell 12); here the goldens are asserted in CI so any
-numerics regression fails a test.  A deliberate algorithm change that moves
+"""Pinned golden numbers for fixed flagship models: the reference's
+committed notebook outputs play this role (solver_demo.ipynb cell 12);
+here the goldens are asserted in CI so any numerics regression fails a
+test.  A deliberate algorithm change that moves
 one of these must re-pin it with justification in the commit message.
 
 The octree golden lives in tests/test_octree.py (same pattern)."""

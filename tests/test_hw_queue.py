@@ -329,7 +329,7 @@ def test_stale_flight_artifact_rotated_not_inherited(tmp_path,
     log = tmp_path / "log.txt"
     fpath = str(log) + ".flight.jsonl"
     dead = FlightRecorder(fpath, heartbeat_s=30)
-    dead.begin("step:killed by tunnel death")     # never closed
+    dead.begin("step:killed by device loss")     # never closed
     dead.close()
     assert flight_verdict_path(fpath)["verdict"] == "died"
 
@@ -341,12 +341,12 @@ def test_stale_flight_artifact_rotated_not_inherited(tmp_path,
     # the dead artifact moved aside intact; the fresh stream is clean
     prev = flight_verdict_path(fpath + ".prev")
     assert prev["verdict"] == "died"
-    assert prev["in_flight"] == ["step:killed by tunnel death"]
+    assert prev["in_flight"] == ["step:killed by device loss"]
     v = flight_verdict_path(fpath)
     assert v["verdict"] == "clean", v
     text = log.read_text()
     assert "verdict=died" in text
-    assert "in flight at death: step:killed by tunnel death" in text
+    assert "in flight at death: step:killed by device loss" in text
 
 
 def test_run_step_survives_flight_recorder_trouble(tmp_path, monkeypatch):

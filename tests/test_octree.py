@@ -111,7 +111,7 @@ def _solver(model, n_parts, n_dev=None, tol=1e-8, **kw):
 def test_matvec_matches_dense_mixed_d_blocks(model):
     """Device matvec on the general path vs scipy assembly — on a model
     whose type blocks have DIFFERENT d (24..51 dofs/elem), proving the
-    per-block generality of ops/matvec.py (VERDICT round 1, weak #8)."""
+    per-block generality of ops/matvec.py."""
     import jax.numpy as jnp
 
     K = model.assemble_csr()
@@ -175,7 +175,7 @@ GOLDEN_OCTREE_CHECKSUM = 243.89247971925158
 
 def test_golden_iteration_count(model):
     """Pinned golden for the flagship octree model: numerics drift between
-    rounds must fail loudly (VERDICT round 1, missing #5).  If a deliberate
+    rounds must fail loudly.  If a deliberate
     algorithm change moves this, re-pin with justification."""
     s = _solver(model, 4)
     res = s.step(1.0)

@@ -189,3 +189,182 @@ def test_solver_pallas_interpret_hybrid_matches_xla():
     assert abs(it_x - it_p) <= 2, (it_x, it_p)
     np.testing.assert_allclose(u_p, u_x, rtol=1e-3,
                                atol=1e-5 * float(np.abs(u_x).max()))
+
+
+# ----------------------------------------------------------------------
+# pallas="auto"/"on" resolution: no fallback that hides the device
+# ----------------------------------------------------------------------
+
+def _fake_mesh(platform, kind):
+    from types import SimpleNamespace
+
+    dev = SimpleNamespace(platform=platform, device_kind=kind)
+    return SimpleNamespace(devices=np.array([dev], dtype=object))
+
+
+V5E = ("v6", "TPU v5 lite")
+
+
+def test_pallas_auto_on_tpu_reraises_compile_failure(monkeypatch):
+    """A kernel failure under "auto" on a verified device kind RAISES:
+    no warning, no switch to the XLA matvec."""
+    from pcg_mpi_solver_tpu.ops import pallas_matvec
+    from pcg_mpi_solver_tpu.solver import driver
+
+    def refuse(shapes, dtype=None):
+        raise RuntimeError("Mosaic: unsupported shape")
+
+    monkeypatch.setattr(pallas_matvec, "probe_shapes", refuse)
+    monkeypatch.setattr(driver, "_PALLAS_PROBED", set())
+    monkeypatch.setattr(driver, "PALLAS_VERIFIED", frozenset({V5E}))
+    shapes = (((3, 9, 8, 7), (8, 7, 6)),)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        driver._pallas_enabled("auto", _fake_mesh("tpu", "TPU v5 lite"),
+                               shapes=shapes)
+
+
+def test_pallas_auto_decides_from_device_before_compiling(monkeypatch):
+    """"auto" engages from platform + (variant, device_kind), probes the
+    kernel once per shape set, and never probes off a verified pair."""
+    from pcg_mpi_solver_tpu.ops import pallas_matvec
+    from pcg_mpi_solver_tpu.solver import driver
+
+    probed = []
+    monkeypatch.setattr(pallas_matvec, "probe_shapes",
+                        lambda shapes, dtype=None: probed.append(shapes))
+    monkeypatch.setattr(driver, "_PALLAS_PROBED", set())
+    monkeypatch.setattr(driver, "PALLAS_VERIFIED", frozenset())
+    shapes = (((3, 9, 8, 7), (8, 7, 6)),)
+    for plat, kind in (("cpu", "cpu"), ("tpu", "TPU v4"),
+                       ("tpu", "TPU v5 lite")):
+        assert not driver._pallas_enabled("auto", _fake_mesh(plat, kind),
+                                          shapes=shapes)
+    assert probed == []
+    monkeypatch.setattr(driver, "PALLAS_VERIFIED", frozenset({V5E}))
+    v5e = _fake_mesh("tpu", "TPU v5 lite")
+    assert driver._pallas_enabled("auto", v5e, shapes=shapes)
+    assert driver._pallas_enabled("auto", v5e, shapes=shapes)
+    assert probed == [list(shapes)]
+    # another variant is not vouched for by v6's verification
+    monkeypatch.setenv("PCG_TPU_PALLAS_V", "9")
+    assert not driver._pallas_enabled("auto", v5e, shapes=shapes)
+
+
+def test_pallas_on_raises_on_unverified_tpu_kind(monkeypatch):
+    """An explicit "on" runs no unverified kernel on a TPU: it raises
+    before anything is lowered."""
+    from pcg_mpi_solver_tpu.ops import pallas_matvec
+    from pcg_mpi_solver_tpu.solver import driver
+
+    probed = []
+    monkeypatch.setattr(pallas_matvec, "probe_shapes",
+                        lambda shapes, dtype=None: probed.append(shapes))
+    monkeypatch.setattr(driver, "_PALLAS_PROBED", set())
+    monkeypatch.setattr(driver, "PALLAS_VERIFIED", frozenset({V5E}))
+    shapes = (((3, 9, 8, 7), (8, 7, 6)),)
+    with pytest.raises(ValueError, match="not verified on 'TPU v4'"):
+        driver._pallas_enabled("on", _fake_mesh("tpu", "TPU v4"),
+                               shapes=shapes)
+    assert probed == []
+    assert driver._pallas_enabled("on", _fake_mesh("tpu", "TPU v5 lite"),
+                                  shapes=shapes)
+    assert probed == [list(shapes)]
+
+
+def test_hybrid_pallas_on_raises_on_unverified_tpu_kind(monkeypatch):
+    """The hybrid resolution goes through the same gate."""
+    from types import SimpleNamespace
+
+    from pcg_mpi_solver_tpu.parallel.hybrid import hybrid_pallas_enabled
+    from pcg_mpi_solver_tpu.solver import driver
+
+    monkeypatch.setattr(driver, "PALLAS_VERIFIED", frozenset())
+    lv = SimpleNamespace(nb=2, bx=8, by=8, bz=8)
+    hp = SimpleNamespace(pm=SimpleNamespace(n_parts=1), levels=[lv])
+    mesh = _fake_mesh("tpu", "TPU v5 lite")
+    with pytest.raises(ValueError, match="not verified"):
+        hybrid_pallas_enabled(hp, "on", mesh)
+    assert hybrid_pallas_enabled(hp, "auto", mesh) is False
+
+
+def test_pallas_probe_failure_raises_on_every_process(monkeypatch):
+    """Multi-process: the verdict is agreed, so a host whose probe
+    passed raises too when another host's lowering failed."""
+    import jax
+    from jax.experimental import multihost_utils
+
+    from pcg_mpi_solver_tpu.ops import pallas_matvec
+    from pcg_mpi_solver_tpu.solver import driver
+
+    monkeypatch.setattr(pallas_matvec, "probe_shapes",
+                        lambda shapes, dtype=None: None)
+    monkeypatch.setattr(driver, "_PALLAS_PROBED", set())
+    monkeypatch.setattr(driver, "PALLAS_VERIFIED", frozenset({V5E}))
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    monkeypatch.setattr(multihost_utils, "process_allgather",
+                        lambda x: np.asarray([[True], [False]]))
+    with pytest.raises(RuntimeError, match="another process"):
+        driver._pallas_enabled("auto", _fake_mesh("tpu", "TPU v5 lite"))
+
+
+@pytest.mark.parametrize("mode", ["on", "interpret"])
+def test_hybrid_explicit_pallas_that_cannot_engage_raises(mode):
+    """An explicit kernel request on a partition whose every level is
+    over the per-launch cap raises instead of warning."""
+    from types import SimpleNamespace
+
+    from pcg_mpi_solver_tpu.parallel.hybrid import (
+        PALLAS_BATCH_CAP, hybrid_pallas_enabled)
+    from pcg_mpi_solver_tpu.parallel.mesh import make_mesh
+
+    lv = SimpleNamespace(nb=PALLAS_BATCH_CAP + 1, bx=8, by=8, bz=8)
+    hp = SimpleNamespace(pm=SimpleNamespace(n_parts=1), levels=[lv])
+    with pytest.raises(ValueError, match="cannot engage"):
+        hybrid_pallas_enabled(hp, mode, make_mesh(1))
+    assert hybrid_pallas_enabled(hp, "auto", make_mesh(1)) is False
+
+
+def _kernel_dot_precisions(jaxpr):
+    """Precision of every dot_general inside the pallas_call kernels of
+    ``jaxpr`` (walks sub-jaxprs)."""
+    import jax
+
+    out = []
+
+    def walk(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "dot_general":
+                out.append(eqn.params["precision"])
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jax.extend.core.Jaxpr):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return out
+
+
+@pytest.mark.parametrize("variant", ["2", "3", "5", "6", "7", "8", "9"])
+def test_pallas_kernel_dots_request_f32_precision(variant, monkeypatch):
+    """Every MXU contraction in the kernels asks for full f32
+    precision: Mosaic's default (one bf16 pass) put v6 2.5e-3 off the
+    XLA matvec on the v5e and stalled the flagship solve — a fault the
+    CPU interpreter cannot show, so the request itself is held here."""
+    import jax
+    import jax.numpy as jnp
+
+    from pcg_mpi_solver_tpu.ops import pallas_matvec as pm
+
+    monkeypatch.setenv("PCG_TPU_PALLAS_V", variant)
+    fn = pm.selected_variant()[1]
+    jaxpr = jax.make_jaxpr(lambda x, c, k: fn(x, c, k))(
+        jax.ShapeDtypeStruct((3, 9, 8, 7), jnp.float32),
+        jax.ShapeDtypeStruct((8, 7, 6), jnp.float32),
+        jax.ShapeDtypeStruct((24, 24), jnp.float32))
+    precs = _kernel_dot_precisions(jaxpr)
+    assert precs, "no MXU contraction found in the kernel"
+    hi = jax.lax.Precision.HIGHEST
+    assert all(p is not None and all(q == hi for q in p) for p in precs), \
+        precs

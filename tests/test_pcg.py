@@ -181,8 +181,8 @@ def test_progress_exit_mechanism():
 
 
 def test_mixed_progress_default_no_small_scale_regression():
-    """mixed_progress_window (opt-in since the negative 96^3 A/B,
-    docs/BENCH_LOG.md 2026-08-01): a small mixed solve must converge
+    """mixed_progress_window (opt-in since the negative 96^3 A/B): a
+    small mixed solve must converge
     identically (flag 0, same tol) with it on or off — the min-gain gate
     keeps pre-asymptotic windows unreachable."""
     model = make_cube_model(5, 4, 4, h=0.5, nu=0.3, load="traction",

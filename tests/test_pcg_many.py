@@ -462,11 +462,7 @@ def test_many_snapshot_fingerprint_tracks_fallback_wiring(model,
 
 @pytest.fixture
 def cache_dir(tmp_path):
-    import jax
-
-    before = jax.config.jax_compilation_cache_dir
-    yield str(tmp_path / "warm")
-    jax.config.update("jax_compilation_cache_dir", before)
+    return str(tmp_path / "warm")
 
 
 def test_solve_many_warm_zero_builds_zero_traces(model, cache_dir):

@@ -108,22 +108,36 @@ def test_mg_predicts_costlier_iterations_than_jacobi():
         assert mg["predicted_ms_per_iter"] > ja["predicted_ms_per_iter"]
 
 
-def test_resolve_profile_platform_and_env_overrides(monkeypatch):
+def test_resolve_profile_device_kind_and_env_overrides(monkeypatch):
     assert perf.resolve_profile("cpu").name == "cpu"
     assert perf.resolve_profile("CPU (x86)").name == "cpu"
-    assert perf.resolve_profile("TPU v4").name == "tpu"
-    assert perf.resolve_profile("tpu").name == "tpu"
     monkeypatch.setenv("PCG_TPU_ROOFLINE_HBM_GBS", "123")
     monkeypatch.setenv("PCG_TPU_ROOFLINE_COLL_LAT_US", "7")
-    prof = perf.resolve_profile("tpu")
+    prof = perf.resolve_profile("TPU v5 lite")
     assert prof.hbm_bytes_per_s == pytest.approx(123e9)
     assert prof.coll_latency_s == pytest.approx(7e-6)
     # overridden HBM rate must move a memory-bound prediction
     base = perf.cost_model(SP_SHAPE, "classic", "jacobi",
-                           profile=perf.HW_PROFILES["tpu"])
+                           profile=perf.HW_PROFILES["TPU v5 lite"])
     fast = perf.cost_model(SP_SHAPE, "classic", "jacobi", profile=prof)
     assert fast["predicted_ms_per_iter"] != \
         base["predicted_ms_per_iter"]
+
+
+def test_resolve_profile_v5e_published_rates():
+    """The v5e entry carries the published peaks (Google Cloud "TPU
+    v5e"): its HBM rate never exceeds 819 GB/s."""
+    p = perf.resolve_profile("TPU v5 lite")   # the v5e's device_kind
+    assert p.name == "TPU v5e"
+    assert p.hbm_bytes_per_s <= 819e9
+    assert p.flops_per_s == pytest.approx(197e12)
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "tpu", "TPU v5e", "TPU v6 lite"])
+def test_resolve_profile_unknown_accelerator_raises(kind):
+    """No rate is invented for an accelerator kind without an entry."""
+    with pytest.raises(ValueError, match="no roofline profile"):
+        perf.resolve_profile(kind)
 
 
 def test_bench_detail_schema_covers_model_fields():
@@ -139,11 +153,15 @@ def test_bench_line_prediction_from_detail_fields():
     from pcg_mpi_solver_tpu.bench import _predict_ms_per_iter
 
     detail = {"n_dof": 3_000_000, "n_parts": 8, "backend": "structured",
-              "mode": "mixed", "dtype": "float64", "platform": "TPU v6e",
+              "mode": "mixed", "dtype": "float64", "platform": "tpu",
+              "device_kind": "TPU v5 lite",
               "pcg_variant": "fused", "precond": "jacobi", "nrhs": 1}
     pred = _predict_ms_per_iter(detail)
     assert pred and np.isfinite(pred) and pred > 0
     assert _predict_ms_per_iter({**detail, "n_dof": 0}) is None
+    # an accelerator kind with no profile gets no invented number
+    assert _predict_ms_per_iter({**detail, "device_kind": "TPU v6e"}) \
+        is None
     with pytest.raises(KeyError):
         _predict_ms_per_iter({**detail, "pcg_variant": "mislabeled"})
 

@@ -1,7 +1,7 @@
 """Profile harvest (ISSUE 15, obs/profview.py + obs/trend.py): the
 capture -> parse -> attribute round trip on CPU, the tolerant-reader
 degradation legs, the overlap interval math on synthetic timelines, and
-the bench-trend regression sentinel over the committed BENCH_r0*.json
+the bench-trend regression sentinel over tests/data/bench_rounds/
 artifacts."""
 
 import glob
@@ -162,6 +162,38 @@ def test_scope_map_from_hlo_text():
     assert m == {"dot.0": "matvec", "add.2": "axpy"}
 
 
+def test_scope_map_unlabeled_fusions_inherit_phase():
+    """The installed XLA labels a fusion's fused computation, not the
+    fusion line: the fusion takes its computation's agreed phase, and an
+    unlabeled split (reduce-window) takes its labeled operand's; a
+    computation of two phases, or loop control, maps to nothing."""
+    txt = '''
+%fused_computation.8 (p0: f64[1,8]) -> f64[1,8] {
+  %mul.3 = f64[1,8] multiply(...), metadata={op_name="jit(f)/pcg/reduce/mul"}
+}
+
+%wrapped_rw_computation (p0: f64[1,8]) -> f64[1,1] {
+  ROOT %reduce-window.2 = f64[1,1] reduce-window(%p0), window={size=1x8}
+}
+
+%mixed_computation (p0: f64[1,8]) -> f64[1,8] {
+  %a = f64[1,8] add(...), metadata={op_name="jit(f)/pcg/axpy/add"}
+  %b = f64[1,8] multiply(...), metadata={op_name="jit(f)/pcg/matvec/mul"}
+}
+
+ENTRY %main (p: f64[1,8]) -> f64[1,1] {
+  %mm_fusion = f64[1,8] fusion(%gte.1, %gte.2), kind=kLoop, calls=%fused_computation.8
+  %wrapped_reduce-window.6 = f64[1,1] fusion(%mm_fusion, %constant.21), kind=kLoop, calls=%wrapped_rw_computation
+  %mixed_fusion = f64[1,8] fusion(%gte.3), kind=kLoop, calls=%mixed_computation
+  %compare_and_fusion = pred[] fusion(%gte.4), kind=kLoop, calls=%cond_computation, metadata={op_name="jit(f)/while/cond/and"}
+}
+'''
+    m = profview.scope_map_from_hlo_text(txt)
+    assert m["mm_fusion"] == "reduction"
+    assert m["wrapped_reduce-window.6"] == "reduction"
+    assert "mixed_fusion" not in m and "compare_and_fusion" not in m
+
+
 # ----------------------------------------------------------------------
 # tolerant reader: gz + plain, truncated, missing lanes, missing file
 # ----------------------------------------------------------------------
@@ -193,7 +225,7 @@ def test_reader_truncated_file_degrades_not_crashes(tmp_path):
     with open(p, "rb") as f:
         blob = f.read()
     with open(p, "wb") as f:
-        f.write(blob[: len(blob) // 2])     # the dead-tunnel artifact
+        f.write(blob[: len(blob) // 2])     # the killed-process artifact
     evs, probs = profview.read_trace_events(p)
     assert evs == [] and probs, probs
     rep = profview.profile_report(p)
@@ -341,31 +373,35 @@ def test_scope_map_from_solver_nonempty_all_variants():
 
 
 # ----------------------------------------------------------------------
-# trend sentinel over the committed artifacts
+# trend sentinel over round artifacts (synthetic fixtures)
 # ----------------------------------------------------------------------
 
-def test_trend_parses_committed_artifacts():
-    arts = sorted(glob.glob(os.path.join(REPO, "BENCH_r0*.json")))
-    assert len(arts) >= 5
+ROUNDS = os.path.join(REPO, "tests", "data", "bench_rounds")
+
+
+def test_trend_parses_round_artifacts():
+    arts = sorted(glob.glob(os.path.join(ROUNDS, "BENCH_r0*.json")))
+    assert len(arts) == 4
     rep = trend.trend_report(arts)
-    # r01..r03 are failed-round wrappers (rc != 0, parsed null): they
-    # contribute zero lines but must parse without error
+    # r01 is a failed-round wrapper (rc != 0, parsed null): it
+    # contributes zero lines but must parse without error
     by_label = {s["label"]: s["lines"] for s in rep["sources"]}
     assert by_label["BENCH_r01.json"] == 0
-    assert by_label["BENCH_r04.json"] >= 1
-    assert by_label["BENCH_r05.json"] >= 1
-    # r04 (46875 dofs) and r05 (10.3M dofs) are different legs: matched
-    # pairs cannot be fabricated across shapes
+    assert by_label["BENCH_r02.json"] == 1
+    assert by_label["BENCH_r04.json"] == 1
+    # the pre-schema r02 line matches the schema r03 line of its shape
+    # under the historical defaults; the 10.3M-dof r04 line is another
+    # leg: matched pairs cannot be fabricated across shapes
     assert rep["regressed"] == 0
-    assert rep["single"] >= 2
+    assert rep["flat"] == 1 and rep["single"] == 1
     legs = {l["leg"] for l in rep["legs"]}
     assert any("10328853" in l for l in legs)
 
 
 def test_trend_seeded_regression_exits_nonzero(tmp_path, capsys):
-    arts = sorted(glob.glob(os.path.join(REPO, "BENCH_r0*.json")))
+    arts = sorted(glob.glob(os.path.join(ROUNDS, "BENCH_r0*.json")))
     fresh_line = json.load(open(
-        os.path.join(REPO, "BENCH_r05.json")))["parsed"]
+        os.path.join(ROUNDS, "BENCH_r04.json")))["parsed"]
     fresh = dict(fresh_line, value=fresh_line["value"] * 0.5)
     fp = str(tmp_path / "fresh.json")
     json.dump(fresh, open(fp, "w"))

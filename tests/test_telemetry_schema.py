@@ -1,5 +1,6 @@
 """Fast artifact lint (tier-1): every committed BENCH_*.json round
-artifact — and any telemetry JSONL the tree carries — must validate
+artifact (tests/data/bench_rounds/ holds fixtures of each shape) — and
+any telemetry JSONL the tree carries — must validate
 against the versioned schemas in obs/schema.py, via the same
 tools/check_telemetry_schema.py entry point CI and humans run."""
 
@@ -17,7 +18,7 @@ import check_telemetry_schema as lint  # noqa: E402
 
 def test_committed_bench_artifacts_validate():
     paths = lint.default_paths()
-    assert paths, "expected committed BENCH_*.json artifacts at repo root"
+    assert paths, "expected committed BENCH_*.json artifacts"
     errors = []
     for p in paths:
         errors.extend(lint.check_file(p))
@@ -38,7 +39,8 @@ def test_tool_cli_exit_codes(tmp_path):
 
 
 def test_tool_accepts_failed_round_wrapper(tmp_path):
-    """BENCH_r01..r03 shape: the driver captured a crash (rc != 0,
+    """tests/data/bench_rounds/BENCH_r01.json shape: the driver captured
+    a crash (rc != 0,
     parsed null) — a legitimate artifact, not a schema violation."""
     p = tmp_path / "BENCH_failed.json"
     p.write_text(json.dumps({"n": 1, "cmd": "python bench.py", "rc": 1,
@@ -66,7 +68,7 @@ def test_setup_detail_fields_validate():
     """Warm-path bench fields (cache/ subsystem): numeric-or-null
     setup_s/time_to_first_iter_s and the off/cold/warm setup_cache enum
     are enforced WHEN present; absent fields (pre-warm-path committed
-    artifacts) stay valid — exercised above on the real BENCH_r0*.json."""
+    artifacts) stay valid — exercised above on the fixtures."""
     from pcg_mpi_solver_tpu.obs.schema import validate_bench_line
 
     base = {"metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0}
