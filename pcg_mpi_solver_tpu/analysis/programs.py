@@ -218,6 +218,36 @@ def build_programs(fast: bool = False) -> List[Program]:
     return out
 
 
+_MATVEC_CACHE: List = []
+
+
+def matvec_programs() -> List:
+    """(name, ClosedJaxpr) of ONE assembled matvec per backend — general,
+    structured and hybrid (a small graded octree) — each a 2-part SPMD
+    program, so the interface assembly is real work.  The scope-labels
+    rule reads the ``mv/*`` stage labels from them."""
+    if _MATVEC_CACHE:
+        return _MATVEC_CACHE
+    from pcg_mpi_solver_tpu.models.octree import make_octree_model
+    from pcg_mpi_solver_tpu.solver import Solver
+
+    octree = make_octree_model(2, 2, 2, max_level=2, n_incl=2, seed=3,
+                               load="traction", load_value=1.0)
+    for backend in ("general", "structured", "hybrid"):
+        if backend == "hybrid":
+            cfg = RunConfig(solver=SolverConfig(iters_per_dispatch=0))
+            s = Solver(octree, cfg, mesh=_mesh2(), n_parts=2,
+                       backend="hybrid")
+        else:
+            s = build_solver(backend)
+        mv = jax.shard_map(s.ops.matvec, mesh=s.mesh,
+                           in_specs=(s._specs, s._part_spec),
+                           out_specs=s._part_spec, check_vma=False)
+        _MATVEC_CACHE.append(
+            (f"matvec[{backend}]", jax.make_jaxpr(mv)(s.data, s.un)))
+    return _MATVEC_CACHE
+
+
 # ---------------------------------------------------------------------------
 # Donation surfaces (donation-integrity rule): every donate_carry
 # dispatch surface of the real drivers, with example abstract arguments

@@ -70,7 +70,7 @@ TRACE_NEUTRAL_RUNCONFIG = frozenset({
     "scratch_path", "model_name", "run_id", "n_parts",
     "partition_method", "speed_test", "checkpoint_every",
     "snapshot_every", "preflight", "cache_dir", "telemetry_path",
-    "flight_path", "telemetry_profile", "profile_dir", "comm_probe_iters",
+    "flight_path", "profile_dir", "comm_probe_iters",
     "solver", "time_history",
     # ISSUE 14: sharded setup builds the SAME partition rows this
     # process would otherwise slice out of a full build — device data,

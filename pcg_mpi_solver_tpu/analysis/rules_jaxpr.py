@@ -175,6 +175,26 @@ def check_scope_labels(prog, phase_scopes=None) -> List[Finding]:
     return out
 
 
+def check_matvec_stages(name: str, closed_jaxpr,
+                        stages=None) -> List[Finding]:
+    """Every ``mv/*`` stage label (ops/matvec.MATVEC_STAGES: gather,
+    contract, scatter, halo) must appear in a backend's traced matvec —
+    a stage that lost its ``jax.named_scope`` would silently fold its
+    device time into the matvec's unstaged rest in every parsed trace.
+    ``stages`` is the seeded-violation test hook."""
+    from pcg_mpi_solver_tpu.analysis import jaxpr_utils as ju
+    from pcg_mpi_solver_tpu.ops.matvec import MATVEC_STAGES
+
+    found = ju.scope_labels(closed_jaxpr, prefix="mv/")
+    return [Finding(
+        rule="scope-labels", loc=f"program:{name}",
+        message=f"matvec stage label {label!r} is absent from the traced "
+                "program: its device time would read as unstaged matvec "
+                f"time (labels found: {sorted(found)})")
+        for label in (stages if stages is not None else MATVEC_STAGES)
+        if not found.get(label)]
+
+
 def check_unknown_label_loudness(bucket_fn=None) -> List[Finding]:
     """The parser-side half of the contract: a device op matching NO
     phase must be COUNTED (other_events/other_ms), and a ``pcg/<x>``
@@ -235,12 +255,18 @@ def check_unknown_label_loudness(bucket_fn=None) -> List[Finding]:
 @rule("scope-labels", kind="jaxpr", fast=True,
       doc="every pcg/* named-scope label the trace consumer "
           "(obs/profview.py) buckets on appears in the traced hot loop "
-          "of every variant (scalar + blocked), and the parser counts "
+          "of every variant (scalar + blocked), every backend's matvec "
+          "carries the four mv/* stage labels, and the parser counts "
           "+ reports unknown labels instead of dropping them")
 def scope_labels_rule(ctx) -> List[Finding]:
+    from pcg_mpi_solver_tpu.analysis.programs import matvec_programs
+
     out = []
     for prog in ctx.programs():
         out.extend(check_scope_labels(prog))
+        out.extend(check_matvec_stages(prog.name, prog.jaxpr))
+    for name, jx in matvec_programs():
+        out.extend(check_matvec_stages(name, jx))
     out.extend(check_unknown_label_loudness())
     return out
 

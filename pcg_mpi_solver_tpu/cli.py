@@ -83,13 +83,10 @@ def _load_settings(path, args) -> "RunConfig":
 def _apply_telemetry_flags(cfg, args) -> None:
     """Wire the shared per-run flags into the RunConfig: --telemetry-out
     (JSONL sink), --trace-resid (in-graph convergence ring),
-    --profile-spans (jax.profiler annotations), --cache-dir, and the
-    validate/ --preflight policy override."""
+    --cache-dir, and the validate/ --preflight policy override."""
     cfg.telemetry_path = getattr(args, "telemetry_out", None) or ""
     cfg.flight_path = getattr(args, "flight_out", None) or ""
     cfg.solver.trace_resid = int(getattr(args, "trace_resid", None) or 0)
-    if getattr(args, "profile_spans", False):
-        cfg.telemetry_profile = True
     cfg.cache_dir = _resolve_cache_dir(args)
     cfg.preflight = getattr(args, "preflight", None) or ""
 
@@ -239,10 +236,6 @@ def _add_telemetry_flags(p) -> None:
     p.add_argument("--summary", action="store_true",
                    help="print the per-step / per-dispatch telemetry "
                         "table after the run")
-    p.add_argument("--profile-spans", action="store_true",
-                   help="wrap each device dispatch in a named "
-                        "jax.profiler.TraceAnnotation (also "
-                        "PCG_TPU_PROFILE_SPANS=1)")
 
 
 def cmd_ingest(args):

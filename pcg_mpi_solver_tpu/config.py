@@ -334,11 +334,6 @@ class RunConfig:
     # "" = environment default (PCG_TPU_FLIGHT), ultimately off.
     # CLI: --flight-out.
     flight_path: str = ""
-    # Opt-in jax.profiler.TraceAnnotation around each device dispatch so
-    # profiler traces show named pcg-tpu/<dispatch> regions (also
-    # PCG_TPU_PROFILE_SPANS=1).  Independent of profile_dir below, which
-    # starts/stops an actual trace collection.
-    telemetry_profile: bool = False
     # When set, the solve loop runs under a jax.profiler trace written here
     # (open with TensorBoard/XProf).  This is the TPU-native replacement for
     # the reference's hand-rolled calc vs comm-wait bracketing

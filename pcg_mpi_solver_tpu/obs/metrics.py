@@ -1,6 +1,6 @@
 """Step metrics registry: counters, gauges, wall-time spans and structured
-events, fanned out to pluggable sinks (stderr, JSONL, and — opt-in — a
-``jax.profiler`` trace annotation around each device dispatch).
+events, fanned out to pluggable sinks (stderr, JSONL), with every span and
+dispatch also a ``jax.profiler`` trace annotation ``pcg-tpu/<name>``.
 
 This is the ONE logging path of the framework: the old ``_vlog`` stderr
 breadcrumbs of ``solver/driver.py`` are now ``note`` events through a
@@ -13,8 +13,14 @@ Design constraints:
   adds zero device<->host transfers per PCG iteration (the in-graph
   residual trace lives in ``obs/trace.py`` and is fetched once per solve).
 * Import-light.  ``bench.py`` imports this module before configuring the
-  accelerator environment, so jax is imported lazily and only when the
-  opt-in profiler spans are enabled.
+  accelerator environment, so jax is never imported here: a span opens
+  its trace annotation only once something else has loaded jax (before
+  that no profiler trace can be collecting).
+* Annotations are always on.  ``TraceAnnotation`` is a no-op while no
+  trace is collecting (a few microseconds per span); under
+  ``jax.profiler.start_trace`` each span is one named interval on the
+  device trace's clock, the same interval its JSONL event and the
+  ``span_stats()`` / ``dispatch_stats()`` tables time.
 * A recorder with no sinks is a cheap null object: counters/spans still
   accumulate (for the ``--summary`` table) but nothing is formatted or
   written.
@@ -27,7 +33,7 @@ import os
 import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional
 
 from pcg_mpi_solver_tpu.obs.schema import TELEMETRY_SCHEMA
@@ -111,6 +117,17 @@ class JsonlSink:
             pass
 
 
+def _annotation(name: str, args: Dict[str, Any]):
+    """``jax.profiler.TraceAnnotation("pcg-tpu/<name>", **args)``, or a
+    null context while jax is not loaded (no trace can be collecting
+    then, and importing it here would initialise it before ``bench.py``
+    configures the accelerator environment)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return nullcontext()
+    return jax.profiler.TraceAnnotation(f"pcg-tpu/{name}", **args)
+
+
 class MetricsRecorder:
     """Counters + gauges + monotonic wall-time spans + structured events.
 
@@ -119,8 +136,7 @@ class MetricsRecorder:
     triplet ``schema``/``t``/``kind`` (see ``obs/schema.py``).
     """
 
-    def __init__(self, sinks=(), profile_spans: bool = False,
-                 clock=time.monotonic):
+    def __init__(self, sinks=(), clock=time.monotonic):
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, Any] = {}
         self.sinks: List[Any] = list(sinks)
@@ -129,7 +145,6 @@ class MetricsRecorder:
         # begin/end flight records so a SIGKILL mid-dispatch leaves a
         # parseable artifact naming the in-flight program.
         self.flight = None
-        self.profile_spans = bool(profile_spans)
         self._clock = clock
         self._spans: Dict[str, List[float]] = {}    # name -> [count, total_s]
         # per-dispatch-name: [calls, cold_s, warm_s] — the first call of a
@@ -141,13 +156,11 @@ class MetricsRecorder:
 
     # -- construction ---------------------------------------------------
     @classmethod
-    def default(cls, jsonl_path: Optional[str] = None,
-                profile: Optional[bool] = None) -> "MetricsRecorder":
+    def default(cls, jsonl_path: Optional[str] = None) -> "MetricsRecorder":
         """The solver-facing factory: stderr breadcrumbs gated on
         ``PCG_TPU_VERBOSE=1`` per event (the historical knob, checked at
         every emit like the old ``_vlog`` so it can be flipped on a LIVE
-        process), JSONL sink iff a path is given, profiler spans iff
-        requested (or ``PCG_TPU_PROFILE_SPANS=1``)."""
+        process), JSONL sink iff a path is given."""
         sinks: List[Any] = [EnvGatedStderrSink()]
         if jsonl_path:
             # Multi-process jax.distributed: each process appends to its
@@ -160,9 +173,7 @@ class MetricsRecorder:
             from pcg_mpi_solver_tpu.obs.flight import shard_jsonl_path
 
             sinks.append(JsonlSink(shard_jsonl_path(jsonl_path)))
-        if profile is None:
-            profile = os.environ.get("PCG_TPU_PROFILE_SPANS") == "1"
-        return cls(sinks=sinks, profile_spans=bool(profile))
+        return cls(sinks=sinks)
 
     def add_sink(self, sink) -> None:
         with self._lock:
@@ -215,12 +226,15 @@ class MetricsRecorder:
 
     # -- timing ---------------------------------------------------------
     @contextmanager
-    def span(self, name: str, emit: bool = False):
+    def span(self, name: str, emit: bool = False, **args):
         """Accumulate monotonic wall time under ``name``; ``emit=True``
-        additionally emits a ``bench_phase`` event on exit."""
+        additionally emits a ``bench_phase`` event on exit.  The span is
+        also the trace annotation ``pcg-tpu/<name>``, carrying ``args``
+        (e.g. ``step=3``) as its arguments."""
         t0 = self._clock()
         try:
-            yield
+            with _annotation(name, args):
+                yield
         finally:
             dt = self._clock() - t0
             with self._lock:
@@ -231,11 +245,11 @@ class MetricsRecorder:
                 self.event("bench_phase", name=name, wall_s=round(dt, 6))
 
     @contextmanager
-    def dispatch(self, name: str, emit: bool = True):
+    def dispatch(self, name: str, emit: bool = True, **args):
         """Wrap one jitted device dispatch: cold/warm attribution (first
-        call of a program = the call that paid XLA compile) and, when
-        ``profile_spans`` is on, a ``jax.profiler.TraceAnnotation`` so the
-        dispatch shows up as a named region in profiler traces.
+        call of a program = the call that paid XLA compile) and the trace
+        annotation ``pcg-tpu/<name>`` (with ``args``), so the dispatch is
+        a named region in profiler traces.
 
         Caller contract: jax dispatch is ASYNC — keep a blocking
         device->host fetch (``int(scalar)``, ``float(scalar)``,
@@ -245,12 +259,6 @@ class MetricsRecorder:
             st = self._dispatch.setdefault(name, [0, 0.0, 0.0])
             cold = st[0] == 0
             st[0] += 1
-        if self.profile_spans:
-            import jax  # deferred: bench configures env before jax init
-
-            ann = jax.profiler.TraceAnnotation(f"pcg-tpu/{name}")
-        else:
-            ann = None
         # Flight bracket (obs/flight.py): the begin record is fsync'd
         # BEFORE the dispatch runs, so a device loss / SIGKILL inside
         # the device call leaves "dispatch:<name> in flight" on disk.
@@ -261,10 +269,7 @@ class MetricsRecorder:
         ok = True
         err = None
         try:
-            if ann is not None:
-                with ann:
-                    yield
-            else:
+            with _annotation(name, args):
                 yield
         except BaseException as e:
             ok = False

@@ -196,6 +196,13 @@ def device_data(pm: PartitionedModel, dtype=jnp.float64,
     return d
 
 
+#: The named stages every backend's matvec is traced in (``jax.named_scope``
+#: labels; the analysis/ ``scope-labels`` rule proves each appears):
+#: dof vector to element or grid layout, the per-type ``Ke`` product,
+#: element or grid values back to dofs, and the cross-part assembly.
+MATVEC_STAGES = ("mv/gather", "mv/contract", "mv/scatter", "mv/halo")
+
+
 @dataclasses.dataclass(frozen=True)
 class Ops:
     """Static-shape metadata + the operator methods.
@@ -376,46 +383,55 @@ class Ops:
         batches over the trailing axis ((d x d) @ (d x N x nrhs): higher
         MXU utilization at near-constant gather/scatter traffic, the
         ISSUE-6 batched-SpMV shape) and the result keeps the block axis."""
+        # Traced in three named stages: ``mv/gather`` (dof vector to
+        # element values), ``mv/contract`` (the per-type ``Ke`` product)
+        # and ``mv/scatter`` (element values back to dofs, springs
+        # included), so a profiler trace splits the matvec's time by stage.
         blocked = x.ndim == 3
         if self.use_node_ell:
             rows = []
             for blk in data["blocks"]:
-                u3 = self._gather_u3(x, blk)             # (P, a, n, c[, r])
-                sgn = (blk["sign_nc"][..., None] if blocked
-                       else blk["sign_nc"])
-                u3 = jnp.where(sgn, -u3, u3)
-                ck = blk["ck"][:, None, :, None]
-                if blocked:
-                    v = jnp.einsum("bdac,pancr->pbndr", blk["Ke4"],
-                                   ck[..., None] * u3,
-                                   precision=self.precision)
-                else:
-                    v = jnp.einsum("bdac,panc->pbnd", blk["Ke4"],
-                                   ck * u3,
-                                   precision=self.precision)  # (P, b, n, d)
-                v = jnp.where(sgn, -v, v)
-                Pn, nn, N = v.shape[:3]
-                rows.append(v.reshape((Pn, nn * N, 3) + x.shape[2:]))
-            y = self._scatter_rows(data, rows)
+                with jax.named_scope("mv/gather"):
+                    sgn = (blk["sign_nc"][..., None] if blocked
+                           else blk["sign_nc"])
+                    u3 = self._gather_u3(x, blk)         # (P, a, n, c[, r])
+                    u3 = jnp.where(sgn, -u3, u3)
+                with jax.named_scope("mv/contract"):
+                    ck = blk["ck"][:, None, :, None]
+                    if blocked:
+                        v = jnp.einsum("bdac,pancr->pbndr", blk["Ke4"],
+                                       ck[..., None] * u3,
+                                       precision=self.precision)
+                    else:
+                        v = jnp.einsum("bdac,panc->pbnd", blk["Ke4"],
+                                       ck * u3,
+                                       precision=self.precision)
+                    v = jnp.where(sgn, -v, v)            # (P, b, n, d)
+                    Pn, nn, N = v.shape[:3]
+                    rows.append(v.reshape((Pn, nn * N, 3) + x.shape[2:]))
         else:
             per_block_v = []
             for blk in data["blocks"]:
-                u = self._gather_u(data, x, blk)             # (P, d, N[, r])
-                sgn = blk["sign"][..., None] if blocked else blk["sign"]
-                u = jnp.where(sgn, -u, u)
-                ck = blk["ck"][:, None, :]
-                if blocked:
-                    v = jnp.einsum("de,penr->pdnr", blk["Ke"],
-                                   ck[..., None] * u,
-                                   precision=self.precision)
-                else:
-                    v = jnp.einsum("de,pen->pdn", blk["Ke"],
-                                   ck * u,
-                                   precision=self.precision)
-                v = jnp.where(sgn, -v, v)
+                with jax.named_scope("mv/gather"):
+                    sgn = blk["sign"][..., None] if blocked else blk["sign"]
+                    u = self._gather_u(data, x, blk)         # (P, d, N[, r])
+                    u = jnp.where(sgn, -u, u)
+                with jax.named_scope("mv/contract"):
+                    ck = blk["ck"][:, None, :]
+                    if blocked:
+                        v = jnp.einsum("de,penr->pdnr", blk["Ke"],
+                                       ck[..., None] * u,
+                                       precision=self.precision)
+                    else:
+                        v = jnp.einsum("de,pen->pdn", blk["Ke"],
+                                       ck * u,
+                                       precision=self.precision)
+                    v = jnp.where(sgn, -v, v)
                 per_block_v.append(v)
-            y = self._scatter_blocks(data, per_block_v)
-        return self._apply_springs(data, x, y)
+        with jax.named_scope("mv/scatter"):
+            y = (self._scatter_rows(data, rows) if self.use_node_ell
+                 else self._scatter_blocks(data, per_block_v))
+            return self._apply_springs(data, x, y)
 
     def _apply_springs(self, data: dict, x, y):
         """Cohesive interface springs: f_a += k*(x_a - x_b), f_b -= same
@@ -585,8 +601,11 @@ class Ops:
         """Full assembled K.x across all parts (reference calcMPFint).
         ``x`` may carry a trailing RHS-block axis (P, n_loc, nrhs); the
         result keeps it, and the interface-assembly psum count stays ONE
-        regardless of the block width."""
-        return self.iface_assemble(data, self.matvec_local(data, x))
+        regardless of the block width.  The assembly is the ``mv/halo``
+        stage (the local product's stages: :meth:`matvec_local`)."""
+        y = self.matvec_local(data, x)
+        with jax.named_scope("mv/halo"):
+            return self.iface_assemble(data, y)
 
     def comm_estimate(self, storage_dtype=None,
                       variant: str = "classic",
@@ -845,31 +864,39 @@ def build_bucketed_blocks(pm: PartitionedModel, dtype=jnp.float64):
 def bucketed_matvec(ops: Ops, data: dict, x: jnp.ndarray) -> jnp.ndarray:
     """Assembled K.x via the bucketed blocks (data["buckets"] +
     device_data(..., blocks=False) leaves).  Same contract as
-    Ops.matvec; summation order differs (see module note above)."""
+    Ops.matvec; summation order differs (see module note above).  Same
+    ``mv/*`` stages as Ops.matvec."""
     Pn = x.shape[0]
     nr = ops.n_node_loc + 1
-    x3p = jnp.concatenate(
-        [x.reshape(Pn, ops.n_node_loc, 3),
-         jnp.zeros((Pn, 1, 3), x.dtype)], axis=1).reshape(Pn * nr, 3)
-    offs = (jnp.arange(Pn, dtype=jnp.int32) * nr)[:, None, None, None]
-    y3 = jnp.zeros((Pn, ops.n_node_loc, 3), x.dtype)
+    with jax.named_scope("mv/gather"):
+        x3p = jnp.concatenate(
+            [x.reshape(Pn, ops.n_node_loc, 3),
+             jnp.zeros((Pn, 1, 3), x.dtype)], axis=1).reshape(Pn * nr, 3)
+        offs = (jnp.arange(Pn, dtype=jnp.int32) * nr)[:, None, None, None]
+    with jax.named_scope("mv/scatter"):
+        y3 = jnp.zeros((Pn, ops.n_node_loc, 3), x.dtype)
     for bkt in data["buckets"]:
         node = bkt["node"]                              # (P, T, nn, Nmax)
         _, T, nn, N = node.shape
-        u3 = jnp.take(x3p, (node + offs).reshape(-1), axis=0,
-                      mode="clip").reshape(Pn, T, nn, N, 3)
-        # dof-row order d = 3a + c, matching TypeBlock.sign's layout
-        u = u3.transpose(0, 1, 2, 4, 3).reshape(Pn, T, 3 * nn, N)
-        u = jnp.where(bkt["sign"], -u, u)
-        v = jnp.einsum("tde,pten->ptdn", bkt["Ke"],
-                       bkt["ck"][:, :, None, :] * u,
-                       precision=ops.precision)
-        v = jnp.where(bkt["sign"], -v, v)
-        rows = (v.reshape(Pn, T, nn, 3, N).transpose(0, 1, 2, 4, 3)
-                .reshape(Pn, T * nn * N, 3))
-        ids = node.reshape(Pn, T * nn * N)
-        y3 = jax.vmap(
-            lambda yp, ip, rp: yp.at[ip].add(rp, mode="drop"))(y3, ids, rows)
-    y = y3.reshape(Pn, ops.n_loc)
-    y = ops._apply_springs(data, x, y)
-    return ops.iface_assemble(data, y)
+        with jax.named_scope("mv/gather"):
+            u3 = jnp.take(x3p, (node + offs).reshape(-1), axis=0,
+                          mode="clip").reshape(Pn, T, nn, N, 3)
+            # dof-row order d = 3a + c, matching TypeBlock.sign's layout
+            u = u3.transpose(0, 1, 2, 4, 3).reshape(Pn, T, 3 * nn, N)
+            u = jnp.where(bkt["sign"], -u, u)
+        with jax.named_scope("mv/contract"):
+            v = jnp.einsum("tde,pten->ptdn", bkt["Ke"],
+                           bkt["ck"][:, :, None, :] * u,
+                           precision=ops.precision)
+            v = jnp.where(bkt["sign"], -v, v)
+        with jax.named_scope("mv/scatter"):
+            rows = (v.reshape(Pn, T, nn, 3, N).transpose(0, 1, 2, 4, 3)
+                    .reshape(Pn, T * nn * N, 3))
+            ids = node.reshape(Pn, T * nn * N)
+            y3 = jax.vmap(
+                lambda yp, ip, rp: yp.at[ip].add(rp, mode="drop"))(
+                    y3, ids, rows)
+    with jax.named_scope("mv/scatter"):
+        y = ops._apply_springs(data, x, y3.reshape(Pn, ops.n_loc))
+    with jax.named_scope("mv/halo"):
+        return ops.iface_assemble(data, y)

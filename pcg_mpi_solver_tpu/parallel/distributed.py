@@ -111,10 +111,15 @@ def gather_owned_global(pm, x, mesh: Optional[jax.sharding.Mesh] = None,
     part).  The one shared mask-and-scatter idiom for every solver's
     global views — a trailing RHS-block axis rides through unchanged
     (one fetch, one masked scatter for the whole block)."""
-    tail = tuple(np.shape(x))[2:]
-    out = np.zeros((pm.glob_n_dof,) + tail, dtype=dtype or np.float64)
+    return scatter_owned_global(pm, fetch_global(x, mesh), dtype)
+
+
+def scatter_owned_global(pm, xh: np.ndarray, dtype=None) -> np.ndarray:
+    """The host half of :func:`gather_owned_global`: an already-fetched
+    (P, n_loc[, R]) array -> (glob_n_dof[, R]) through the owner mask."""
+    out = np.zeros((pm.glob_n_dof,) + xh.shape[2:], dtype=dtype or np.float64)
     m = (pm.weight > 0) & (pm.dof_gid >= 0)
-    out[pm.dof_gid[m]] = fetch_global(x, mesh)[m]
+    out[pm.dof_gid[m]] = xh[m]
     return out
 
 

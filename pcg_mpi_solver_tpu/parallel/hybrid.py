@@ -598,8 +598,9 @@ class HybridOps(Ops):
             from pcg_mpi_solver_tpu.ops.pallas_matvec import (
                 batched_structured_matvec)
 
-            return batched_structured_matvec(
-                xg, ck, Ke, interpret=self.pallas_interpret)
+            with jax.named_scope("mv/contract"):
+                return batched_structured_matvec(
+                    xg, ck, Ke, interpret=self.pallas_interpret)
         if self.form == "corner":
             from pcg_mpi_solver_tpu.parallel.structured import (
                 corner_matvec_grid)
@@ -612,23 +613,31 @@ class HybridOps(Ops):
 
             v = gsplit_matvec_grid(Ke, ck, xg, self.precision)
         else:
-            slots = [xg[:, :, dx:dx + bx, dy:dy + by, dz:dz + bz]
-                     for dx, dy, dz in _CORNERS]
-            u = jnp.concatenate(slots, axis=1)         # (P, 24, cells)
-            v = jnp.einsum("de,pexyz->pdxyz", Ke, ck[:, None] * u,
-                           precision=self.precision)
-        terms = []
-        for a, (dx, dy, dz) in enumerate(_CORNERS):
-            terms.append(jnp.pad(
-                v[:, 3 * a:3 * a + 3],
-                ((0, 0), (0, 0), (dx, 1 - dx), (dy, 1 - dy), (dz, 1 - dz))))
-        y = terms[0]
-        for t in terms[1:]:
-            y = y + t
+            with jax.named_scope("mv/gather"):
+                slots = [xg[:, :, dx:dx + bx, dy:dy + by, dz:dz + bz]
+                         for dx, dy, dz in _CORNERS]
+                u = jnp.concatenate(slots, axis=1)     # (P, 24, cells)
+            with jax.named_scope("mv/contract"):
+                v = jnp.einsum("de,pexyz->pdxyz", Ke, ck[:, None] * u,
+                               precision=self.precision)
+        with jax.named_scope("mv/scatter"):
+            terms = []
+            for a, (dx, dy, dz) in enumerate(_CORNERS):
+                terms.append(jnp.pad(
+                    v[:, 3 * a:3 * a + 3],
+                    ((0, 0), (0, 0), (dx, 1 - dx), (dy, 1 - dy),
+                     (dz, 1 - dz))))
+            y = terms[0]
+            for t in terms[1:]:
+                y = y + t
         return y
 
     # -- operator protocol ---------------------------------------------
     def matvec_local(self, data, x):
+        """Transition blocks through Ops.matvec_local, then each level
+        grid, in the same ``mv/gather`` (node rows to level lattices),
+        ``mv/contract`` (the brick stencil product) and ``mv/scatter``
+        (pad-add corner terms, the level combine, the springs) stages."""
         if x.ndim == 3:
             # RHS-block axis (Ops.matvec contract): the level-grid
             # gather/stencil/scatter machinery runs on flat vectors, so
@@ -640,22 +649,28 @@ class HybridOps(Ops):
         if data["blocks"]:
             y = Ops.matvec_local(self, data, x)
         else:
-            y = self._apply_springs(data, x, jnp.zeros_like(x))
+            with jax.named_scope("mv/scatter"):
+                y = self._apply_springs(data, x, jnp.zeros_like(x))
         if data["levels"]:
-            x3p = self._rows_pad(x)
+            with jax.named_scope("mv/gather"):
+                x3p = self._rows_pad(x)
             pal = self.pallas_levels or (False,) * len(data["levels"])
             use_gather = self._use_gather(data)
             rows_levels = []
             for lv, dims, pok in zip(data["levels"], self.level_dims, pal):
-                xg = self._level_gather(x3p, lv, dims, Pn)
-                ck = lv["ck"].reshape((Pn * dims[0],) + lv["ck"].shape[2:])
+                with jax.named_scope("mv/gather"):
+                    xg = self._level_gather(x3p, lv, dims, Pn)
+                    ck = lv["ck"].reshape((Pn * dims[0],)
+                                          + lv["ck"].shape[2:])
                 yg = self._stencil(data["brick_Ke"], ck, xg, pallas_ok=pok)
-                if use_gather:
-                    rows_levels.append(self._grid_rows(yg, Pn))
-                else:
-                    y = self._level_scatter_add(y, yg, lv, dims, Pn)
+                with jax.named_scope("mv/scatter"):
+                    if use_gather:
+                        rows_levels.append(self._grid_rows(yg, Pn))
+                    else:
+                        y = self._level_scatter_add(y, yg, lv, dims, Pn)
             if use_gather:
-                y = self._combined_gather_add(y, rows_levels, data, Pn)
+                with jax.named_scope("mv/scatter"):
+                    y = self._combined_gather_add(y, rows_levels, data, Pn)
         return y
 
     def diag_local(self, data):

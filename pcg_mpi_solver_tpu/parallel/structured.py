@@ -236,22 +236,27 @@ def corner_matvec_grid(Ke, ck, xg):
     w = []
     for a in range(8):
         dx, dy, dz = _CORNERS[a]
-        w.append(ck[:, None] * xg[:, :, dx:dx + cx, dy:dy + cy, dz:dz + cz])
+        with jax.named_scope("mv/gather"):
+            xa = xg[:, :, dx:dx + cx, dy:dy + cy, dz:dz + cz]
+        with jax.named_scope("mv/contract"):
+            w.append(ck[:, None] * xa)
     y = None
     for b in range(8):
         ex, ey, ez = _CORNERS[b]
-        comps = []
-        for d in range(3):
-            acc = None
-            for a in range(8):
-                for c in range(3):
-                    t = Ke[3 * b + d, 3 * a + c] * w[a][:, c]
-                    acc = t if acc is None else acc + t
-            comps.append(acc)
-        vb = jnp.stack(comps, axis=1)                  # (P, 3, cells)
-        term = jnp.pad(vb, ((0, 0), (0, 0), (ex, 1 - ex),
-                            (ey, 1 - ey), (ez, 1 - ez)))
-        y = term if y is None else y + term
+        with jax.named_scope("mv/contract"):
+            comps = []
+            for d in range(3):
+                acc = None
+                for a in range(8):
+                    for c in range(3):
+                        t = Ke[3 * b + d, 3 * a + c] * w[a][:, c]
+                        acc = t if acc is None else acc + t
+                comps.append(acc)
+            vb = jnp.stack(comps, axis=1)              # (P, 3, cells)
+        with jax.named_scope("mv/scatter"):
+            term = jnp.pad(vb, ((0, 0), (0, 0), (ex, 1 - ex),
+                                (ey, 1 - ey), (ez, 1 - ez)))
+            y = term if y is None else y + term
     return y
 
 
@@ -271,10 +276,12 @@ def gsplit_matvec_grid(Ke, ck, xg, precision):
     v = None
     for a in range(8):
         dx, dy, dz = _CORNERS[a]
-        xa = xg[:, :, dx:dx + cx, dy:dy + cy, dz:dz + cz]
-        t = jnp.einsum("dc,pcxyz->pdxyz", Ke[:, 3 * a:3 * a + 3],
-                       ck[:, None] * xa, precision=precision)
-        v = t if v is None else v + t
+        with jax.named_scope("mv/gather"):
+            xa = xg[:, :, dx:dx + cx, dy:dy + cy, dz:dz + cz]
+        with jax.named_scope("mv/contract"):
+            t = jnp.einsum("dc,pcxyz->pdxyz", Ke[:, 3 * a:3 * a + 3],
+                           ck[:, None] * xa, precision=precision)
+            v = t if v is None else v + t
     return v
 
 
@@ -447,19 +454,26 @@ class StructuredOps(Ops):
             return self._gse_corner(blk, xg, ck)
         if self.form == "gsplit":
             return self._gse_split(blk, xg, ck)
-        u = self._gather_cells(xg)                     # (P, 24, cells)
-        v = jnp.einsum("de,pexyz->pdxyz", blk["Ke"], ck[:, None] * u,
-                       precision=self.precision)
-        return self._scatter_cells(v)
+        with jax.named_scope("mv/gather"):
+            u = self._gather_cells(xg)                 # (P, 24, cells)
+        with jax.named_scope("mv/contract"):
+            v = jnp.einsum("de,pexyz->pdxyz", blk["Ke"], ck[:, None] * u,
+                           precision=self.precision)
+        with jax.named_scope("mv/scatter"):
+            return self._scatter_cells(v)
 
     def _gse_corner(self, blk, xg, ck):
         return corner_matvec_grid(blk["Ke"], ck, xg)
 
     def _gse_split(self, blk, xg, ck):
-        return self._scatter_cells(
-            gsplit_matvec_grid(blk["Ke"], ck, xg, self.precision))
+        v = gsplit_matvec_grid(blk["Ke"], ck, xg, self.precision)
+        with jax.named_scope("mv/scatter"):
+            return self._scatter_cells(v)
 
     def matvec_local(self, data, x):
+        """Part-local K.x on the slab grid, in the ``mv/gather``,
+        ``mv/contract`` and ``mv/scatter`` stages of Ops.matvec_local
+        (the fused Pallas kernel is one ``mv/contract``)."""
         if x.ndim == 3:
             # RHS-block axis (Ops.matvec contract): the stencil is built
             # around grid reshapes of one flat vector, so the block is
@@ -468,15 +482,18 @@ class StructuredOps(Ops):
             return jax.vmap(lambda xc: self.matvec_local(data, xc),
                             in_axes=-1, out_axes=-1)(x)
         blk = data["blocks"][0]
-        xg = self._grid(x)                             # (P, 3, nxn, nny, nnz)
+        with jax.named_scope("mv/gather"):
+            xg = self._grid(x)                         # (P, 3, nxn, nny, nnz)
         chunk = self._chunk_planes(x.dtype)
         if (self.use_pallas and chunk == 0
                 and np.dtype(x.dtype) == np.float32):
             from pcg_mpi_solver_tpu.ops.pallas_matvec import (
                 batched_structured_matvec)
 
-            y = batched_structured_matvec(xg, blk["ck"], blk["Ke"],
-                                          interpret=self.pallas_interpret)
+            with jax.named_scope("mv/contract"):
+                y = batched_structured_matvec(
+                    xg, blk["ck"], blk["Ke"],
+                    interpret=self.pallas_interpret)
             return y.reshape(x.shape)
         if chunk == 0:
             # slice-gather + einsum: contiguous slices, MXU matmul, shifted
@@ -489,15 +506,20 @@ class StructuredOps(Ops):
 
         def body(i, y):
             a = i * chunk
-            xs = jax.lax.dynamic_slice(
-                xg, (0, 0, a, 0, 0), (Pl, 3, chunk + 1, ny + 1, nz + 1))
-            cks = jax.lax.dynamic_slice(
-                blk["ck"], (0, a, 0, 0), (Pl, chunk, ny, nz))
+            with jax.named_scope("mv/gather"):
+                xs = jax.lax.dynamic_slice(
+                    xg, (0, 0, a, 0, 0), (Pl, 3, chunk + 1, ny + 1, nz + 1))
+                cks = jax.lax.dynamic_slice(
+                    blk["ck"], (0, a, 0, 0), (Pl, chunk, ny, nz))
             ys = self._gse(blk, xs, cks)
-            cur = jax.lax.dynamic_slice(y, (0, 0, a, 0, 0), ys.shape)
-            return jax.lax.dynamic_update_slice(y, cur + ys, (0, 0, a, 0, 0))
+            with jax.named_scope("mv/scatter"):
+                cur = jax.lax.dynamic_slice(y, (0, 0, a, 0, 0), ys.shape)
+                return jax.lax.dynamic_update_slice(y, cur + ys,
+                                                    (0, 0, a, 0, 0))
 
-        y = jax.lax.fori_loop(0, n_chunks, body, jnp.zeros_like(xg))
+        with jax.named_scope("mv/scatter"):
+            y0 = jnp.zeros_like(xg)
+        y = jax.lax.fori_loop(0, n_chunks, body, y0)
         return y.reshape(x.shape)
 
     def matvec(self, data, x):
@@ -505,7 +527,8 @@ class StructuredOps(Ops):
             return jax.vmap(lambda xc: self.matvec(data, xc),
                             in_axes=-1, out_axes=-1)(x)
         yg = self._grid(self.matvec_local(data, x))
-        return self._halo(yg).reshape(x.shape)
+        with jax.named_scope("mv/halo"):
+            return self._halo(yg).reshape(x.shape)
 
     def diag_local(self, data):
         blk = data["blocks"][0]

@@ -29,7 +29,7 @@ import numpy as np
 from pcg_mpi_solver_tpu.obs.trace import trace_host_init, trace_specs
 from pcg_mpi_solver_tpu.solver.pcg import (
     LAGGED_VARIANTS, carry_part_specs, cold_carry, pcg, refine_tol,
-    select_best)
+    scoped, select_best)
 
 
 def _state_kind(state) -> str:
@@ -124,6 +124,7 @@ class ChunkedEngine:
 
             traced = self.trace_len > 0
 
+            @scoped("solve/start")
             def _inner_start(data, r, normr, n2b, trace=None):
                 tol_cycle = refine_tol(scfg.tol * n2b, normr, scfg.inner_tol)
                 rhat32 = (r / normr).astype(dd32)
@@ -174,9 +175,11 @@ class ChunkedEngine:
                     data64 = data["f64"]
                     eff = data64["eff"]
                     w = data64["weight"] * eff
-                    x2 = x + xinc32.astype(x.dtype) * scale
-                    r2 = fext - eff * ops.matvec(data64, x2)
-                    normr2 = jnp.sqrt(ops.wdot(w, r2, r2))
+                    with jax.named_scope("solve/start"):
+                        x2 = x + xinc32.astype(x.dtype) * scale
+                    with jax.named_scope("solve/residual64"):
+                        r2 = fext - eff * ops.matvec(data64, x2)
+                        normr2 = jnp.sqrt(ops.wdot(w, r2, r2))
                     return x2, r2, normr2
 
                 # donated previous iterate: x2 replaces x 1:1
@@ -184,6 +187,7 @@ class ChunkedEngine:
                     _refine, (data_specs, P, P, P, R), (P, P, R),
                     donate_argnums=(2,))
             else:
+                @scoped("solve/start")
                 def _refine_pre(x, xinc32, scale):
                     return x + xinc32.astype(x.dtype) * scale
 
@@ -191,6 +195,7 @@ class ChunkedEngine:
                 self._refine_pre_fn = smap(_refine_pre, (P, P, R), P,
                                            donate_argnums=(0,))
 
+                @scoped("solve/residual64")
                 def _refine_post(data, fext, kx2):
                     data64 = data["f64"]
                     w = data64["weight"] * data64["eff"]
@@ -260,6 +265,14 @@ class ChunkedEngine:
         if self._rec is None:
             return contextlib.nullcontext()
         return self._rec.dispatch(name)
+
+    def _span(self, name: str):
+        """Host span (wall time + the ``pcg-tpu/<name>`` trace
+        annotation) around several dispatches; free without a
+        recorder."""
+        if self._rec is None:
+            return contextlib.nullcontext()
+        return self._rec.span(name)
 
     def warmup(self, data, fext, carry, normr0, n2b, prec):
         """Compile every budget-loop program by running each ONCE with a
@@ -413,83 +426,91 @@ class ChunkedEngine:
                     resilience.sync_boundary()
                 prev = cur
                 try:
-                    # One refinement cycle: run the f32 inner solve to ITS
-                    # convergence via resumable capped dispatches, refine.
-                    vlog(f"inner_start dispatch (normr={float(normr):.3e})")
-                    start_args = (data, r, normr, n2b) + (
-                        (trace,) if trace is not None else ())
-                    with self._disp("inner_start"):
-                        rhat32, tol_cycle, c32 = self._inner_start_fn(
-                            *start_args)
-                    inner_flag, xin = 1, None
-                    first_dispatch, poisoned = True, False
-                    while inner_flag == 1 and total < scfg.max_iter:
-                        budget = jnp.asarray(scfg.max_iter - total,
-                                             jnp.int32)
-                        vlog(f"inner_cycle dispatch (total={total})")
-                        if faults is not None:
-                            faults.on_dispatch()
-                        cyc_args = (data, rhat32, prec, tol_cycle, c32,
-                                    budget) + ((normr,) if trace is not None
-                                               else ())
-                        with self._disp("inner_cycle"):
-                            xin, c32, iflag = self._inner_cycle_fn(*cyc_args)
-                            # scalar fetches INSIDE the span: jax dispatch
-                            # is async, so the span only measures execution
-                            # if it contains the blocking host transfer
-                            exec_n = int(c32["exec"])
-                            total += exec_n
-                            inner_flag = int(iflag)
-                        if faults is not None:
-                            faults.on_dispatch_done()
-                        vlog(f"inner_cycle done: +{exec_n} iters "
-                             f"flag={inner_flag}")
-                        if resilience is not None:
-                            # Corruption detection off ALREADY-fetched
-                            # scalars (no extra host sync on the healthy
-                            # path): (a) flag 0 with 0 iterations on the
-                            # cycle's FIRST dispatch is impossible for the
-                            # normalized inner rhs (||rhat|| = 1 > any
-                            # tol_cycle <= 0.25) unless an Inf rhs faked
-                            # tolb = tol * ||Inf|| = Inf; (b) a NaN carry
-                            # norm trips no MATLAB flag at all.  Either
-                            # way, hand the step to the driver ladder.
-                            if (first_dispatch and inner_flag == 0
-                                    and exec_n == 0) or not math.isfinite(
-                                        float(c32["normr_act"])):
-                                vlog("inner state non-finite/corrupt; "
-                                     "handing the step to the recovery "
-                                     "ladder")
-                                poisoned = True
-                                break
-                        first_dispatch = False
-                    if poisoned:
+                    with self._span("refine_cycle"):
+                        # One refinement cycle: run the f32 inner solve to
+                        # ITS convergence via resumable capped dispatches,
+                        # refine.
+                        vlog(f"inner_start dispatch "
+                             f"(normr={float(normr):.3e})")
+                        start_args = (data, r, normr, n2b) + (
+                            (trace,) if trace is not None else ())
+                        with self._disp("inner_start"):
+                            rhat32, tol_cycle, c32 = self._inner_start_fn(
+                                *start_args)
+                        inner_flag, xin = 1, None
+                        first_dispatch, poisoned = True, False
+                        while inner_flag == 1 and total < scfg.max_iter:
+                            budget = jnp.asarray(scfg.max_iter - total,
+                                                 jnp.int32)
+                            vlog(f"inner_cycle dispatch (total={total})")
+                            if faults is not None:
+                                faults.on_dispatch()
+                            cyc_args = (data, rhat32, prec, tol_cycle, c32,
+                                        budget) + (
+                                (normr,) if trace is not None else ())
+                            with self._disp("inner_cycle"):
+                                xin, c32, iflag = self._inner_cycle_fn(
+                                    *cyc_args)
+                                # scalar fetches INSIDE the span: jax
+                                # dispatch is async, so the span only
+                                # measures execution if it contains the
+                                # blocking host transfer
+                                exec_n = int(c32["exec"])
+                                total += exec_n
+                                inner_flag = int(iflag)
+                            if faults is not None:
+                                faults.on_dispatch_done()
+                            vlog(f"inner_cycle done: +{exec_n} iters "
+                                 f"flag={inner_flag}")
+                            if resilience is not None:
+                                # Corruption detection off ALREADY-fetched
+                                # scalars (no extra host sync on the healthy
+                                # path): (a) flag 0 with 0 iterations on the
+                                # cycle's FIRST dispatch is impossible for the
+                                # normalized inner rhs (||rhat|| = 1 > any
+                                # tol_cycle <= 0.25) unless an Inf rhs faked
+                                # tolb = tol * ||Inf|| = Inf; (b) a NaN carry
+                                # norm trips no MATLAB flag at all.  Either
+                                # way, hand the step to the recovery ladder.
+                                if (first_dispatch and inner_flag == 0
+                                        and exec_n == 0) or not math.isfinite(
+                                            float(c32["normr_act"])):
+                                    vlog("inner state non-finite/corrupt; "
+                                         "handing the step to the recovery "
+                                         "ladder")
+                                    poisoned = True
+                                    break
+                            first_dispatch = False
+                        if poisoned:
+                            if trace is not None:
+                                trace = c32["trace"]
+                            cur = float("nan")
+                            break
                         if trace is not None:
+                            # ring hand-off to the next cycle
+                            # (device-to-device)
                             trace = c32["trace"]
-                        cur = float("nan")
-                        break
-                    if trace is not None:
-                        # ring hand-off to the next cycle (device-to-device)
-                        trace = c32["trace"]
-                    if inner_flag != 0:
-                        # Failed/exhausted inner solve: min-residual
-                        # selection (the resumable path defers it; matches
-                        # one-shot pcg_mixed's inner finalize_bad).
-                        with self._disp("final32"):
-                            xin = self._final32_fn(data, rhat32, c32)
-                    vlog("refine dispatch (f64 true-residual matvec)")
-                    with self._disp("refine"):
-                        if self._amul_fn is None:
-                            x, r, normr = self._refine_fn(
-                                data, fext, x, xin, normr)
-                        else:
-                            x = self._refine_pre_fn(x, xin, normr)
-                            r, normr = self._refine_post_fn(
-                                data, fext, self._amul_fn(data, x))
-                        # blocking fetch inside the span (async dispatch) —
-                        # this also absorbs any still-running earlier
-                        # program (inner_start/final32 have no fetch)
-                        cur = float(normr)
+                        if inner_flag != 0:
+                            # Failed/exhausted inner solve: min-residual
+                            # selection (the resumable path defers it;
+                            # matches one-shot pcg_mixed's inner
+                            # finalize_bad).
+                            with self._disp("final32"):
+                                xin = self._final32_fn(data, rhat32, c32)
+                        vlog("refine dispatch (f64 true-residual matvec)")
+                        with self._disp("refine"):
+                            if self._amul_fn is None:
+                                x, r, normr = self._refine_fn(
+                                    data, fext, x, xin, normr)
+                            else:
+                                x = self._refine_pre_fn(x, xin, normr)
+                                r, normr = self._refine_post_fn(
+                                    data, fext, self._amul_fn(data, x))
+                            # blocking fetch inside the span (async
+                            # dispatch) — this also absorbs any
+                            # still-running earlier program
+                            # (inner_start/final32 have no fetch)
+                            cur = float(normr)
                 except Exception as e:                  # noqa: BLE001
                     st = (resilience.handle_dispatch_failure(e, "mixed")
                           if resilience is not None else None)

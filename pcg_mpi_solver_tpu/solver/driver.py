@@ -30,7 +30,7 @@ from pcg_mpi_solver_tpu.ops.matvec import Ops, device_data
 from pcg_mpi_solver_tpu.parallel.mesh import PARTS_AXIS, make_mesh
 from pcg_mpi_solver_tpu.parallel.partition import PartitionedModel, partition_model
 from pcg_mpi_solver_tpu.resilience.faultinject import FaultPlan
-from pcg_mpi_solver_tpu.solver.pcg import pcg, pcg_mixed
+from pcg_mpi_solver_tpu.solver.pcg import pcg, pcg_mixed, scoped
 
 #: (kernel variant, ``jax.Device.device_kind``) pairs on which the Pallas
 #: matvec has matched the XLA matvec AND solved the flagship on the chip.
@@ -183,8 +183,7 @@ class Solver:
         # JSONL sink iff config.telemetry_path is set).
         self.recorder = recorder if recorder is not None else (
             MetricsRecorder.default(
-                jsonl_path=self.config.telemetry_path or None,
-                profile=True if self.config.telemetry_profile else None))
+                jsonl_path=self.config.telemetry_path or None))
         self._rec = self.recorder
         # ---- flight recorder (obs/flight.py): crash-durable begin/end
         # brackets + heartbeats around every solve dispatch, so a device
@@ -691,6 +690,7 @@ class Solver:
         self.last_trace: Optional[ConvergenceTrace] = None
         trace_len = self.trace_len
 
+        @scoped("solve/start")
         def _step(data, un_prev, delta):
             # Host-side trace counter: runs ONLY while jax traces this
             # function.  The warm-path contract — an AOT-cache hit
@@ -1215,9 +1215,11 @@ class Solver:
             if self.f64_refresh == "bucketed":
                 from pcg_mpi_solver_tpu.ops.matvec import bucketed_matvec
 
+                @scoped("solve/residual64")
                 def _amul64g(rd, v):
                     return rd["eff"] * bucketed_matvec(rops, rd, v)
             else:
+                @scoped("solve/residual64")
                 def _amul64g(rd, v):
                     return rd["eff"] * rops.matvec(rd, v)
 
@@ -1226,6 +1228,7 @@ class Solver:
                 out_specs=P, check_vma=False))
             self._amul64_fn = lambda data, v: amul64g_jit(rdev, v)
         else:
+            @scoped("solve/residual64")
             def _amul64(data, v):
                 d = data["f64"] if mixed else data
                 return d["eff"] * self.ops.matvec(d, v)
@@ -1234,6 +1237,7 @@ class Solver:
                 _amul64, mesh=self.mesh, in_specs=(self._specs, P),
                 out_specs=P, check_vma=False))
 
+        @scoped("solve/start")
         def _start_pre(data, delta):
             data64 = data["f64"] if mixed else data
             return data64["Ud"] * delta
@@ -1242,6 +1246,7 @@ class Solver:
             _start_pre, mesh=self.mesh, in_specs=(self._specs, R),
             out_specs=P, check_vma=False))
 
+        @scoped("solve/start")
         def _start_mid(data, un_prev, delta, kudi):
             data64 = data["f64"] if mixed else data
             eff = data64["eff"]
@@ -1254,6 +1259,7 @@ class Solver:
             _start_mid, mesh=self.mesh, in_specs=(self._specs, P, R, P),
             out_specs=(P, P), check_vma=False))
 
+        @scoped("solve/start")
         def _start_post(data, fext, x0, kx0):
             data64 = data["f64"] if mixed else data
             w = data64["weight"] * data64["eff"]
@@ -1287,7 +1293,8 @@ class Solver:
             amul_fn=self._amul64_fn, trace_len=self.trace_len,
             recorder=self._rec, donate=self._donate,
             prec_spec=prec_spec)
-        self._finish_fn = jax.jit(lambda x, udi: x + udi)
+        self._finish_fn = jax.jit(
+            scoped("solve/start")(lambda x, udi: x + udi))
 
     def _step_chunked(self, delta):
         """Host-driven solve: repeated capped-iteration dispatches.
@@ -1500,6 +1507,7 @@ class Solver:
                                            variant=variant)
             trace_len, trace_dtype = self.trace_len, self._trace_dtype
 
+            @scoped("solve/start")
             def _restart(data, fext, x, kx):
                 d = data["f64"] if mixed else data
                 w = d["weight"] * d["eff"]
@@ -1534,6 +1542,7 @@ class Solver:
             mixed = self.mixed
             mg = self.config.solver.precond == "mg"
 
+            @scoped("solve/start")
             def _fb(data):
                 if mixed:
                     inv = make_prec(self.ops32, data["f32"], "jacobi")
@@ -1573,6 +1582,7 @@ class Solver:
                 cap=self._dispatch_cap, mixed=False, trace_len=0,
                 recorder=self._rec, donate=self._donate)
 
+            @scoped("solve/start")
             def _p64(data):
                 # scalar Jacobi: the escalation rung sits after the
                 # fallback-prec rung, so the safest inverse is the point
@@ -1591,9 +1601,10 @@ class Solver:
         silent retrace on the next step)."""
         from pcg_mpi_solver_tpu.parallel.distributed import put_sharded
 
-        self.un = put_sharded(
-            np.zeros((self.pm.n_parts, self.pm.n_loc), self.dtype),
-            self.mesh, self._part_spec)
+        with self._rec.span("reset"):
+            self.un = put_sharded(
+                np.zeros((self.pm.n_parts, self.pm.n_loc), self.dtype),
+                self.mesh, self._part_spec)
 
     # ------------------------------------------------------------------
     # Batched multi-RHS solves (ISSUE 6): many load cases, ONE operator
@@ -2152,61 +2163,68 @@ class Solver:
         # restart from — resilience is the chunked path's job
         # (_step_chunked -> run_with_recovery); the except arm below
         # only restores a retryable zero state.
-        t0 = time.perf_counter()
-        if self._dispatch_cap > 0:
-            flag, relres, iters = self._step_chunked(delta)
-        else:
-            try:
-                with self._rec.dispatch("step"):
-                    out = self._step_fn(
-                        self.data, self.un, jnp.asarray(delta, self.dtype))
-                    un, flag, relres, iters = out[:4]
-                    # Scalar fetch INSIDE the timed region and the dispatch
-                    # span: async dispatch returns immediately; fetching
-                    # the scalars cannot return before execution ends.
-                    flag, relres, iters = int(flag), float(relres), int(iters)
-            except BaseException:
-                # The dispatch may have CONSUMED the donated self.un
-                # before failing (or before a KeyboardInterrupt landed) —
-                # restore a live zero state so the solver stays retryable
-                # instead of every later access dying on a deleted
-                # buffer.  Only when actually consumed: an error raised
-                # before the jitted call ran (bad delta, a sink raising)
-                # must keep the intact previous iterate.
-                if self._donate and getattr(self.un, "is_deleted",
-                                            lambda: False)():
-                    self.reset_state()
-                    # the divergence from donate_carry=False (which
-                    # would have kept the previous iterate) must be
-                    # visible to whoever catches and retries
-                    self._rec.note(
-                        "failed dispatch consumed the donated solution "
-                        "vector; state RESET TO ZERO — a retry resumes "
-                        "from u=0, not the previous iterate")
-                raise
-            # trace ring: the solve's ONE device->host trace transfer
-            self.last_trace = (unpack_trace(out[4]) if self.trace_len
-                               else None)
-            self.un = un
-        wall = time.perf_counter() - t0
-        res = StepResult(flag, relres, iters, wall)
-        self.flags.append(res.flag)
-        self.relres.append(res.relres)
-        self.iters.append(res.iters)
-        self.step_times.append(wall)
-        self._proc_step_times.append(wall)
-        step_i = len(self.flags)
-        # time_to_tol_s: the ROADMAP-4 time-to-solution signal — wall to
-        # CONVERGED-at-tol, null on any non-0 flag (additive field; the
-        # bench stamps the same semantics on its result lines)
-        self._rec.event("step", step=step_i, flag=flag, relres=relres,
-                        iters=iters, wall_s=round(wall, 6),
-                        time_to_tol_s=(round(wall, 6) if flag == 0
-                                       else None))
-        if self.trace_len and self.last_trace is not None:
-            self._rec.event("resid_trace",
-                            **self.last_trace.to_event_fields(step_i))
-        return res
+        # the request's span: every dispatch below nests under it, and
+        # the host time between them is its own (``pcg-tpu/solve``)
+        with self._rec.span("solve", step=len(self.flags) + 1):
+            t0 = time.perf_counter()
+            if self._dispatch_cap > 0:
+                flag, relres, iters = self._step_chunked(delta)
+            else:
+                try:
+                    with self._rec.dispatch("step"):
+                        out = self._step_fn(
+                            self.data, self.un,
+                            jnp.asarray(delta, self.dtype))
+                        un, flag, relres, iters = out[:4]
+                        # Scalar fetch INSIDE the timed region and the
+                        # dispatch span: async dispatch returns immediately;
+                        # fetching the scalars cannot return before
+                        # execution ends.
+                        flag, relres, iters = (int(flag), float(relres),
+                                               int(iters))
+                except BaseException:
+                    # The dispatch may have CONSUMED the donated self.un
+                    # before failing (or before a KeyboardInterrupt
+                    # landed) — restore a live zero state so the solver
+                    # stays retryable instead of every later access dying
+                    # on a deleted buffer.  Only when actually consumed:
+                    # an error raised before the jitted call ran (bad
+                    # delta, a sink raising) must keep the intact previous
+                    # iterate.
+                    if self._donate and getattr(self.un, "is_deleted",
+                                                lambda: False)():
+                        self.reset_state()
+                        # the divergence from donate_carry=False (which
+                        # would have kept the previous iterate) must be
+                        # visible to whoever catches and retries
+                        self._rec.note(
+                            "failed dispatch consumed the donated solution "
+                            "vector; state RESET TO ZERO — a retry resumes "
+                            "from u=0, not the previous iterate")
+                    raise
+                # trace ring: the solve's ONE device->host trace transfer
+                self.last_trace = (unpack_trace(out[4]) if self.trace_len
+                                   else None)
+                self.un = un
+            wall = time.perf_counter() - t0
+            res = StepResult(flag, relres, iters, wall)
+            self.flags.append(res.flag)
+            self.relres.append(res.relres)
+            self.iters.append(res.iters)
+            self.step_times.append(wall)
+            self._proc_step_times.append(wall)
+            step_i = len(self.flags)
+            # time_to_tol_s: the ROADMAP-4 time-to-solution signal — wall
+            # to CONVERGED-at-tol, null on any non-0 flag (additive field;
+            # the bench stamps the same semantics on its result lines)
+            self._rec.event("step", step=step_i, flag=flag, relres=relres,
+                            iters=iters, wall_s=round(wall, 6),
+                            time_to_tol_s=(round(wall, 6) if flag == 0
+                                           else None))
+            if self.trace_len and self.last_trace is not None:
+                self._rec.event("resid_trace",
+                                **self.last_trace.to_event_fields(step_i))
+            return res
 
     def resume_elastic(self, snapshot_dir: Optional[str] = None,
                        **solve_kw):
@@ -2632,11 +2650,16 @@ class Solver:
         return rows[self.owner_mask()[p0:p1]], p0, p1
 
     def displacement_global(self) -> np.ndarray:
-        """Full global solution vector (n_dof,), assembled on host."""
-        from pcg_mpi_solver_tpu.parallel.distributed import gather_owned_global
+        """Full global solution vector (n_dof,), assembled on host: the
+        device->host copy is the span ``fetch.transfer``, the owner-mask
+        scatter into the global array ``fetch.assemble``."""
+        from pcg_mpi_solver_tpu.parallel.distributed import (
+            fetch_global, scatter_owned_global)
 
-        return gather_owned_global(self.pm, self.un, self.mesh,
-                                   np.dtype(self.dtype))
+        with self._rec.span("fetch.transfer"):
+            un = fetch_global(self.un, self.mesh)
+        with self._rec.span("fetch.assemble"):
+            return scatter_owned_global(self.pm, un, np.dtype(self.dtype))
 
 
 _REPLICATED_KEYS = frozenset(

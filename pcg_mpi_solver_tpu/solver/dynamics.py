@@ -78,8 +78,7 @@ class DynamicsSolver:
         # sink iff config.telemetry_path is set.
         self.recorder = recorder if recorder is not None else (
             MetricsRecorder.default(
-                jsonl_path=self.config.telemetry_path or None,
-                profile=True if self.config.telemetry_profile else None))
+                jsonl_path=self.config.telemetry_path or None))
         self._rec = self.recorder
         # Flight recorder (obs/flight.py): the same crash-durable
         # dispatch brackets the quasi-static Solver gets — a long

@@ -143,8 +143,7 @@ class NewmarkSolver:
         # config.telemetry_path is set).
         self.recorder = recorder if recorder is not None else (
             MetricsRecorder.default(
-                jsonl_path=self.config.telemetry_path or None,
-                profile=True if self.config.telemetry_profile else None))
+                jsonl_path=self.config.telemetry_path or None))
         self._rec = self.recorder
         # Flight recorder: same crash-durable dispatch brackets as the
         # quasi-static and explicit-dynamics drivers (obs/flight.py).

@@ -22,6 +22,12 @@ phases under ``jax.named_scope`` labels — ``pcg/matvec``,
 events bucket deterministically into the obs/perf.py attribution
 phases (obs/profview.py parses them back; the analysis/ fast-tier
 ``scope-labels`` rule proves the labels exist in every traced variant).
+The work outside those four phases is labelled too: ``solve/track`` is
+the loop's own bookkeeping (convergence and stagnation tests,
+min-residual tracking, the trace ring), ``solve/start`` the work around
+the loop (initial residual, norms, terminal selection) and
+``solve/residual64`` the mixed path's f64 true-residual refresh.  The
+labels are metadata only: the compiled program is the same without them.
 
 ``variant="fused"`` restructures the loop body around the
 Chronopoulos–Gear recurrence (the single-reduction CG of arXiv:2105.06176
@@ -66,6 +72,7 @@ O(1); NOT bit-exact with the reference.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -159,6 +166,17 @@ VALID_PCG_VARIANTS = PCG_VARIANTS
 # always_min``), and a warm resume must never flag-0 off the
 # predecessor's stale norm.
 LAGGED_VARIANTS = ("fused", "pipelined")
+
+
+def scoped(label: str):
+    """Decorator: trace ``f`` under the ``jax.named_scope`` ``label``."""
+    def deco(f):
+        @functools.wraps(f)
+        def g(*args, **kwargs):
+            with jax.named_scope(label):
+                return f(*args, **kwargs)
+        return g
+    return deco
 
 
 def drift_limit_for(variant: str) -> int:
@@ -278,6 +296,7 @@ def refine_tol(tolb, normr, inner_tol):
                     inner_tol, 0.25).astype(jnp.float32)
 
 
+@scoped("solve/start")
 def select_best(ops: Ops, data: dict, fext: jnp.ndarray, carry: dict,
                 always_min: bool = False):
     """Min-residual fallback for a terminally-failed resumable solve.
@@ -307,6 +326,7 @@ def select_best(ops: Ops, data: dict, fext: jnp.ndarray, carry: dict,
     return x, relres
 
 
+@scoped("solve/start")
 def pcg(
     ops: Ops,
     data: dict,
@@ -431,9 +451,13 @@ def pcg(
         Traced under the ``pcg/matvec`` named scope so profiler-trace
         events bucket deterministically (obs/profview.py; the handful of
         out-of-loop applications — r0, finalize, deferred checks — are
-        O(1) per solve and absorbed by the per-iteration division)."""
+        O(1) per solve and absorbed by the per-iteration division).  The
+        restriction is the last step of ``mv/scatter``: XLA fuses it with
+        the scatter's final adds."""
         with jax.named_scope("pcg/matvec"):
-            return eff * ops.matvec(data, v)
+            y = ops.matvec(data, v)
+            with jax.named_scope("mv/scatter"):
+                return eff * y
 
     if warm:
         x0 = carry_in["x"]
@@ -1129,7 +1153,11 @@ def pcg(
 
     loop_body = (body_pipelined if pipelined
                  else body_fused if fused else body)
-    c = jax.lax.while_loop(cond, loop_body, carry0)
+    # the loop itself is ``solve/track``: its cond, its body and the
+    # carry copies XLA adds around the loop (which carry the while op's
+    # label) all count as the loop's own work
+    with jax.named_scope("solve/track"):
+        c = jax.lax.while_loop(cond, loop_body, carry0)
 
     # ---- finalize (reference pcg_solver.py:566-584): on any non-converged
     # exit return the minimal-residual iterate (MATLAB pcg semantics).
@@ -1209,6 +1237,7 @@ def pcg(
     return result
 
 
+@scoped("solve/start")
 def pcg_mixed(
     ops32: Ops,
     data32: dict,
@@ -1289,8 +1318,9 @@ def pcg_mixed(
         return c["flag"] == -1
 
     def body(c):
-        r = fext - amul64(c["x"])
-        normr = jnp.sqrt(ops64.wdot(w64, r, r))
+        with jax.named_scope("solve/residual64"):
+            r = fext - amul64(c["x"])
+            normr = jnp.sqrt(ops64.wdot(w64, r, r))
         converged = normr <= tolb
         # no-progress guard: refinement must contract the residual
         # (first trip: normr_prev = inf, never trips)
@@ -1461,6 +1491,7 @@ def cold_carry_many(x0, r0, normr0, dot_dtype,
     return out
 
 
+@scoped("solve/start")
 def select_best_many(ops: Ops, data: dict, fext: jnp.ndarray, carry: dict,
                      always_min: bool = False,
                      respect_flags: bool = False):
@@ -1501,6 +1532,7 @@ def select_best_many(ops: Ops, data: dict, fext: jnp.ndarray, carry: dict,
     return x, relres
 
 
+@scoped("solve/start")
 def restart_carry_many(ops: Ops, data: dict, fext: jnp.ndarray,
                        carry: dict, restart_mask, fallback_mask,
                        quarantine_mask, variant: str = "classic") -> dict:
@@ -1565,6 +1597,7 @@ def restart_carry_many(ops: Ops, data: dict, fext: jnp.ndarray,
     return out
 
 
+@scoped("solve/start")
 def pcg_many(
     ops: Ops,
     data: dict,
@@ -1629,7 +1662,9 @@ def pcg_many(
         # named pcg/matvec: blocked trace events bucket like the scalar
         # loop's (obs/profview.py)
         with jax.named_scope("pcg/matvec"):
-            return eff[..., None] * ops.matvec(data, v)
+            y = ops.matvec(data, v)
+            with jax.named_scope("mv/scatter"):
+                return eff[..., None] * y
 
     if warm:
         x0 = carry_in["x"]
@@ -2118,7 +2153,11 @@ def pcg_many(
 
     loop_body = (body_pipelined if pipelined
                  else body_fused if fused else body)
-    c = jax.lax.while_loop(cond, loop_body, carry0)
+    # the loop itself is ``solve/track``: its cond, its body and the
+    # carry copies XLA adds around the loop (which carry the while op's
+    # label) all count as the loop's own work
+    with jax.named_scope("solve/track"):
+        c = jax.lax.while_loop(cond, loop_body, carry0)
 
     skip_mask = zero_rhs | initial_ok | frozen0
 
@@ -2190,6 +2229,7 @@ def pcg_many(
     return result
 
 
+@scoped("solve/start")
 def pcg_mixed_many(
     ops32: Ops,
     data32: dict,
@@ -2240,8 +2280,9 @@ def pcg_mixed_many(
         return jnp.any(c["flag"] == -1)
 
     def body(c):
-        r = fext - amul64(c["x"])
-        normr = jnp.sqrt(ops64.wdot_many(w64, r, r))
+        with jax.named_scope("solve/residual64"):
+            r = fext - amul64(c["x"])
+            normr = jnp.sqrt(ops64.wdot_many(w64, r, r))
         live = c["flag"] == -1
         converged = normr <= tolb
         stalled = normr > 0.5 * c["normr"]

@@ -266,6 +266,6 @@ def test_cli_preflight_flag(tmp_path, capsys):
     args = argparse.Namespace(preflight="warn", tol=None, max_iter=None,
                               precision=None, precond=None,
                               telemetry_out=None, trace_resid=None,
-                              profile_spans=False, cache_dir=None)
+                              cache_dir=None)
     cfg = _load_settings(None, args)
     assert cfg.preflight == "warn"
