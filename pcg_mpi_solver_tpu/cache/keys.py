@@ -35,7 +35,10 @@ from pcg_mpi_solver_tpu.config import PCG_VARIANTS
 #    the partition cache became shard-addressed (glue + per-part
 #    entries, cache/shards.py); monolithic entries pickled by older code
 #    lack the new fields and must re-key rather than deserialize.
-CACHE_SCHEMA = 3
+# 4: the hybrid CombineMaps gained the transition-row segment, the
+#    node-minor (P, KD, N) layout and the hpos add-back map; a cached
+#    hybrid partition holding the old maps must not reach the new matvec.
+CACHE_SCHEMA = 4
 
 # Monkeypatchable in tests to simulate a package-version bump without
 # editing the package.

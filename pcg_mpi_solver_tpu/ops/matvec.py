@@ -377,6 +377,34 @@ class Ops:
         return self._scatter(data, flat)
 
     # -- the matvec -----------------------------------------------------
+    def _element_rows(self, data: dict, x: jnp.ndarray) -> list:
+        """Node-ELL layout: per type block, the element values of K.x as
+        (P, nn*N, 3[, R]) node rows (row a*N+n, the slot order of
+        PartitionedModel.ell), traced in ``mv/gather`` and
+        ``mv/contract``."""
+        blocked = x.ndim == 3
+        rows = []
+        for blk in data["blocks"]:
+            with jax.named_scope("mv/gather"):
+                sgn = (blk["sign_nc"][..., None] if blocked
+                       else blk["sign_nc"])
+                u3 = self._gather_u3(x, blk)         # (P, a, n, c[, r])
+                u3 = jnp.where(sgn, -u3, u3)
+            with jax.named_scope("mv/contract"):
+                ck = blk["ck"][:, None, :, None]
+                if blocked:
+                    v = jnp.einsum("bdac,pancr->pbndr", blk["Ke4"],
+                                   ck[..., None] * u3,
+                                   precision=self.precision)
+                else:
+                    v = jnp.einsum("bdac,panc->pbnd", blk["Ke4"],
+                                   ck * u3,
+                                   precision=self.precision)
+                v = jnp.where(sgn, -v, v)            # (P, b, n, d)
+                Pn, nn, N = v.shape[:3]
+                rows.append(v.reshape((Pn, nn * N, 3) + x.shape[2:]))
+        return rows
+
     def matvec_local(self, data: dict, x: jnp.ndarray) -> jnp.ndarray:
         """Part-local K.x (no cross-part assembly).  x: (P, n_loc), or
         (P, n_loc, nrhs) for a RHS block — then every per-type matmul
@@ -389,26 +417,7 @@ class Ops:
         # included), so a profiler trace splits the matvec's time by stage.
         blocked = x.ndim == 3
         if self.use_node_ell:
-            rows = []
-            for blk in data["blocks"]:
-                with jax.named_scope("mv/gather"):
-                    sgn = (blk["sign_nc"][..., None] if blocked
-                           else blk["sign_nc"])
-                    u3 = self._gather_u3(x, blk)         # (P, a, n, c[, r])
-                    u3 = jnp.where(sgn, -u3, u3)
-                with jax.named_scope("mv/contract"):
-                    ck = blk["ck"][:, None, :, None]
-                    if blocked:
-                        v = jnp.einsum("bdac,pancr->pbndr", blk["Ke4"],
-                                       ck[..., None] * u3,
-                                       precision=self.precision)
-                    else:
-                        v = jnp.einsum("bdac,panc->pbnd", blk["Ke4"],
-                                       ck * u3,
-                                       precision=self.precision)
-                    v = jnp.where(sgn, -v, v)            # (P, b, n, d)
-                    Pn, nn, N = v.shape[:3]
-                    rows.append(v.reshape((Pn, nn * N, 3) + x.shape[2:]))
+            rows = self._element_rows(data, x)
         else:
             per_block_v = []
             for blk in data["blocks"]:

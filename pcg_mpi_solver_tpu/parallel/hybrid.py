@@ -16,11 +16,13 @@ nodes.  This backend:
 - gathers each level's NODE lattice once per matvec (one (n,3)-row gather
   per level — ~8x less gather traffic than per-element corner gathers),
   runs the same slice-gather -> MXU einsum -> padded-translate-scatter
-  stencil as the structured backend (parallel/structured.py), and
-  row-scatters the result back into the local dof vector;
-- keeps ONLY transition cells on the general node-ELL gather path
-  (ops/matvec.py) — they are excluded from the type blocks via
+  stencil as the structured backend (parallel/structured.py);
+- keeps ONLY transition cells in the general type blocks (ops/matvec.py)
+  — they are excluded from the type blocks via
   ``partition_model(block_filter=...)``;
+- accumulates the level lattices' live slots and the transition
+  elements' node rows into the local dof vector through ONE
+  partition-composed gather combine (CombineMaps), with no row scatter;
 - shares everything else (interface psum assembly, weighted dots, PCG,
   exports) with the general backend through the same Ops protocol.
 
@@ -81,27 +83,51 @@ class LevelGrid:
 
 @dataclasses.dataclass
 class CombineMaps:
-    """Slot->node gather-combine maps (the scatter-free level combine).
+    """Slot->node gather-combine maps (the scatter-free node combine).
 
     A 2026-07-30 chip session measured the duplicate-row scatter at
     88.7 ns/row against 5.9 ns/row gathers — so the combine is
-    transposed: all levels' lattice slots
-    are sorted by target node at PARTITION time and composed into direct
-    per-node source-slot indices.  At solve time the element->node
-    accumulation (reference pcg_solver.py:300's bincount) becomes KD
-    row gathers (+ a small scatter for the rare heavy nodes), never a
-    7M-row scatter.
+    transposed: every element->node contribution row (level-grid lattice
+    slots and transition-element rows alike) is sorted by target node at
+    PARTITION time and composed into direct per-node source-slot indices.
+    At solve time the element->node accumulation (reference
+    pcg_solver.py:300's bincount) is gathers only: KD dense row gathers
+    for every node, KE row gathers for the H heavy nodes, and one gather
+    that adds each heavy node's sum back by its ``hpos``.
 
     Slot numbering: levels in list order, each level flat over
-    (block, lattice pos) exactly as its ``nidx`` — runtime row arrays are
-    concatenated in the same order, with ONE trailing zero row at index
-    ``n_slots`` serving as the universal pad target.
+    (block, lattice pos) exactly as its ``nidx``; then, when the
+    transition blocks join the combine, their (node slot, element) rows
+    in block order as ``Ops.matvec_local`` builds them — runtime row
+    arrays are concatenated in the same order, with ONE trailing zero row
+    at index ``n_slots`` serving as the universal pad target.  A lattice
+    slot that touches no ck != 0 cell of its block only ever carries an
+    exact zero and is left out of the maps (``n_dropped``).
     """
 
-    n_slots: int                # total slots across levels (zero row = pad)
-    gidx: np.ndarray            # (P, n_node_loc, KD) int32 slot ids
-    hnode: np.ndarray           # (P, H) int32 heavy node ids (pad=n_node_loc)
-    hgidx: np.ndarray           # (P, H, KE) int32 slot ids
+    n_slots: int                # level slots + transition rows (zero row = pad)
+    gidx: np.ndarray            # (P, KD, n_node_loc) int32 slot ids
+    hgidx: np.ndarray           # (P, KE, H) int32 slot ids of the heavy rest
+    hpos: np.ndarray            # (P, n_node_loc) int32 heavy row (H = none)
+    n_dropped: int              # lattice slots of real nodes left out, all parts
+
+    @property
+    def kd(self) -> int:
+        return int(self.gidx.shape[1])
+
+    @property
+    def rows_per_matvec(self) -> int:
+        """Rows one combine gathers per part: KD*N dense, KE*H heavy,
+        and N for the heavy add-back (none without heavy nodes)."""
+        KE, H = self.hgidx.shape[1:]
+        N = self.gidx.shape[2]
+        return self.kd * N + (KE * H + N if H else 0)
+
+    def gauges(self) -> Dict[str, int]:
+        return {"hybrid.combine_rows_per_matvec": self.rows_per_matvec,
+                "hybrid.combine_kd": self.kd,
+                "hybrid.combine_heavy_nodes": int(self.hgidx.shape[2]),
+                "hybrid.combine_slots_dropped": int(self.n_dropped)}
 
 
 @dataclasses.dataclass
@@ -341,7 +367,12 @@ def partition_hybrid(model: ModelData, n_parts: int,
         brick_diag=np.asarray(lib["diagKe"], np.float64),
         brick_Se=(np.asarray(lib["Se"], np.float64)
                   if lib.get("Se") is not None else None),
-        combine=build_combine_maps(levels, pm.n_node_loc, P),
+        # transition rows join the combine exactly when the ops build
+        # them as node rows (the node-ELL layout, Ops.use_node_ell)
+        combine=build_combine_maps(
+            levels, pm.n_node_loc, P,
+            [tb.node for tb in pm.type_blocks] if pm.ell is not None
+            else []),
     )
 
 
@@ -359,13 +390,44 @@ def partition_env_knobs() -> Dict[str, object]:
     }
 
 
-def combine_kd() -> int:
+def combine_kd() -> Optional[int]:
     """Dense width of the gather-combine (slots gathered for EVERY node
-    before falling to the heavy-node residual): PCG_TPU_HYBRID_KD."""
-    kd = int(os.environ.get("PCG_TPU_HYBRID_KD", "2"))
+    before falling to the heavy-node residual): PCG_TPU_HYBRID_KD, or
+    None when unset — then ``build_combine_maps`` picks the width that
+    gathers the fewest rows for the partition at hand."""
+    raw = os.environ.get("PCG_TPU_HYBRID_KD", "")
+    if not raw:
+        return None
+    kd = int(raw)
     if kd < 1:
         raise ValueError(f"PCG_TPU_HYBRID_KD must be >= 1, got {kd}")
     return kd
+
+
+def _live_slots(ck: np.ndarray) -> np.ndarray:
+    """(P, nb, bx, by, bz) cell scales -> (P, nb, lattice slots) bool:
+    the lattice points that are a corner of at least one ck != 0 cell of
+    their block.  Every other slot's stencil, diagonal, node-block and
+    averaging output is an exact zero."""
+    nz = ck != 0
+    P, nb, bx, by, bz = nz.shape
+    live = np.zeros((P, nb, bx + 1, by + 1, bz + 1), dtype=bool)
+    for dx, dy, dz in _CORNERS:
+        live[:, :, dx:dx + bx, dy:dy + by, dz:dz + bz] |= nz
+    return live.reshape(P, nb, -1)
+
+
+def _pick_kd(lens: List[np.ndarray], N: int) -> int:
+    """The dense width that gathers the fewest rows per combine,
+    ``KD*N + KE*H + N`` (``KD*N`` when no node is heavy), over the
+    parts' per-node slot counts ``lens``."""
+    lmax = max(int(ln.max(initial=0)) for ln in lens)
+
+    def rows(kd):
+        H = max(int((ln > kd).sum()) for ln in lens)
+        return kd * N + ((lmax - kd) * H + N if H else 0)
+
+    return min(range(1, max(lmax, 1) + 1), key=rows)
 
 
 def hybrid_combine_mode() -> str:
@@ -379,53 +441,50 @@ def hybrid_combine_mode() -> str:
     return mode
 
 
-def build_combine_maps(levels: List[LevelGrid], n_node_loc: int,
-                       P: int) -> Optional[CombineMaps]:
-    """Sort every level's lattice slots by target node and compose direct
-    per-node source indices (see CombineMaps).  All host-side numpy, one
-    argsort over the concatenated slot count per part."""
+def build_combine_maps(levels: List[LevelGrid], n_node_loc: int, P: int,
+                       trans_nodes: List[np.ndarray]
+                       ) -> Optional[CombineMaps]:
+    """Sort every live lattice slot and every transition row by target
+    node and compose direct per-node source indices (see CombineMaps).
+    ``trans_nodes``: the transition type blocks' (P, nn, N) local node
+    ids in block order, or empty when their rows stay on the node-ELL
+    path.  All host-side numpy, one argsort per part."""
     if not levels:
         return None
-    KD = combine_kd()
-    nslot = [lv.nb * (lv.bx + 1) * (lv.by + 1) * (lv.bz + 1)
-             for lv in levels]
-    Ns = int(np.sum(nslot))
-    # slot id = position in the level-order concatenation = plain range
-    slots_all = np.arange(Ns, dtype=np.int64)
-    gidx = np.full((P, n_node_loc, KD), Ns, dtype=np.int64)
-    starts_l, lens_l, ss_l = [], [], []
-    ke_max = 0
-    h_max = 0
+    Ns = sum(a[0].size for a in [lv.nidx for lv in levels] + trans_nodes)
+    live = [_live_slots(lv.ck) for lv in levels]
+    sorted_l, lens_l = [], []
+    n_dropped = 0
     for p in range(P):
-        tgt = np.concatenate([lv.nidx[p].reshape(-1) for lv in levels]) \
-            .astype(np.int64)
-        real = tgt < n_node_loc
-        order = np.argsort(tgt[real], kind="stable")
-        t_s = tgt[real][order]
-        s_s = slots_all[real][order]
-        starts = np.searchsorted(t_s, np.arange(n_node_loc, dtype=np.int64))
-        lens = np.diff(np.append(starts, len(t_s)))
+        lvl = np.concatenate([lv.nidx[p].reshape(-1) for lv in levels])
+        keep = np.concatenate([m[p].reshape(-1) for m in live])
+        n_dropped += int(((lvl < n_node_loc) & ~keep).sum())
+        tgt = np.concatenate(
+            [np.where(keep, lvl, n_node_loc)]
+            + [tn[p].reshape(-1) for tn in trans_nodes]).astype(np.int64)
+        slots = np.flatnonzero(tgt < n_node_loc)
+        s_s = slots[np.argsort(tgt[slots], kind="stable")]
+        lens = np.bincount(tgt[slots], minlength=n_node_loc)
+        sorted_l.append((s_s, np.cumsum(lens) - lens))
+        lens_l.append(lens)
+    KD = combine_kd() or _pick_kd(lens_l, n_node_loc)
+    KE = max(max(int(ln.max(initial=0)) for ln in lens_l) - KD, 0)
+    H = max(int((ln > KD).sum()) for ln in lens_l)
+    gidx = np.full((P, KD, n_node_loc), Ns, dtype=np.int32)
+    hgidx = np.full((P, KE, H), Ns, dtype=np.int32)
+    hpos = np.full((P, n_node_loc), H, dtype=np.int32)
+    for p, ((s_s, starts), lens) in enumerate(zip(sorted_l, lens_l)):
         for k in range(KD):
             sel = lens > k
-            gidx[p, sel, k] = s_s[starts[sel] + k]
-        starts_l.append(starts)
-        lens_l.append(lens)
-        ss_l.append(s_s)
-        ke_max = max(ke_max, int(lens.max(initial=0)) - KD)
-        h_max = max(h_max, int((lens > KD).sum()))
-    KE = max(ke_max, 0)
-    hnode = np.full((P, h_max), n_node_loc, dtype=np.int64)
-    hgidx = np.full((P, h_max, KE), Ns, dtype=np.int64)
-    for p in range(P):
-        heavy = np.where(lens_l[p] > KD)[0]
-        hnode[p, :len(heavy)] = heavy
+            gidx[p, k, sel] = s_s[starts[sel] + k]
+        heavy = np.flatnonzero(lens > KD)
+        hpos[p, heavy] = np.arange(len(heavy))
         for k in range(KE):
-            sel = lens_l[p][heavy] > KD + k
-            hgidx[p, :len(heavy), k][sel] = \
-                ss_l[p][starts_l[p][heavy[sel]] + KD + k]
-    return CombineMaps(n_slots=Ns, gidx=gidx.astype(np.int32),
-                       hnode=hnode.astype(np.int32),
-                       hgidx=hgidx.astype(np.int32))
+            sel = lens[heavy] > KD + k
+            hgidx[p, k, np.flatnonzero(sel)] = \
+                s_s[starts[heavy[sel]] + KD + k]
+    return CombineMaps(n_slots=Ns, gidx=gidx, hgidx=hgidx, hpos=hpos,
+                       n_dropped=n_dropped)
 
 
 def device_data_hybrid(hp: HybridPartition, dtype=jnp.float64) -> dict:
@@ -442,8 +501,8 @@ def device_data_hybrid(hp: HybridPartition, dtype=jnp.float64) -> dict:
     if hp.combine is not None:
         d["combine"] = {
             "gidx": jnp.asarray(hp.combine.gidx),
-            "hnode": jnp.asarray(hp.combine.hnode),
             "hgidx": jnp.asarray(hp.combine.hgidx),
+            "hpos": jnp.asarray(hp.combine.hpos),
         }
     return d
 
@@ -547,32 +606,59 @@ class HybridOps(Ops):
         )(y3, lv["nidx"].reshape(Pn, -1), rows)
         return y3.reshape(Pn, self.n_loc)
 
-    def _combined_gather_add(self, y, rows_levels, data, Pn):
-        """Scatter-free combine: add every level's lattice-slot rows into
-        y (P, n_loc) through the partition-composed slot->node maps
-        (CombineMaps; rationale in the CombineMaps docstring).
-        ``rows_levels``: per-level (P, n_slots_l, w) arrays in
-        level order; w is the row width (3 for matvec/diag)."""
+    def _trans_in_combine(self, data) -> bool:
+        """Whether the transition blocks' rows are slots of the combine
+        maps (partition_hybrid builds them in exactly for the node-ELL
+        layout), so the node-ELL sum is off the gather-combine path."""
+        return self._use_gather(data) and self.use_node_ell
+
+    @staticmethod
+    def _take_rows(src, idx):
+        """src (P, R, w), idx (P, ...) -> (P, ..., w): one flat row
+        gather with the part folded into the row index, as Ops' gather
+        primitives do."""
+        Pn, R, w = src.shape
+        offs = (jnp.arange(Pn, dtype=jnp.int32) * R).reshape(
+            (Pn,) + (1,) * (idx.ndim - 1))
+        g = jnp.take(src.reshape(Pn * R, w), (idx + offs).reshape(-1),
+                     axis=0, mode="clip")
+        return g.reshape(idx.shape + (w,))
+
+    def _combined_gather_add(self, y, rows_levels, data, Pn,
+                             rows_trans=None):
+        """Scatter-free combine: add every level's lattice-slot rows and,
+        when they are in the maps, the transition rows into y (P, n_loc)
+        through the partition-composed slot->node maps (CombineMaps;
+        rationale in the CombineMaps docstring).  ``rows_levels``:
+        per-level (P, n_slots_l, w) arrays in level order; w is the row
+        width (3 for matvec/diag).  ``rows_trans``: the transition
+        blocks' (P, nn*N, w) rows in block order, or None for a consumer
+        that has already added the transitions itself (zero rows)."""
         cm = data["combine"]
         w = rows_levels[0].shape[-1]
-        rows = jnp.concatenate(rows_levels, axis=1)
-        rows = jnp.concatenate(
-            [rows, jnp.zeros((Pn, 1, w), rows.dtype)], axis=1)  # pad row
-        take = jax.vmap(lambda rp, gi: jnp.take(rp, gi, axis=0))
+        dt = rows_levels[0].dtype
+        parts = list(rows_levels)
+        if self._trans_in_combine(data):
+            parts += rows_trans if rows_trans is not None else [
+                jnp.zeros((Pn, b["node"].shape[1] * b["node"].shape[2], w),
+                          dt) for b in data["blocks"]]
+        rows = jnp.concatenate(parts + [jnp.zeros((Pn, 1, w), dt)],
+                               axis=1)                      # pad row
         acc = None
-        for k in range(cm["gidx"].shape[-1]):
-            t = take(rows, cm["gidx"][:, :, k])
+        for k in range(cm["gidx"].shape[1]):
+            t = self._take_rows(rows, cm["gidx"][:, k])
             acc = t if acc is None else acc + t
-        y3 = y.reshape(Pn, self.n_node_loc, w) + acc
-        if cm["hnode"].shape[1]:
+        if cm["hgidx"].shape[2]:
             hacc = None
-            for k in range(cm["hgidx"].shape[-1]):
-                t = take(rows, cm["hgidx"][:, :, k])
+            for k in range(cm["hgidx"].shape[1]):
+                t = self._take_rows(rows, cm["hgidx"][:, k])
                 hacc = t if hacc is None else hacc + t
-            y3 = jax.vmap(
-                lambda yp, idx, r: yp.at[idx].add(r, mode="drop")
-            )(y3, cm["hnode"], hacc)
-        return y3.reshape(Pn, -1)
+            # each heavy node's rest comes back by a gather: a zero row
+            # after the heavy sums is every other node's hpos
+            hacc = jnp.concatenate([hacc, jnp.zeros((Pn, 1, w), dt)],
+                                   axis=1)
+            acc = acc + self._take_rows(hacc, cm["hpos"])
+        return (y.reshape(Pn, self.n_node_loc, w) + acc).reshape(Pn, -1)
 
     def _use_gather(self, data) -> bool:
         """ONE eligibility rule for the gather-combine across every
@@ -634,10 +720,14 @@ class HybridOps(Ops):
 
     # -- operator protocol ---------------------------------------------
     def matvec_local(self, data, x):
-        """Transition blocks through Ops.matvec_local, then each level
-        grid, in the same ``mv/gather`` (node rows to level lattices),
-        ``mv/contract`` (the brick stencil product) and ``mv/scatter``
-        (pad-add corner terms, the level combine, the springs) stages."""
+        """Transition blocks, then each level grid, in the same
+        ``mv/gather`` (node rows to element and level lattices),
+        ``mv/contract`` (the element and brick stencil products) and
+        ``mv/scatter`` (pad-add corner terms, the node combine, the
+        springs) stages.  Under the gather combine the transition rows
+        and the live lattice slots reach their nodes through ONE
+        composed combine; otherwise the transitions go through
+        Ops.matvec_local's node-ELL sum."""
         if x.ndim == 3:
             # RHS-block axis (Ops.matvec contract): the level-grid
             # gather/stencil/scatter machinery runs on flat vectors, so
@@ -646,7 +736,9 @@ class HybridOps(Ops):
             return jax.vmap(lambda xc: self.matvec_local(data, xc),
                             in_axes=-1, out_axes=-1)(x)
         Pn = x.shape[0]
-        if data["blocks"]:
+        rows_trans = (self._element_rows(data, x)
+                      if self._trans_in_combine(data) else None)
+        if data["blocks"] and rows_trans is None:
             y = Ops.matvec_local(self, data, x)
         else:
             with jax.named_scope("mv/scatter"):
@@ -670,7 +762,8 @@ class HybridOps(Ops):
                         y = self._level_scatter_add(y, yg, lv, dims, Pn)
             if use_gather:
                 with jax.named_scope("mv/scatter"):
-                    y = self._combined_gather_add(y, rows_levels, data, Pn)
+                    y = self._combined_gather_add(y, rows_levels, data, Pn,
+                                                  rows_trans)
         return y
 
     def diag_local(self, data):

@@ -620,6 +620,10 @@ class Solver:
         self._rec.gauge("precision_mode", solver_cfg.precision_mode)
         self._rec.gauge("pcg_variant", solver_cfg.pcg_variant)
         self._rec.gauge("precond", solver_cfg.precond)
+        cmaps = getattr(self.pm, "combine", None)
+        if cmaps is not None and getattr(self.ops, "combine", "") == "gather":
+            for k, v in cmaps.gauges().items():
+                self._rec.gauge(k, v)
         # mixed mode: the Krylov iterations (vectors AND dot reductions)
         # run on the f32 ops, so that is the ops object to size from;
         # the variant sets the per-iteration collective count (fused =

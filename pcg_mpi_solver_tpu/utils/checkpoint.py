@@ -200,7 +200,7 @@ def _combine_kd(solver) -> int | str:
     if getattr(solver.ops, "combine", "n/a") != "gather":
         return "n/a"
     cm = getattr(getattr(solver, "pm", None), "combine", None)
-    return int(cm.gidx.shape[-1]) if cm is not None else "n/a"
+    return cm.kd if cm is not None else "n/a"
 
 
 def _effective_kernel(solver) -> str:

@@ -305,22 +305,89 @@ def test_combine_gather_matches_scatter(pair):
 
 
 def test_combine_maps_cover_every_slot_once(pair):
-    """Every real (non-pad-target) lattice slot appears in exactly one
-    gidx/hgidx cell; pad cells all point at the zero row."""
+    """Every lattice slot that touches a ck != 0 cell of its block and
+    every real transition row appears in exactly one gidx/hgidx cell,
+    under the node it belongs to; every dropped slot of a real node
+    touches no such cell; pad cells all point at the zero row."""
+    from pcg_mpi_solver_tpu.parallel.hybrid import _CORNERS
+
     _, (ops_h, data_h), pm_g, hp = pair
     cm = hp.combine
     nn = hp.pm.n_node_loc
+    assert cm.n_slots == sum(lv.nidx[0].size for lv in hp.levels) + sum(
+        tb.node.shape[1] * tb.node.shape[2] for tb in hp.pm.type_blocks)
+    n_dropped = 0
     for p in range(hp.pm.n_parts):
-        tgt = np.concatenate(
-            [lv.nidx[p].reshape(-1) for lv in hp.levels]).astype(np.int64)
+        touch = []
+        for lv in hp.levels:
+            ck = lv.ck[p] != 0                         # (nb, bx, by, bz)
+            nb, bx, by, bz = ck.shape
+            cnt = np.zeros((nb, bx + 1, by + 1, bz + 1), np.int64)
+            for dx, dy, dz in _CORNERS:
+                cnt[:, dx:dx + bx, dy:dy + by, dz:dz + bz] += ck
+            touch.append(cnt.reshape(-1))
+        touch = np.concatenate(touch)
+        lvl = np.concatenate([lv.nidx[p].reshape(-1) for lv in hp.levels])
+        trans = np.concatenate(
+            [tb.node[p].reshape(-1) for tb in hp.pm.type_blocks]
+            or [np.zeros(0, np.int64)])
+        tgt = np.concatenate([lvl, trans]).astype(np.int64)
+        live = np.concatenate([(lvl < nn) & (touch > 0), trans < nn])
+        # a ck != 0 brick's corners are all real local nodes
+        assert (lvl[touch > 0] < nn).all()
+        # the node each map cell adds into: gidx by its column, hgidx by
+        # the heavy row that hpos points that node at
+        H = cm.hgidx.shape[2]
+        heavy_node = np.full(H, nn)
+        is_heavy = cm.hpos[p] < H
+        heavy_node[cm.hpos[p][is_heavy]] = np.flatnonzero(is_heavy)
+        slot_node = np.concatenate([
+            np.broadcast_to(np.arange(nn), cm.gidx[p].shape).reshape(-1),
+            np.broadcast_to(heavy_node, cm.hgidx[p].shape).reshape(-1)])
         used = np.concatenate([cm.gidx[p].reshape(-1),
                                cm.hgidx[p].reshape(-1)])
-        used = used[used < cm.n_slots]
-        # exactly the slots whose target is a real node, each once
-        expect = np.where(tgt < nn)[0]
-        np.testing.assert_array_equal(np.sort(used), expect)
-        # heavy node ids are real or pad
-        assert (cm.hnode[p] <= nn).all()
+        real = used < cm.n_slots
+        # exactly the live slots and real transition rows, each once
+        np.testing.assert_array_equal(np.sort(used[real]),
+                                      np.flatnonzero(live))
+        # ... each under its own node
+        np.testing.assert_array_equal(slot_node[real], tgt[used[real]])
+        # every lattice slot of a real node left out touches no ck != 0
+        # cell: its rows only ever carry exact zeros
+        in_map = np.zeros(len(tgt), bool)
+        in_map[used[real]] = True
+        dropped = (lvl < nn) & ~in_map[:len(lvl)]
+        assert (touch[dropped] == 0).all()
+        n_dropped += int(dropped.sum())
+        # heavy rows are real or the zero row after them
+        assert (cm.hpos[p] <= cm.hgidx.shape[2]).all()
+    assert n_dropped == cm.n_dropped
+    assert cm.n_dropped > 0
+
+
+@pytest.mark.parametrize("nrhs", [0, 2], ids=["scalar", "nrhs2"])
+def test_gather_combine_matvec_has_no_scatter(pair, nrhs):
+    """The default combine accumulates element->node with gathers only
+    (no scatter-add anywhere in matvec_local, blocked or not), and it
+    gathers fewer rows than the node-ELL sum over every node plus the
+    all-slot level combine it replaces (KD = 2, heavy rows scattered)."""
+    _, (ops_h, data_h), pm_g, hp = pair
+    assert ops_h.combine == "gather" and ops_h._trans_in_combine(data_h)
+    shape = (hp.pm.n_parts, hp.pm.n_loc) + ((nrhs,) if nrhs else ())
+    x = jnp.zeros(shape)
+    jaxpr = str(jax.make_jaxpr(ops_h.matvec_local)(data_h, x))
+    assert "gather" in jaxpr and "scatter" not in jaxpr
+    cm, nn = hp.combine, hp.pm.n_node_loc
+    lens = [np.bincount(np.concatenate(
+        [lv.nidx[p].reshape(-1) for lv in hp.levels]), minlength=nn + 1)[:nn]
+        for p in range(hp.pm.n_parts)]
+    H = max(int((ln > 2).sum()) for ln in lens)
+    KE = max(int(ln.max()) for ln in lens) - 2
+    old_rows = hp.pm.ell.shape[-1] * nn + 2 * nn + KE * H + H
+    assert 0 < cm.rows_per_matvec < old_rows
+    gauges = cm.gauges()
+    assert gauges["hybrid.combine_rows_per_matvec"] == cm.rows_per_matvec
+    assert gauges["hybrid.combine_kd"] == cm.gidx.shape[1]
 
 
 def test_auto_backend_prefers_hybrid(model, monkeypatch):

@@ -116,3 +116,31 @@ def test_structured_f32_matvec_program_compiles_for_v5e(one_chip):
     # the f32 operands of one matvec fit one chip's 16 GB many times over
     assert mem is None or mem.argument_size_in_bytes < 2 * 2**30
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_hybrid_f32_matvec_is_scatter_free_for_v5e(one_chip):
+    """The hybrid f32 matvec at the benchmark octree's rehearsal size
+    (n0 = 2, as benchmarks/configs/octree_graded.json pins it): the
+    element->node accumulation of the level slots and the transition
+    rows compiles to gathers only — no scatter survives the TPU
+    compiler."""
+    import jax
+    import jax.numpy as jnp
+
+    from pcg_mpi_solver_tpu.models.octree import make_octree_model
+    from pcg_mpi_solver_tpu.parallel.hybrid import (
+        HybridOps, device_data_hybrid, partition_hybrid)
+
+    model = make_octree_model(nx0=2, ny0=2, nz0=2, h0=1.0, max_level=4,
+                              E=3e10, nu=0.2, rho=1.0, load="traction",
+                              load_value=1.0, n_incl=6, incl_stiff=10.0,
+                              seed=2)
+    hp = partition_hybrid(model, 1)
+    ops = HybridOps.from_hybrid(hp, dot_dtype=jnp.float32)
+    assert ops.combine == "gather"
+    data = jax.tree.map(
+        lambda a: _sds(a.shape, one_chip, a.dtype),
+        device_data_hybrid(hp, jnp.float32))
+    text = jax.jit(ops.matvec_local).lower(
+        data, _sds((1, hp.pm.n_loc), one_chip)).compile().as_text()
+    assert "gather(" in text and "scatter(" not in text
