@@ -129,7 +129,10 @@ def precond_cycle_cost(precond: str, mg_degree: int = 2):
 
 def device_data(pm: PartitionedModel, dtype=jnp.float64,
                 flat: Optional[bool] = None, blocks: bool = True) -> dict:
-    """Pack a PartitionedModel into the device pytree the ops consume.
+    """Pack a PartitionedModel into the pytree the ops consume, as host
+    (numpy) arrays: ``parallel.distributed.put_tree`` then places each
+    part's rows on the device that owns them, and nothing is staged on
+    one device first.
 
     All leaves have a leading parts axis P (shard it over the mesh), except
     the small per-type constant matrices (Ke etc.), which are replicated.
@@ -145,27 +148,26 @@ def device_data(pm: PartitionedModel, dtype=jnp.float64,
 
     def _blk(tb):
         b = {
-            "Ke": jnp.asarray(tb.Ke, dtype),
-            "diag_Ke": jnp.asarray(tb.diag_Ke, dtype),
-            "Se": jnp.asarray(tb.Se, dtype) if tb.Se is not None else None,
-            "sign": jnp.asarray(tb.sign),
-            "node": jnp.asarray(tb.node, jnp.int32),
-            "ck": jnp.asarray(tb.ck, dtype),
-            "ce": jnp.asarray(tb.ce, dtype),
+            "Ke": np.asarray(tb.Ke, dtype),
+            "diag_Ke": np.asarray(tb.diag_Ke, dtype),
+            "Se": np.asarray(tb.Se, dtype) if tb.Se is not None else None,
+            "sign": np.asarray(tb.sign),
+            "node": np.asarray(tb.node, np.int32),
+            "ck": np.asarray(tb.ck, dtype),
+            "ce": np.asarray(tb.ce, dtype),
         }
         if flat:
-            b["dof"] = jnp.asarray(tb.dof, jnp.int32)
+            b["dof"] = np.asarray(tb.dof, np.int32)
         if pm.ell is not None:
             # node-component layouts for the node-ELL fast path: the element
             # matmul runs directly on gathered (node, elem, comp) rows, so
             # no runtime relayout of the (..., 3)-minor arrays is needed.
             nn = tb.d // 3
-            b["Ke4"] = jnp.asarray(tb.Ke.reshape(nn, 3, nn, 3), dtype)
-            b["diag_Ke4"] = jnp.asarray(tb.diag_Ke.reshape(nn, 3), dtype)
-            b["sign_nc"] = jnp.asarray(
-                np.ascontiguousarray(
-                    tb.sign.reshape(tb.sign.shape[0], nn, 3, -1)
-                    .transpose(0, 1, 3, 2)))
+            b["Ke4"] = np.asarray(tb.Ke.reshape(nn, 3, nn, 3), dtype)
+            b["diag_Ke4"] = np.asarray(tb.diag_Ke.reshape(nn, 3), dtype)
+            b["sign_nc"] = np.ascontiguousarray(
+                tb.sign.reshape(tb.sign.shape[0], nn, 3, -1)
+                .transpose(0, 1, 3, 2))
         return b
 
     d = {
@@ -173,27 +175,39 @@ def device_data(pm: PartitionedModel, dtype=jnp.float64,
         # the ELL scatter map is only consumed by the blocks path
         # (_scatter_rows); without blocks it would be ~1e8 int32 of dead
         # HBM at flagship scale
-        "ell": (jnp.asarray(pm.ell, jnp.int32)
+        "ell": (np.asarray(pm.ell, np.int32)
                 if pm.ell is not None and blocks else None),
-        "iface_local": jnp.asarray(pm.iface_local, jnp.int32),
-        "iface_slot": jnp.asarray(pm.iface_slot, jnp.int32),
-        "niface_local": jnp.asarray(pm.niface_local, jnp.int32),
-        "niface_slot": jnp.asarray(pm.niface_slot, jnp.int32),
-        "weight": jnp.asarray(pm.weight, dtype),
-        "node_weight": jnp.asarray(pm.node_weight, dtype),
-        "eff": jnp.asarray(pm.eff, dtype),
-        "F": jnp.asarray(pm.F, dtype),
-        "Ud": jnp.asarray(pm.Ud, dtype),
+        "iface_local": np.asarray(pm.iface_local, np.int32),
+        "iface_slot": np.asarray(pm.iface_slot, np.int32),
+        "niface_local": np.asarray(pm.niface_local, np.int32),
+        "niface_slot": np.asarray(pm.niface_slot, np.int32),
+        "weight": np.asarray(pm.weight, dtype),
+        "node_weight": np.asarray(pm.node_weight, dtype),
+        "eff": np.asarray(pm.eff, dtype),
+        "F": np.asarray(pm.F, dtype),
+        "Ud": np.asarray(pm.Ud, dtype),
     }
     if flat:
-        d["scat_perm"] = jnp.asarray(pm.scat_perm, jnp.int32)
-        d["scat_ids"] = jnp.asarray(pm.scat_ids, jnp.int32)
+        d["scat_perm"] = np.asarray(pm.scat_perm, np.int32)
+        d["scat_ids"] = np.asarray(pm.scat_ids, np.int32)
     if pm.spr_a is not None:
         # cohesive interface springs (PartitionedModel spr_*)
-        d["spr_a"] = jnp.asarray(pm.spr_a, jnp.int32)
-        d["spr_b"] = jnp.asarray(pm.spr_b, jnp.int32)
-        d["spr_k"] = jnp.asarray(pm.spr_k, dtype)
+        d["spr_a"] = np.asarray(pm.spr_a, np.int32)
+        d["spr_b"] = np.asarray(pm.spr_b, np.int32)
+        d["spr_k"] = np.asarray(pm.spr_k, dtype)
     return d
+
+
+def with_f32_shadow(data: dict) -> dict:
+    """The mixed-precision tree ``{"f64": data, "f32": shadow}``: the
+    shadow casts the float leaves to float32 on the host and holds every
+    other leaf (index and bool arrays) as the same object, which
+    ``put_tree`` then uploads once for both trees."""
+    return {"f64": data,
+            "f32": jax.tree.map(
+                lambda x: (x.astype(np.float32)
+                           if np.issubdtype(x.dtype, np.floating) else x),
+                data)}
 
 
 #: The named stages every backend's matvec is traced in (``jax.named_scope``
@@ -258,26 +272,27 @@ class Ops:
         global shared-id vector, ONE psum, gather back.  y: (P, n) or, with
         a trailing RHS-block axis, (P, n, R) — the psum payload widens to
         (n_glob, R) but the collective COUNT stays one either way (the
-        batched-solve contract, tools/check_collectives.py)."""
-        if y.ndim == 3:
-            R = y.shape[-1]
-            vals = jnp.take_along_axis(y, local[:, :, None], axis=1,
-                                       mode="fill", fill_value=0)
-            glob = jnp.zeros((n_glob, R), y.dtype)
+        batched-solve contract, tools/check_collectives.py).
+
+        Traced in three scopes, nested in the caller's (``mv/halo`` in a
+        matvec): ``pack`` (take the part's shared entries, scatter-add
+        them into the shared-id vector), ``psum`` and ``unpack`` (gather
+        the sums back, set them into the part's vector)."""
+        tail = y.shape[2:]                                  # () or (R,)
+        with jax.named_scope("pack"):
+            vals = jnp.take_along_axis(
+                y, local.reshape(local.shape + (1,) * len(tail)), axis=1,
+                mode="fill", fill_value=0)
+            glob = jnp.zeros((n_glob,) + tail, y.dtype)
             glob = glob.at[slot.reshape(-1)].add(
-                vals.reshape(-1, R), mode="drop")
+                vals.reshape((-1,) + tail), mode="drop")
+        with jax.named_scope("psum"):
             glob = self._psum(glob)
+        with jax.named_scope("unpack"):
             new = glob.at[slot].get(mode="fill", fill_value=0)
             return jax.vmap(
                 lambda yp, loc, nv: yp.at[loc].set(nv, mode="drop"))(
                 y, local, new)
-        vals = jnp.take_along_axis(y, local, axis=1, mode="fill", fill_value=0)
-        glob = jnp.zeros((n_glob,), y.dtype)
-        glob = glob.at[slot.reshape(-1)].add(vals.reshape(-1), mode="drop")
-        glob = self._psum(glob)
-        new = glob.at[slot].get(mode="fill", fill_value=0)
-        return jax.vmap(lambda yp, loc, nv: yp.at[loc].set(nv, mode="drop"))(
-            y, local, new)
 
     def iface_assemble(self, data: dict, y: jnp.ndarray) -> jnp.ndarray:
         """Dof-space assembly: (P, n_loc) partial sums -> fully assembled."""
@@ -816,7 +831,8 @@ class Ops:
 # refresh; never the direct/f64 parity path).
 
 def build_bucketed_blocks(pm: PartitionedModel, dtype=jnp.float64):
-    """Stack pm.type_blocks into padded same-shape buckets.
+    """Stack pm.type_blocks into padded same-shape buckets (host arrays,
+    like :func:`device_data`).
 
     Returns a list of dicts {"Ke": (T, d, d), "node": (P, T, nn, Nmax),
     "sign": (P, T, d, Nmax), "ck": (P, T, Nmax)} — parts axis LEADING on
@@ -863,10 +879,8 @@ def build_bucketed_blocks(pm: PartitionedModel, dtype=jnp.float64):
             node[:, t, :tb.n_nodes, :n] = tb.node
             sign[:, t, :tb.d, :n] = tb.sign
             ck[:, t, :n] = tb.ck
-        buckets.append({"Ke": jnp.asarray(Ke, dtype),
-                        "node": jnp.asarray(node),
-                        "sign": jnp.asarray(sign),
-                        "ck": jnp.asarray(ck, dtype)})
+        buckets.append({"Ke": np.asarray(Ke, dtype), "node": node,
+                        "sign": sign, "ck": np.asarray(ck, dtype)})
     return buckets
 
 

@@ -328,21 +328,45 @@ def local_part_range(mesh: jax.sharding.Mesh, n_parts: int):
 
 
 def put_tree(tree, mesh: jax.sharding.Mesh, specs):
-    """put_sharded over a pytree of arrays with a matching pytree of specs
-    (None leaves pass through, as with device_put)."""
-    if jax.process_count() == 1:
-        shardings = jax.tree.map(
-            lambda s: jax.sharding.NamedSharding(mesh, s), specs,
-            is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
-        return jax.device_put(tree, shardings)
+    """put_sharded over a pytree of host arrays with a matching pytree of
+    specs (None leaves pass through, as with device_put).
 
-    def rec(t, s):
+    Each distinct array is placed once: a leaf that sits in several
+    places of the tree (the index arrays the mixed f32 shadow shares with
+    the f64 tree) is one device array in all of them.  Single-process,
+    the leaves go in one batched ``device_put``, each part's rows from
+    the host straight to the device that owns them."""
+    index, leaves, shardings = {}, [], []
+
+    def collect(t, s):
+        if t is None:
+            return
+        if isinstance(t, dict):
+            for k, v in t.items():
+                collect(v, s[k])
+        elif isinstance(t, (list, tuple)):
+            for i, v in enumerate(t):
+                collect(v, s[i])
+        elif id(t) not in index:
+            index[id(t)] = len(leaves)
+            leaves.append(t)
+            shardings.append(s)
+
+    collect(tree, specs)
+    if jax.process_count() == 1:
+        placed = jax.device_put(
+            leaves, [jax.sharding.NamedSharding(mesh, s) for s in shardings])
+    else:
+        placed = [put_sharded(np.asarray(t), mesh, s)
+                  for t, s in zip(leaves, shardings)]
+
+    def rebuild(t):
         if t is None:
             return None
         if isinstance(t, dict):
-            return {k: rec(v, s[k]) for k, v in t.items()}
+            return {k: rebuild(v) for k, v in t.items()}
         if isinstance(t, (list, tuple)):
-            return type(t)(rec(v, s[i]) for i, v in enumerate(t))
-        return put_sharded(np.asarray(t), mesh, s)
+            return type(t)(rebuild(v) for v in t)
+        return placed[index[id(t)]]
 
-    return rec(tree, specs)
+    return rebuild(tree)

@@ -488,22 +488,21 @@ def build_combine_maps(levels: List[LevelGrid], n_node_loc: int, P: int,
 
 
 def device_data_hybrid(hp: HybridPartition, dtype=jnp.float64) -> dict:
+    """``device_data`` plus the level grids and the combine maps, as host
+    arrays (see ``device_data``)."""
     d = device_data(hp.pm, dtype)
     d["levels"] = [{
-        "ck": jnp.asarray(lv.ck, dtype),
-        "ce": jnp.asarray(lv.ce, dtype),
-        "nidx": jnp.asarray(lv.nidx, jnp.int32),
+        "ck": np.asarray(lv.ck, dtype),
+        "ce": np.asarray(lv.ce, dtype),
+        "nidx": np.asarray(lv.nidx, np.int32),
     } for lv in hp.levels]
-    d["brick_Ke"] = jnp.asarray(hp.brick_Ke, dtype)
-    d["brick_diag"] = jnp.asarray(hp.brick_diag, dtype)
+    d["brick_Ke"] = np.asarray(hp.brick_Ke, dtype)
+    d["brick_diag"] = np.asarray(hp.brick_diag, dtype)
     if hp.brick_Se is not None:
-        d["brick_Se"] = jnp.asarray(hp.brick_Se, dtype)
+        d["brick_Se"] = np.asarray(hp.brick_Se, dtype)
     if hp.combine is not None:
-        d["combine"] = {
-            "gidx": jnp.asarray(hp.combine.gidx),
-            "hgidx": jnp.asarray(hp.combine.hgidx),
-            "hpos": jnp.asarray(hp.combine.hpos),
-        }
+        d["combine"] = {"gidx": hp.combine.gidx, "hgidx": hp.combine.hgidx,
+                        "hpos": hp.combine.hpos}
     return d
 
 

@@ -310,6 +310,24 @@ class PartitionedModel:
     part_range: Optional[Tuple[int, int]] = None
 
 
+def balance_gauges(pm: PartitionedModel) -> Dict[str, float]:
+    """How evenly a partition spreads the interface and the work: the most
+    interface entries one part packs into the shared-dof assembly of
+    every matvec (``comm.iface_entries_max``; 0 on one part), and the
+    largest part's dofs and elements over the mean over parts (a hybrid
+    partition's level-grid bricks count with its type blocks' elements)."""
+    dofs = np.asarray(pm.ndof_p, np.float64)
+    elems = np.sum([tb.n_elem for tb in pm.type_blocks]
+                   + [lv.n_cells for lv in getattr(pm, "levels", ())],
+                   axis=0, dtype=np.float64)
+    return {
+        "comm.iface_entries_max": int(
+            (pm.iface_local < pm.n_loc).sum(axis=1).max()),
+        "part.dofs_max_over_mean": float(dofs.max() / dofs.mean()),
+        "part.elems_max_over_mean": float(elems.max() / elems.mean()),
+    }
+
+
 def _pad_to(x: np.ndarray, n: int, fill) -> np.ndarray:
     out = np.full((n,) + x.shape[1:], fill, dtype=x.dtype)
     out[: len(x)] = x

@@ -179,19 +179,21 @@ def partition_structured(model: ModelData, n_parts: int,
 
 
 def device_data_structured(sp: StructuredPartition, dtype=jnp.float64) -> dict:
+    """The slab stencil's data as host arrays (see
+    ``ops.matvec.device_data``)."""
     return {
         "blocks": [{
-            "Ke": jnp.asarray(sp.Ke, dtype),
-            "diag_Ke": jnp.asarray(sp.diag_Ke, dtype),
-            "Se": jnp.asarray(sp.Se, dtype),
-            "ck": jnp.asarray(sp.ck, dtype),
-            "ce": jnp.asarray(sp.ce, dtype),
+            "Ke": np.asarray(sp.Ke, dtype),
+            "diag_Ke": np.asarray(sp.diag_Ke, dtype),
+            "Se": np.asarray(sp.Se, dtype),
+            "ck": np.asarray(sp.ck, dtype),
+            "ce": np.asarray(sp.ce, dtype),
         }],
-        "weight": jnp.asarray(sp.weight, dtype),
-        "node_weight": jnp.asarray(sp.node_weight, dtype),
-        "eff": jnp.asarray(sp.eff, dtype),
-        "F": jnp.asarray(sp.F, dtype),
-        "Ud": jnp.asarray(sp.Ud, dtype),
+        "weight": np.asarray(sp.weight, dtype),
+        "node_weight": np.asarray(sp.node_weight, dtype),
+        "eff": np.asarray(sp.eff, dtype),
+        "F": np.asarray(sp.F, dtype),
+        "Ud": np.asarray(sp.Ud, dtype),
     }
 
 

@@ -26,9 +26,10 @@ from pcg_mpi_solver_tpu.obs.metrics import MetricsRecorder
 from pcg_mpi_solver_tpu.obs.trace import (
     ConvergenceTrace, clamp_trace_len, empty_trace, trace_init, trace_specs,
     unpack_trace)
-from pcg_mpi_solver_tpu.ops.matvec import Ops, device_data
+from pcg_mpi_solver_tpu.ops.matvec import Ops, device_data, with_f32_shadow
 from pcg_mpi_solver_tpu.parallel.mesh import PARTS_AXIS, make_mesh
-from pcg_mpi_solver_tpu.parallel.partition import PartitionedModel, partition_model
+from pcg_mpi_solver_tpu.parallel.partition import (
+    PartitionedModel, balance_gauges, partition_model)
 from pcg_mpi_solver_tpu.resilience.faultinject import FaultPlan
 from pcg_mpi_solver_tpu.solver.pcg import pcg, pcg_mixed, scoped
 
@@ -577,26 +578,25 @@ class Solver:
         if self.mixed:
             # f32 shadow of the float leaves; index/bool arrays are shared
             # (same device buffers), so the extra memory is only the f32 floats.
-            data = {
-                "f64": data,
-                "f32": jax.tree.map(
-                    lambda x: x.astype(jnp.float32)
-                    if jnp.issubdtype(x.dtype, jnp.floating) else x, data),
-            }
+            data = with_f32_shadow(data)
             self.ops32 = ops32_factory()
         self._specs = _data_specs(data)
-        # Multi-host aware upload: each process materializes only its
-        # addressable shards (parallel/distributed.py).
+        # The whole tree is host arrays up to here: the upload places each
+        # part's rows on its own device (multi-host: each process only
+        # its addressable shards; parallel/distributed.py).
         from pcg_mpi_solver_tpu.parallel.distributed import put_tree
 
-        self.data = put_tree(data, self.mesh, self._specs)
         self._refresh64 = None
-        if self._refresh64_src is not None:
-            rops, rdata = self._refresh64_src
-            rspecs = _data_specs(rdata)
-            self._refresh64 = (rops, put_tree(rdata, self.mesh, rspecs),
-                               rspecs)
-            self._refresh64_src = None      # free the host copies
+        with self._rec.span("upload"):
+            self.data = put_tree(data, self.mesh, self._specs)
+            if self._refresh64_src is not None:
+                rops, rdata = self._refresh64_src
+                rspecs = _data_specs(rdata)
+                self._refresh64 = (rops, put_tree(rdata, self.mesh, rspecs),
+                                   rspecs)
+                self._refresh64_src = None      # free the host copies
+            jax.block_until_ready(
+                (self.data, self._refresh64 and self._refresh64[1]))
 
         self._part_spec = jax.sharding.PartitionSpec(PARTS_AXIS)
         self._rep_spec = jax.sharding.PartitionSpec()
@@ -623,6 +623,9 @@ class Solver:
         cmaps = getattr(self.pm, "combine", None)
         if cmaps is not None and getattr(self.ops, "combine", "") == "gather":
             for k, v in cmaps.gauges().items():
+                self._rec.gauge(k, v)
+        if self.backend != "structured":
+            for k, v in balance_gauges(self.pm).items():
                 self._rec.gauge(k, v)
         # mixed mode: the Krylov iterations (vectors AND dot reductions)
         # run on the f32 ops, so that is the ops object to size from;

@@ -129,11 +129,11 @@ class DynamicsSolver:
         # assembled diagonal, sliced per part (partition extract_NodalVectors
         # analogue) — no cross-part assembly needed.
         inv_m = np.where(self.pm.inv_diag_M > 0, self.pm.inv_diag_M, 0.0)
-        data["inv_M"] = jnp.asarray(inv_m, dtype)
+        data["inv_M"] = np.asarray(inv_m, dtype)
         # Prescribed velocity (reference Vd, partition_mesh.py:324-330),
         # sliced per part like F/Ud.
         gid = self.pm.dof_gid
-        data["Vd"] = jnp.asarray(
+        data["Vd"] = np.asarray(
             np.where(gid >= 0, model.Vd[np.maximum(gid, 0)], 0.0), dtype)
 
         # Probe maps: local index of each probe dof per part + owner mask,
@@ -152,8 +152,8 @@ class DynamicsSolver:
                     "(out of range or Dirichlet-constrained everywhere)")
             p, i = hits[0]
             pidx[p, j], pmask[p, j] = i, 1.0
-        data["probe_idx"] = jnp.asarray(pidx, jnp.int32)
-        data["probe_mask"] = jnp.asarray(pmask, dtype)
+        data["probe_idx"] = pidx
+        data["probe_mask"] = np.asarray(pmask, dtype)
         self._specs = _data_specs(data)
 
         from pcg_mpi_solver_tpu.parallel.distributed import put_sharded, put_tree

@@ -46,7 +46,7 @@ from pcg_mpi_solver_tpu.obs.metrics import MetricsRecorder
 from pcg_mpi_solver_tpu.obs.trace import (
     ConvergenceTrace, clamp_trace_len, empty_trace, trace_init,
     unpack_trace)
-from pcg_mpi_solver_tpu.ops.matvec import Ops
+from pcg_mpi_solver_tpu.ops.matvec import Ops, with_f32_shadow
 from pcg_mpi_solver_tpu.parallel.mesh import PARTS_AXIS, make_mesh
 from pcg_mpi_solver_tpu.resilience.faultinject import FaultPlan
 from pcg_mpi_solver_tpu.solver.driver import StepResult, _data_specs
@@ -270,9 +270,9 @@ class NewmarkSolver:
         # partition_mesh.py:324-330), gathered exactly — bitwise equal to
         # the model's M (zero-mass dofs stay 0: A = K there, still SPD).
         gid = self.pm.dof_gid
-        data["diag_M"] = jnp.asarray(
+        data["diag_M"] = np.asarray(
             np.where(gid >= 0, model.diag_M[np.maximum(gid, 0)], 0.0), dtype)
-        data["Vd"] = jnp.asarray(
+        data["Vd"] = np.asarray(
             np.where(gid >= 0, model.Vd[np.maximum(gid, 0)], 0.0), dtype)
 
         if scfg.precond == "mg":
@@ -282,12 +282,7 @@ class NewmarkSolver:
             data["mg"] = mgmod.cast_tree(self._mg_setup[0].tree, dtype)
 
         if self.mixed:
-            data = {
-                "f64": data,
-                "f32": jax.tree.map(
-                    lambda x: x.astype(jnp.float32)
-                    if jnp.issubdtype(x.dtype, jnp.floating) else x, data),
-            }
+            data = with_f32_shadow(data)
             ops32_base = mk_ops(jnp.float32)
             if scfg.precond == "mg":
                 from pcg_mpi_solver_tpu.ops import mg as mgmod

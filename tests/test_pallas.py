@@ -112,7 +112,7 @@ def test_pallas_matvec_zero_ck_column_isolated(kernel_fn):
     sp = partition_structured(model, 1)
     data = device_data_structured(sp, jnp.float32)
     blk = data["blocks"][0]
-    ck0 = blk["ck"][0]
+    ck0 = jnp.asarray(blk["ck"][0])
     ck_masked = ck0.at[:, :, -1].set(0.0)
 
     rng = np.random.default_rng(9)
