@@ -43,6 +43,7 @@ def main():
     data = device_data_structured(sp, jnp.float32)
     ops = StructuredOps.from_partition(sp, dot_dtype=jnp.float32)
     blk = data["blocks"][0]
+    ck = ops._ck_grid(blk["ck"])[0]             # (nx, ny, nz) cell grid
     print(f"{model.n_dof} dofs on {jax.devices()[0]}", flush=True)
 
     rng = np.random.default_rng(0)
@@ -118,7 +119,7 @@ def main():
                            for v in only)]
     for name, fn in variants:
         try:
-            t, y = timeit(fn, xg, blk["ck"][0], blk["Ke"])
+            t, y = timeit(fn, xg, ck, blk["Ke"])
             err = float(jnp.abs(y.reshape(-1) - y0[0]).max()
                         / jnp.abs(y0).max())
             print(f"{name}: {t*1e3:8.3f} ms/matvec  "

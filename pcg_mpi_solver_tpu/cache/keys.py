@@ -38,7 +38,10 @@ from pcg_mpi_solver_tpu.config import PCG_VARIANTS
 # 4: the hybrid CombineMaps gained the transition-row segment, the
 #    node-minor (P, KD, N) layout and the hpos add-back map; a cached
 #    hybrid partition holding the old maps must not reach the new matvec.
-CACHE_SCHEMA = 4
+# 5: the structured gse stencil runs on the flattened (y, z) node plane
+#    and its device ``ck`` is the flat cell plane; an AOT step program
+#    exported for the 5D stencil must not be replayed.
+CACHE_SCHEMA = 5
 
 # Monkeypatchable in tests to simulate a package-version bump without
 # editing the package.

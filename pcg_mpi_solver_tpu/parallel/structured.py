@@ -52,6 +52,7 @@ class StructuredPartition:
     nz: int
 
     ck: np.ndarray              # (P, nxc, ny, nz) cell stiffness scale
+                                # (the device holds flat_cells(ck))
     ce: np.ndarray              # (P, nxc, ny, nz) cell strain scale (1/h)
     Ke: np.ndarray              # (24, 24)
     diag_Ke: np.ndarray         # (24,)
@@ -178,15 +179,29 @@ def partition_structured(model: ModelData, n_parts: int,
     )
 
 
+def flat_cells(ck: np.ndarray) -> np.ndarray:
+    """(P, cx, ny, nz) cell grid -> (P, cx, ny*(nz+1) - 1) cell plane.
+
+    The stencil runs on the node grid with its (y, z) plane flattened,
+    ``(P, 3, nxn, nny*nnz)``; cell (iy, iz) then sits at ``iy*nnz + iz``
+    of the flat plane, and the slot ``iz == nz`` between two rows is an
+    inert ghost column that holds 0.0, so it contributes exact zeros."""
+    ck = np.asarray(ck)
+    P, cx = ck.shape[:2]
+    pad = np.pad(ck, ((0, 0), (0, 0), (0, 0), (0, 1)))
+    return pad.reshape(P, cx, -1)[:, :, :-1]
+
+
 def device_data_structured(sp: StructuredPartition, dtype=jnp.float64) -> dict:
     """The slab stencil's data as host arrays (see
-    ``ops.matvec.device_data``)."""
+    ``ops.matvec.device_data``).  ``ck`` is held once, in the flat-plane
+    form of :func:`flat_cells`; ``ce`` (export only) keeps the cell grid."""
     return {
         "blocks": [{
             "Ke": np.asarray(sp.Ke, dtype),
             "diag_Ke": np.asarray(sp.diag_Ke, dtype),
             "Se": np.asarray(sp.Se, dtype),
-            "ck": np.asarray(sp.ck, dtype),
+            "ck": np.asarray(flat_cells(sp.ck), dtype),
             "ce": np.asarray(sp.ce, dtype),
         }],
         "weight": np.asarray(sp.weight, dtype),
@@ -333,6 +348,61 @@ class StructuredOps(Ops):
         Pl = x.shape[0]
         return x.reshape(Pl, 3, self.nxc + 1, self.ny + 1, self.nz + 1)
 
+    def _plane(self, x):
+        """(Pl, n_loc) -> (Pl, 3, nxn, nny*nnz): the node grid with its
+        (y, z) plane flattened — a pure reshape of the (c, ix, iy, iz)
+        layout, so the plane is the minor (lane) axis and pads by at
+        most 127 slots where a z row of nnz nodes pads to 128 or 256."""
+        Pl = x.shape[0]
+        return x.reshape(Pl, 3, self.nxc + 1, (self.ny + 1) * (self.nz + 1))
+
+    def _ck_grid(self, ck):
+        """Flat cell plane (Pl, cx, [1,] Lc) -> (Pl, cx, ny, nz) cell grid
+        (drops the ghost column; see flat_cells)."""
+        Pl, cx = ck.shape[0], ck.shape[1]
+        full = jnp.pad(ck.reshape(Pl, cx, -1), ((0, 0), (0, 0), (0, 1)))
+        return full.reshape(Pl, cx, self.ny, self.nz + 1)[..., :self.nz]
+
+    def _corner_shifts(self):
+        """Flat-plane offset ``dy*nnz + dz`` of each element corner, and
+        the plane lengths (Ln nodes, Lc = ny*nnz - 1 cell slots)."""
+        nnz = self.nz + 1
+        shifts = [int(dy * nnz + dz) for _dx, dy, dz in _CORNERS]
+        return shifts, (self.ny + 1) * nnz, self.ny * nnz - 1
+
+    def _gather_plane(self, xp):
+        """(Pl,3,cx+1,Ln) -> (Pl,24,cx,Lc): corner a of the cell at flat
+        slot l is node l + dy*nnz + dz, so the gather is 8 contiguous
+        windows of the plane (cx from the node slab, so x-slab chunks run
+        through the same code)."""
+        cx = xp.shape[2] - 1
+        shifts, _ln, lc = self._corner_shifts()
+        slots = [xp[:, :, dx:dx + cx, s:s + lc]
+                 for (dx, _dy, _dz), s in zip(_CORNERS, shifts)]
+        return jnp.concatenate(slots, axis=1)  # dof order: 3*corner + comp
+
+    def _scatter_plane(self, v):
+        """(Pl,24,cx,Lc) -> (Pl,3,cx+1,Ln): the sum of 8 zero-padded
+        translates of the plane, in _scatter_cells' order."""
+        shifts, ln, lc = self._corner_shifts()
+        y = None
+        for a, ((dx, _dy, _dz), s) in enumerate(zip(_CORNERS, shifts)):
+            t = jnp.pad(v[:, 3 * a:3 * a + 3],
+                        ((0, 0), (0, 0), (dx, 1 - dx), (s, ln - s - lc)))
+            y = t if y is None else y + t
+        return y
+
+    def stencil_lane_fill(self) -> float:
+        """Real cells over lane-padded slots of the XLA stencil's (24,
+        cells) arrays, whose minor axis the TPU pads to a multiple of 128
+        lanes: the flat plane (gse) pads ny*(nz+1) - 1 slots, of which the
+        ghost column is unused too; the 5D grid (corner, gsplit) pads each
+        z row of nz cells."""
+        lanes = lambda n: -(-n // 128) * 128
+        if self.form == "gse":
+            return self.ny * self.nz / lanes(self.ny * (self.nz + 1) - 1)
+        return self.nz / lanes(self.nz)
+
     def _gather_cells(self, xg):
         """(Pl,3,cx+1,cy+1,cz+1) -> (Pl,24,cx,cy,cz) via 8 contiguous
         slices (cell shape derived from the node grid, so x-slab chunks
@@ -385,7 +455,8 @@ class StructuredOps(Ops):
 
     def _halo(self, yg):
         """Combine partial sums on shared slab-boundary planes: one
-        bidirectional ppermute of (3,nny,nnz) planes over the mesh axis."""
+        bidirectional ppermute of x planes — (3, nny*nnz) on the flat
+        plane, (3, nny, nnz) on the 5D grid — over the mesh axis."""
         P = self.n_parts
         if P == 1:
             return yg
@@ -434,11 +505,16 @@ class StructuredOps(Ops):
                 return c if c < self.nxc else 0
         return 0
 
-    def _gse(self, blk, xg, ck):
-        """One slab matvec; two XLA formulations, env-selected.
+    def _gse(self, blk, xp, ck):
+        """One slab matvec on the flat plane: xp (Pl, 3, cx+1, Ln) ->
+        (Pl, 3, cx+1, Ln); ck (Pl, cx, 1, Lc) is the flat cell plane as a
+        one-row (y, z) grid, so it keeps the cell grid's (P, x, y, z)
+        axes.  Three XLA formulations, env-selected.
 
         - ``gse`` (default): gather -> one (24,24)@(24,cells) MXU einsum
-          -> scatter.  Materializes the gathered corner array and the
+          -> scatter, all on the flat plane, whose (24, cells) arrays fill
+          ~99% of their lanes where a z row of 96 (150) cells fills 75%
+          (59%).  Materializes the gathered corner array and the
           product — two (24, cells) HBM round-trips (~650 MB each way at
           10M dofs).
         - ``corner`` (PCG_TPU_MATVEC_FORM=corner): per-output-corner
@@ -451,18 +527,24 @@ class StructuredOps(Ops):
           roofline anyway; the op is HBM-bound) for ~4x less HBM
           traffic.  The knob is resolved ONCE at ops construction
           (self.form) — toggling the env later does nothing.
+        - ``gsplit``: see gsplit_matvec_grid.
+
+        corner and gsplit run on the 5D grid view of xp and ck.
         """
-        if self.form == "corner":
-            return self._gse_corner(blk, xg, ck)
-        if self.form == "gsplit":
-            return self._gse_split(blk, xg, ck)
+        if self.form != "gse":
+            Pl, cx = xp.shape[0], xp.shape[2] - 1
+            xg = xp.reshape(Pl, 3, cx + 1, self.ny + 1, self.nz + 1)
+            ckg = self._ck_grid(ck)
+            fn = self._gse_corner if self.form == "corner" else self._gse_split
+            return fn(blk, xg, ckg).reshape(xp.shape)
+        ck = ck.reshape(ck.shape[0], ck.shape[1], -1)  # (P, cx, Lc)
         with jax.named_scope("mv/gather"):
-            u = self._gather_cells(xg)                 # (P, 24, cells)
+            u = self._gather_plane(xp)                 # (P, 24, cx, Lc)
         with jax.named_scope("mv/contract"):
-            v = jnp.einsum("de,pexyz->pdxyz", blk["Ke"], ck[:, None] * u,
+            v = jnp.einsum("de,pexl->pdxl", blk["Ke"], ck[:, None] * u,
                            precision=self.precision)
         with jax.named_scope("mv/scatter"):
-            return self._scatter_cells(v)
+            return self._scatter_plane(v)
 
     def _gse_corner(self, blk, xg, ck):
         return corner_matvec_grid(blk["Ke"], ck, xg)
@@ -483,9 +565,14 @@ class StructuredOps(Ops):
             # into its batched twin; no per-column Python loop.
             return jax.vmap(lambda xc: self.matvec_local(data, xc),
                             in_axes=-1, out_axes=-1)(x)
+        return self._matvec_plane(data, x).reshape(x.shape)
+
+    def _matvec_plane(self, data, x):
+        """Part-local K.x of one (Pl, n_loc) vector, on the flat plane
+        (Pl, 3, nxn, nny*nnz)."""
         blk = data["blocks"][0]
         with jax.named_scope("mv/gather"):
-            xg = self._grid(x)                         # (P, 3, nxn, nny, nnz)
+            xp = self._plane(x)                        # (P, 3, nxn, Ln)
         chunk = self._chunk_planes(x.dtype)
         if (self.use_pallas and chunk == 0
                 and np.dtype(x.dtype) == np.float32):
@@ -494,54 +581,52 @@ class StructuredOps(Ops):
 
             with jax.named_scope("mv/contract"):
                 y = batched_structured_matvec(
-                    xg, blk["ck"], blk["Ke"],
+                    self._grid(x), self._ck_grid(blk["ck"]), blk["Ke"],
                     interpret=self.pallas_interpret)
-            return y.reshape(x.shape)
+            return y.reshape(xp.shape)
         if chunk == 0:
             # slice-gather + einsum: contiguous slices, MXU matmul, shifted
             # slice-adds — no vector gather/scatter anywhere.
-            return self._gse(blk, xg, blk["ck"]).reshape(x.shape)
+            return self._gse(blk, xp, blk["ck"][:, :, None])
 
-        Pl = xg.shape[0]
-        nxc, ny, nz = self.nxc, self.ny, self.nz
-        n_chunks = nxc // chunk
+        Pl, ln = xp.shape[0], xp.shape[3]
+        lc = blk["ck"].shape[2]
+        n_chunks = self.nxc // chunk
 
         def body(i, y):
             a = i * chunk
             with jax.named_scope("mv/gather"):
                 xs = jax.lax.dynamic_slice(
-                    xg, (0, 0, a, 0, 0), (Pl, 3, chunk + 1, ny + 1, nz + 1))
+                    xp, (0, 0, a, 0), (Pl, 3, chunk + 1, ln))
                 cks = jax.lax.dynamic_slice(
-                    blk["ck"], (0, a, 0, 0), (Pl, chunk, ny, nz))
-            ys = self._gse(blk, xs, cks)
+                    blk["ck"], (0, a, 0), (Pl, chunk, lc))
+            ys = self._gse(blk, xs, cks[:, :, None])
             with jax.named_scope("mv/scatter"):
-                cur = jax.lax.dynamic_slice(y, (0, 0, a, 0, 0), ys.shape)
+                cur = jax.lax.dynamic_slice(y, (0, 0, a, 0), ys.shape)
                 return jax.lax.dynamic_update_slice(y, cur + ys,
-                                                    (0, 0, a, 0, 0))
+                                                    (0, 0, a, 0))
 
         with jax.named_scope("mv/scatter"):
-            y0 = jnp.zeros_like(xg)
-        y = jax.lax.fori_loop(0, n_chunks, body, y0)
-        return y.reshape(x.shape)
+            y0 = jnp.zeros_like(xp)
+        return jax.lax.fori_loop(0, n_chunks, body, y0)
 
     def matvec(self, data, x):
         if x.ndim == 3:
             return jax.vmap(lambda xc: self.matvec(data, xc),
                             in_axes=-1, out_axes=-1)(x)
-        yg = self._grid(self.matvec_local(data, x))
+        yp = self._matvec_plane(data, x)
         with jax.named_scope("mv/halo"):
-            return self._halo(yg).reshape(x.shape)
+            return self._halo(yp).reshape(x.shape)
 
     def diag_local(self, data):
         blk = data["blocks"][0]
         Pl = blk["ck"].shape[0]
-        v = blk["diag_Ke"][None, :, None, None, None] * blk["ck"][:, None]
-        yg = self._scatter_cells(v)
-        return yg.reshape(Pl, self.n_loc)
+        v = blk["diag_Ke"][None, :, None, None] * blk["ck"][:, None]
+        return self._scatter_plane(v).reshape(Pl, self.n_loc)
 
     def diag(self, data):
-        yg = self._grid(self.diag_local(data))
-        return self._halo(yg).reshape(-1, self.n_loc)
+        yp = self._plane(self.diag_local(data))
+        return self._halo(yp).reshape(-1, self.n_loc)
 
     # -- node-block (3x3) diagonal for block-Jacobi ---------------------
     def node_block_diag(self, data):
@@ -552,7 +637,7 @@ class StructuredOps(Ops):
         from pcg_mpi_solver_tpu.ops.precond import corner_block_field
 
         blk = data["blocks"][0]
-        ck = blk["ck"]                                    # (P, cx, cy, cz)
+        ck = self._ck_grid(blk["ck"])                     # (P, cx, cy, cz)
         Pl = ck.shape[0]
         g = self._halo(corner_block_field(blk["Ke"], ck, _CORNERS))
         return g.reshape(Pl, 9, self.n_node_loc) \
@@ -575,7 +660,7 @@ class StructuredOps(Ops):
         if y.ndim == 3:
             return jax.vmap(lambda yc: self.iface_assemble(data, yc),
                             in_axes=-1, out_axes=-1)(y)
-        return self._halo(self._grid(y)).reshape(y.shape)
+        return self._halo(self._plane(y)).reshape(y.shape)
 
     # -- export path ----------------------------------------------------
     def _node_grid(self, y):
@@ -593,7 +678,7 @@ class StructuredOps(Ops):
     def elem_scale(self, data):
         blk = data["blocks"][0]
         Pl = blk["ck"].shape[0]
-        return [(blk["ck"] * blk["ce"]).reshape(Pl, -1)]
+        return [(self._ck_grid(blk["ck"]) * blk["ce"]).reshape(Pl, -1)]
 
     def nodal_average(self, data, vals_list):
         """Cell values -> averaged nodal grid via 8 shifted slice-adds of

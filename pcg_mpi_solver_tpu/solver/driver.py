@@ -620,6 +620,10 @@ class Solver:
         self._rec.gauge("precision_mode", solver_cfg.precision_mode)
         self._rec.gauge("pcg_variant", solver_cfg.pcg_variant)
         self._rec.gauge("precond", solver_cfg.precond)
+        if self.backend == "structured":
+            # which stencil layout ran: real cells over lane-padded slots
+            self._rec.gauge("structured.stencil_lane_fill",
+                            round(self.ops.stencil_lane_fill(), 4))
         cmaps = getattr(self.pm, "combine", None)
         if cmaps is not None and getattr(self.ops, "combine", "") == "gather":
             for k, v in cmaps.gauges().items():

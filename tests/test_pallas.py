@@ -10,7 +10,7 @@ import pytest
 from pcg_mpi_solver_tpu.models import make_cube_model
 from pcg_mpi_solver_tpu.ops.pallas_matvec import structured_matvec_pallas
 from pcg_mpi_solver_tpu.parallel.structured import (
-    StructuredOps, device_data_structured, partition_structured)
+    StructuredOps, device_data_structured, flat_cells, partition_structured)
 
 
 @pytest.mark.parametrize("dims", [(6, 5, 4), (4, 4, 4), (7, 3, 5)])
@@ -27,7 +27,8 @@ def test_pallas_matvec_matches_xla(dims):
 
     blk = data["blocks"][0]
     xg = x.reshape(1, 3, nx + 1, ny + 1, nz + 1)[0]
-    y = structured_matvec_pallas(xg, blk["ck"][0], blk["Ke"],
+    ck = ops._ck_grid(blk["ck"])[0]
+    y = structured_matvec_pallas(xg, ck, blk["Ke"],
                                  interpret=True)
     np.testing.assert_allclose(
         np.asarray(y).reshape(-1), y_ref, rtol=2e-5, atol=2e-5)
@@ -49,7 +50,8 @@ def test_pallas_matvec_v2_matches_xla(dims):
 
     blk = data["blocks"][0]
     xg = x.reshape(1, 3, nx + 1, ny + 1, nz + 1)[0]
-    y = structured_matvec_pallas_v2(xg, blk["ck"][0], blk["Ke"],
+    ck = ops._ck_grid(blk["ck"])[0]
+    y = structured_matvec_pallas_v2(xg, ck, blk["Ke"],
                                     interpret=True)
     np.testing.assert_allclose(
         np.asarray(y).reshape(-1), y_ref, rtol=2e-5, atol=2e-5)
@@ -84,7 +86,8 @@ def test_pallas_matvec_chunked_matches_xla(variant, dims, planes):
 
     blk = data["blocks"][0]
     xg = x.reshape(1, 3, nx + 1, ny + 1, nz + 1)[0]
-    y = fn(xg, blk["ck"][0], blk["Ke"], interpret=True, planes=planes)
+    ck = ops._ck_grid(blk["ck"])[0]
+    y = fn(xg, ck, blk["Ke"], interpret=True, planes=planes)
     np.testing.assert_allclose(
         np.asarray(y).reshape(-1), y_ref, rtol=2e-5, atol=2e-5)
 
@@ -112,7 +115,7 @@ def test_pallas_matvec_zero_ck_column_isolated(kernel_fn):
     sp = partition_structured(model, 1)
     data = device_data_structured(sp, jnp.float32)
     blk = data["blocks"][0]
-    ck0 = jnp.asarray(blk["ck"][0])
+    ck0 = jnp.asarray(sp.ck[0], jnp.float32)
     ck_masked = ck0.at[:, :, -1].set(0.0)
 
     rng = np.random.default_rng(9)
@@ -121,7 +124,8 @@ def test_pallas_matvec_zero_ck_column_isolated(kernel_fn):
     # nodes on the far-z face only touch the zeroed cells via dz=1 corners;
     # recompute with the XLA path and compare
     ops = StructuredOps.from_partition(sp, dot_dtype=jnp.float32)
-    data2 = {"blocks": [{**blk, "ck": ck_masked[None]}],
+    ck_flat = flat_cells(np.asarray(ck_masked)[None])
+    data2 = {"blocks": [{**blk, "ck": ck_flat}],
              **{k: v for k, v in data.items() if k != "blocks"}}
     y_ref = np.asarray(ops.matvec_local(
         data2, xg.reshape(1, -1)))[0]

@@ -125,6 +125,103 @@ def test_chunked_f64_matvec_matches_unchunked():
                                rtol=1e-13, atol=1e-13)
 
 
+def _gse_5d_local(ops, data, x):
+    """The gse stencil on the 5D cell grid (z the minor axis), built from
+    the ops' grid helpers: what the flat (y, z) plane must reproduce."""
+    blk = data["blocks"][0]
+    u = ops._gather_cells(ops._grid(x))
+    ck = ops._ck_grid(blk["ck"])
+    v = jnp.einsum("de,pexyz->pdxyz", blk["Ke"], ck[:, None] * u,
+                   precision=ops.precision)
+    return ops._scatter_cells(v)
+
+
+@pytest.mark.parametrize("dims,n_parts,dtype,chunked,nrhs", [
+    ((5, 4, 3), 1, "float32", False, 1),
+    ((5, 4, 3), 1, "float64", False, 1),
+    ((6, 5, 3), 2, "float32", False, 1),
+    ((6, 5, 3), 2, "float64", False, 1),
+    ((6, 5, 3), 2, "float64", True, 1),
+    ((5, 4, 3), 1, "float64", False, 3),
+    ((6, 5, 3), 2, "float32", False, 3),
+], ids=["5x4x3_f32", "5x4x3_f64", "6x5x3_2parts_f32", "6x5x3_2parts_f64",
+        "6x5x3_2parts_f64_chunked", "5x4x3_f64_nrhs3",
+        "6x5x3_2parts_f32_nrhs3"])
+def test_flat_plane_gse_matches_5d_and_numpy(dims, n_parts, dtype, chunked,
+                                             nrhs):
+    """The gse matvec on the flattened (y, z) node plane against the
+    same stencil on the 5D grid and against the numpy reference, on odd,
+    non-cubic grids, unsharded (roll halo) with 1 and 2 parts, through
+    the f64 x-chunked loop and on an RHS block."""
+    import dataclasses
+
+    from pcg_mpi_solver_tpu.solver.numpy_ref import NumpyRefSolver
+
+    model = make_cube_model(*dims, h=0.5, nu=0.3, heterogeneous=True, seed=3)
+    sp = partition_structured(model, n_parts)
+    dt = jnp.dtype(dtype)
+    data = device_data_structured(sp, dt)
+    ops = StructuredOps.from_partition(sp, dot_dtype=dt)
+    if chunked:
+        ops = dataclasses.replace(ops, chunk_threshold=1)
+        assert ops._chunk_planes(dt) > 0
+
+    # the flat cell plane: ghost column exactly 0, every real cell its ck
+    nx, ny, nz = dims
+    ck = np.asarray(data["blocks"][0]["ck"])
+    assert ck.shape == (n_parts, nx // n_parts, ny * (nz + 1) - 1)
+    ghost = np.arange(ck.shape[2]) % (nz + 1) == nz
+    assert np.all(ck[..., ghost] == 0.0)
+    np.testing.assert_array_equal(
+        ck[..., ~ghost], np.asarray(sp.ck, dt).reshape(n_parts, nx // n_parts,
+                                                       -1))
+
+    rng = np.random.default_rng(7)
+    xs = rng.normal(size=(nrhs, model.n_dof))
+    xp = np.stack([to_parts(sp, c) for c in xs], axis=-1)
+    x = jnp.asarray(xp[..., 0] if nrhs == 1 else xp, dt)
+    y_loc = np.asarray(ops.matvec_local(data, x)).reshape(xp.shape)
+    y = np.asarray(ops.matvec(data, x)).reshape(xp.shape)
+
+    ref = NumpyRefSolver(model)
+    ops5 = StructuredOps.from_partition(sp, dot_dtype=dt)
+    rtol = 1e-12 if dtype == "float64" else 2e-6
+    for j in range(nrhs):
+        xj = jnp.asarray(xp[..., j], dt)
+        y5_loc = _gse_5d_local(ops5, data, xj)
+        y5 = np.asarray(ops5._halo(y5_loc)).reshape(n_parts, -1)
+        y5_loc = np.asarray(y5_loc).reshape(n_parts, -1)
+        y_ref = ref.matvec(np.asarray(jnp.asarray(xs[j], dt), np.float64))
+        scale = np.abs(y_ref).max()
+        np.testing.assert_allclose(y_loc[..., j], y5_loc, rtol=0,
+                                   atol=rtol * scale)
+        np.testing.assert_allclose(y[..., j], y5, rtol=0, atol=rtol * scale)
+        np.testing.assert_allclose(to_global(sp, y[..., j]), y_ref, rtol=0,
+                                   atol=(10 * rtol) * scale)
+
+
+@pytest.mark.parametrize("form,fill", [
+    ("gse", 4 * 5 / 128),                 # ny*nz over ceil(ny*(nz+1)-1, 128)
+    ("corner", 5 / 128),                  # nz over ceil(nz, 128)
+])
+def test_stencil_lane_fill_gauge(monkeypatch, form, fill):
+    """Structured solves record which stencil layout their matvec runs;
+    the general backend records none."""
+    from pcg_mpi_solver_tpu.obs.metrics import MetricsRecorder
+
+    monkeypatch.setenv("PCG_TPU_MATVEC_FORM", form)
+    model = make_cube_model(8, 4, 5)
+    rec = MetricsRecorder()
+    s = Solver(model, RunConfig(), mesh=make_mesh(2), n_parts=2,
+               backend="structured", recorder=rec)
+    assert s.ops.form == form
+    assert rec.gauges["structured.stencil_lane_fill"] == round(fill, 4)
+    gen = MetricsRecorder()
+    Solver(model, RunConfig(), mesh=make_mesh(2), n_parts=2,
+           backend="general", recorder=gen)
+    assert "structured.stencil_lane_fill" not in gen.gauges
+
+
 def test_alt_forms_match_gse():
     """The alternative formulations (corner: fusion-friendly, no
     (24, cells) intermediates; gsplit: concat-free accumulating einsums)

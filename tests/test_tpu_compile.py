@@ -110,12 +110,51 @@ def test_structured_f32_matvec_program_compiles_for_v5e(one_chip):
 
     s = one_chip
     compiled = jax.jit(fn).lower(
-        _sds((1, n_loc), s), _sds((1, n, n, n), s), _sds((24, 24), s),
+        _sds((1, n_loc), s), _sds((1, n, n * (n + 1) - 1), s),
+        _sds((24, 24), s),
         _sds((24,), s), _sds((1, n_loc), s), _sds((1, n_loc), s)).compile()
     mem = compiled.memory_analysis()
     # the f32 operands of one matvec fit one chip's 16 GB many times over
     assert mem is None or mem.argument_size_in_bytes < 2 * 2**30
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_structured_f32_matvec_is_lane_dense_for_v5e(one_chip):
+    """The gse f32 slab matvec at cube.weak4's per-chip shape (38 x 150 x
+    150 cells) runs on the flattened (y, z) node plane: no intermediate
+    keeps the 150-cell (151-node) z row as its minor axis, which pads to
+    256 lanes, and the compiler's bytes of one matvec stay near the flat
+    form's ~0.98e9 (the 5D grid reads ~1.59e9)."""
+    import dataclasses
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from pcg_mpi_solver_tpu.models import make_cube_model
+    from pcg_mpi_solver_tpu.parallel.structured import (
+        StructuredOps, partition_structured)
+
+    nx, ny, nz = 38, 150, 150
+    sp = partition_structured(make_cube_model(4, 4, 4), 1)
+    ops = dataclasses.replace(
+        StructuredOps.from_partition(sp, dot_dtype=jnp.float32),
+        nxc=nx, ny=ny, nz=nz)
+    n_loc = 3 * (nx + 1) * (ny + 1) * (nz + 1)
+
+    def fn(x, ck, ke):
+        return ops.matvec_local({"blocks": [{"ck": ck, "Ke": ke}]}, x)
+
+    s = one_chip
+    compiled = jax.jit(fn).lower(
+        _sds((1, n_loc), s), _sds((1, nx, ny * (nz + 1) - 1), s),
+        _sds((24, 24), s)).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] <= 1.10e9
+    minor = {int(m) for m in re.findall(r"\[(?:\d+,)*(\d+)\]",
+                                         compiled.as_text())}
+    assert not minor & {nz, nz + 1}
 
 
 def test_hybrid_f32_matvec_is_scatter_free_for_v5e(one_chip):

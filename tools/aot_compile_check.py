@@ -110,7 +110,7 @@ def check_f64matvec(args):
     return _compile(
         fn,
         [((1, 3 * nn * nn * nn), jnp.float64),
-         ((1, n, n, n), jnp.float64), ((24, 24), jnp.float64),
+         ((1, n, n * nn - 1), jnp.float64), ((24, 24), jnp.float64),
          ((24,), jnp.float64)],
         s, f"f64 chunked matvec {n}^3")
 
@@ -330,10 +330,10 @@ def check_cubecycle(args):
         lambda a: sds(a.shape, a.dtype),
         cold_carry(jnp.zeros((1, n_loc), dt), jnp.zeros((1, n_loc), dt),
                    jnp.asarray(1.0, ops.dot_dtype), ops.dot_dtype))
-    shapes = [sds((1, n_loc), dt), sds((1, n, n, n), dt), sds((24, 24), dt),
-              sds((24,), dt), sds((1, n_loc), dt), sds((1, n_loc), dt),
-              sds((1, n_loc), dt), sds((1, n_loc), dt), carry,
-              sds((), jnp.int32)]
+    shapes = [sds((1, n_loc), dt), sds((1, n, n * nn - 1), dt),
+              sds((24, 24), dt), sds((24,), dt), sds((1, n_loc), dt),
+              sds((1, n_loc), dt), sds((1, n_loc), dt), sds((1, n_loc), dt),
+              carry, sds((), jnp.int32)]
     label = (f"{args.dtype} CHUNKED cycle"
              + (" +pallas" if args.pallas == "on" else "") + f" {n}^3")
     return _compile_structs(fn, shapes, label)
@@ -420,7 +420,7 @@ def check_pcg(args):
                 tol=1e-7, max_iter=2000, glob_n_dof_eff=n_loc)
         return r.x, r.flag, r.relres, r.iters
 
-    shapes = [((1, n_loc), dt), ((1, n, n, n), dt), ((24, 24), dt),
+    shapes = [((1, n_loc), dt), ((1, n, n * nn - 1), dt), ((24, 24), dt),
               ((24,), dt), ((1, n_loc), dt), ((1, n_loc), dt),
               ((1, n_loc), dt), ((1, n_loc), dt)]
     label = (f"{args.dtype} PCG program"

@@ -153,6 +153,7 @@ def shape_parity(shape, variant: str, interpret: bool) -> dict:
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(1, sp.n_loc)), jnp.float32)
     blk = data["blocks"][0]
+    ck = ops._ck_grid(blk["ck"])[0]             # (nx, ny, nz) cell grid
     xg = x.reshape(3, nx + 1, ny + 1, nz + 1)
 
     y_xla = np.asarray(jax.jit(ops.matvec_local)(data, x))[0]
@@ -161,13 +162,13 @@ def shape_parity(shape, variant: str, interpret: bool) -> dict:
                                    interpret=interpret))
     t0 = time.perf_counter()
     if _CHIPLESS is not None:
-        run(fn, xg, blk["ck"][0], blk["Ke"])
+        run(fn, xg, ck, blk["Ke"])
         return {"check": "matvec_compile", "variant": variant,
                 "shape": list(shape),
                 "compile_s": time.perf_counter() - t0}
-    comp = fn.lower(xg, blk["ck"][0], blk["Ke"]).compile()
+    comp = fn.lower(xg, ck, blk["Ke"]).compile()
     compile_s = time.perf_counter() - t0
-    y = np.asarray(comp(xg, blk["ck"][0], blk["Ke"])).reshape(-1)
+    y = np.asarray(comp(xg, ck, blk["Ke"])).reshape(-1)
 
     # f64 numpy reference of the same product (independent of both)
     from pcg_mpi_solver_tpu.solver.numpy_ref import NumpyRefSolver
