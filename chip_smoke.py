@@ -7,7 +7,7 @@
 Phases on one chip, all in this one process:
 
 (a) flagship cube, 150^3 cells (10,328,853 dofs), mixed precision,
-    Jacobi, classic PCG, structured backend, pallas="auto";
+    Jacobi, classic PCG, structured backend;
 (b) graded octree (bench generator arguments, max_level=4) on the hybrid
     backend, cut from n0=12 to n0=8 (704,355 dofs): see OCTREE_N0.
 
@@ -84,7 +84,7 @@ def octree_model(n0):
     return m, LOAD * n0 / E
 
 
-def build(jax, model, devices, backend, n_parts, pallas):
+def build(jax, model, devices, backend, n_parts):
     """Solver construction through the README's entry points.
     Returns (solver, set-up seconds)."""
     from pcg_mpi_solver_tpu import RunConfig, SolverConfig
@@ -93,7 +93,7 @@ def build(jax, model, devices, backend, n_parts, pallas):
 
     cfg = RunConfig(solver=SolverConfig(
         tol=TOL, precision_mode="mixed", precond="jacobi",
-        pcg_variant="classic", pallas=pallas))
+        pcg_variant="classic"))
     t0 = time.perf_counter()
     s = Solver(model, cfg, mesh=make_mesh(devices=devices),
                n_parts=n_parts, backend=backend)
@@ -114,12 +114,8 @@ def run(s, model, n_devices, setup_s, compile_s):
     res = s.solve()[0]
     solve_s = time.perf_counter() - t0
     u = s.displacement_global()
-    pallas = s.pallas_variant
-    if s.backend == "hybrid" and pallas != "off":
-        pallas += f" levels={list(s.ops.pallas_levels)}"
     rec = {
         "n_dof": int(model.n_dof), "backend": s.backend,
-        "matvec_form": getattr(s.ops, "form", "n/a"), "pallas": pallas,
         "n_parts": int(s.pm.n_parts), "n_devices": n_devices,
         "flag": int(res.flag), "iters": int(res.iters),
         "relres": float(res.relres),
@@ -130,9 +126,9 @@ def run(s, model, n_devices, setup_s, compile_s):
     return rec, u
 
 
-def solve(jax, model, devices, backend, n_parts, pallas):
+def solve(jax, model, devices, backend, n_parts):
     """Build, compile and solve.  Returns (record, solver, u_global)."""
-    s, setup_s = build(jax, model, devices, backend, n_parts, pallas)
+    s, setup_s = build(jax, model, devices, backend, n_parts)
     rec, u = run(s, model, len(devices), setup_s, warmup(s))
     return rec, s, u
 
@@ -166,12 +162,11 @@ def host_checks(model, u, bar, rec) -> list:
     return fails
 
 
-def phase(jax, name, build, devices, backend, n_parts, pallas,
-          reduced=None):
+def phase(jax, name, build, devices, backend, n_parts, reduced=None):
     t0 = time.perf_counter()
     model, bar = build()
     build_s = time.perf_counter() - t0
-    rec, s, u = solve(jax, model, devices, backend, n_parts, pallas)
+    rec, s, u = solve(jax, model, devices, backend, n_parts)
     rec["model_build_s"] = build_s
     t0 = time.perf_counter()
     fails = host_checks(model, u, bar, rec)
@@ -199,7 +194,7 @@ def shard_bytes(s, devices) -> list:
     return [per[d] for d in devices]
 
 
-def sharded(jax, legs, devices, pallas) -> list:
+def sharded(jax, legs, devices) -> list:
     """Each leg on all devices against one: parity, agreement, spread.
 
     Every solver is built first, then all compile concurrently in
@@ -217,7 +212,7 @@ def sharded(jax, legs, devices, pallas) -> list:
     for name, model, backend in legs:
         for tag, devs in (("n", devices), ("1", devices[:1])):
             solvers[name, tag] = build(jax, model, devs, backend,
-                                       len(devs), pallas)
+                                       len(devs))
     compile_s, errors = {}, {}
 
     def compile_one(key):
@@ -301,19 +296,15 @@ def main(argv=None) -> int:
     emit({"device": device_info(jax, len(devices)),
           "native_built": built, "native_partitioning": native.available()})
 
-    # the one-chip rehearsal runs the Pallas kernels through the
-    # interpreter; the four-device one resolves "auto" as the chip does
-    # (no verified kernel: the XLA matvec on every part)
     toy = args.cpu_rehearsal
     cube_n, oct_n0 = (12, 4) if toy else (150, OCTREE_N0)
-    pallas = "interpret" if toy and args.chips == 1 else "auto"
     fails = []
     if args.chips == 1:
         fails += phase(jax, "a_cube",
                        lambda: cube_model(cube_n, cube_n, cube_n),
-                       devices, "auto", 1, pallas)
+                       devices, "auto", 1)
         fails += phase(jax, "b_octree", lambda: octree_model(oct_n0),
-                       devices, "hybrid", 1, pallas,
+                       devices, "hybrid", 1,
                        reduced=f"octree n0 12 -> {oct_n0}: compile time")
     else:
         # slab decomposition needs nx % n_devices == 0: 150 -> 152
@@ -321,7 +312,7 @@ def main(argv=None) -> int:
         octree, _ = octree_model(oct_n0)
         fails += sharded(jax, [("cube_slabs", cube, "structured"),
                                ("octree_hybrid", octree, "hybrid")],
-                         devices, pallas)
+                         devices)
 
     emit({"ok": not fails and platform == "tpu",
           "device": device_info(jax, len(devices))})

@@ -1,5 +1,5 @@
 """Per-matvec cost breakdown of the HYBRID (octree) backend: isolates
-the per-level row gathers, the block stencils, and the row scatters that
+the per-level row gathers, the block stencils, and the node combine that
 make up matvec_local, so the octree flagship's bottleneck is attributable
 on real hardware (RUNBOOK on-hardware checklist, octree leg).
 
@@ -61,6 +61,7 @@ def main():
 
     # per-level pieces (jitted separately — sums can exceed the fused
     # whole; the split still attributes the dominant cost)
+    rows_levels = []
     for i, dims in enumerate(ops.level_dims):
         lv = data["levels"][i]
 
@@ -79,19 +80,24 @@ def main():
 
         js = jax.jit(s_fn)
         t_s = timeit(js, data, xg)
-        yg = js(data, xg)
+        rows_levels.append(ops._grid_rows(js(data, xg), 1))
         del xg     # free this level's lattice batch before the next
-
-        def sc_fn(d, yg_, i=i, dims=dims):
-            y0 = jnp.zeros((1, ops.n_loc), yg_.dtype)
-            return ops._level_scatter_add(y0, yg_, d["levels"][i], dims, 1)
-
-        t_sc = timeit(jax.jit(sc_fn), data, yg)
-        del yg
         nrows = int(np.prod(lv["nidx"].shape))
         print(f"level {i} (nb={dims[0]} {dims[1]}x{dims[2]}x{dims[3]}, "
               f"{nrows/1e6:.2f}M rows): gather {t_g:7.3f}  stencil "
-              f"{t_s:7.3f}  scatter {t_sc:7.3f} ms", flush=True)
+              f"{t_s:7.3f} ms", flush=True)
+
+    if rows_levels:
+        rows_trans = (ops._element_rows(data, x)
+                      if ops._trans_in_combine(data) else None)
+
+        def c_fn(d, rl, rt):
+            y0 = jnp.zeros((1, ops.n_loc), rl[0].dtype)
+            return ops._combined_gather_add(y0, rl, d, 1, rt)
+
+        t_c = timeit(jax.jit(c_fn), data, rows_levels, rows_trans)
+        print(f"node combine (all levels + transitions): {t_c:7.3f} ms",
+              flush=True)
 
 
 if __name__ == "__main__":

@@ -1,6 +1,6 @@
 """pcg_mpi_solver_tpu — a TPU-native massively-parallel matrix-free PCG framework.
 
-A ground-up JAX/XLA/Pallas re-design of the capabilities of the reference
+A ground-up JAX/XLA re-design of the capabilities of the reference
 ``ankitskr/PCG-MPI-solver`` (matrix-free preconditioned conjugate-gradient
 solver for linear elastostatics on octree-pattern hexahedral meshes,
 reference: /root/reference/src/solver/pcg_solver.py).

@@ -345,8 +345,6 @@ CONSENSUS_SITES = {
     # engage decision gates collective code paths -> agree_flag
     ("pcg_mpi_solver_tpu/solver/driver.py", "__init__"):
         "calls:agree_flag",
-    # pallas-probe allgather: unconditional, AND-reduced on every rank
-    ("pcg_mpi_solver_tpu/solver/driver.py", "_pallas_enabled"): "exempt",
     # export-glue layout exchange: unconditional data movement
     ("pcg_mpi_solver_tpu/solver/driver.py", "_exchange_export_glue"):
         "exempt",

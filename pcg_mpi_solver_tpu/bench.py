@@ -106,10 +106,8 @@ the obs/perf.py analytic cost model's verdict), BENCH_PROFILE=1
 jax.profiler trace captured into BENCH_PROFILE_DIR, default
 bench_profile/, parsed back by obs/profview.py; the final line gains
 detail.measured_ms_per_iter_matvec + detail.overlap_frac and the
-artifact stays on disk for `pcg-tpu prof-report`); plus the solver-level performance knobs
-PCG_TPU_MATVEC_FORM / PCG_TPU_PALLAS_V / PCG_TPU_PALLAS_PLANES /
-PCG_TPU_HYBRID_BLOCK (docs/RUNBOOK.md knob table) — the engaged form is
-reported in detail.matvec_form.
+artifact stays on disk for `pcg-tpu prof-report`); plus the solver-level performance knob
+PCG_TPU_HYBRID_BLOCK (docs/RUNBOOK.md knob table).
 """
 
 import json
@@ -387,8 +385,8 @@ def _accel_platform():
     return jax.devices()[0].platform
 
 
-def _run_config_extra(solver, dtype, mode, pallas_on, n_parts, t_part,
-                      platform, setup=None):
+def _run_config_extra(solver, dtype, mode, n_parts, t_part, platform,
+                      setup=None):
     """The run-configuration detail keys shared by the warm-insurance
     line and the final emitted line (one place, so the two cannot
     drift).  ``setup`` carries the warm-path attribution fields
@@ -410,11 +408,6 @@ def _run_config_extra(solver, dtype, mode, pallas_on, n_parts, t_part,
         "precond": getattr(
             getattr(getattr(solver, "config", None), "solver", None),
             "precond", "jacobi"),
-        "pallas": bool(pallas_on),
-        # ops without a form attribute (general backend) never read the
-        # form knob; the stencil ops PIN it at construction
-        "matvec_form": getattr(solver.ops, "form", "n/a"),
-        "combine": getattr(solver.ops, "combine", "n/a"),
         # batched multi-RHS A/B field: the SolverConfig.nrhs block width
         # this round solved (BENCH_NRHS sets it at cfg build) —
         # schema-validated (obs/schema.BENCH_DETAIL_NUMERIC) and present
@@ -536,9 +529,8 @@ def _solve_once(kind, nx, ny, nz, ot_n, ot_level, backend, n_parts, tol,
                 mode, dtype, emitter=None):
     """Build the model/solver, warm-solve (compile), timed solve.
 
-    Returns (model, solver, r1, iters, t_part, pallas_on, setup_info)
-    where pallas_on reports whether the fused Pallas matvec path stayed
-    engaged and setup_info carries the warm-path attribution fields."""
+    Returns (model, solver, r1, iters, t_part, setup_info) where
+    setup_info carries the warm-path attribution fields."""
     import jax
 
     from pcg_mpi_solver_tpu import RunConfig, SolverConfig, TimeHistoryConfig
@@ -559,7 +551,6 @@ def _solve_once(kind, nx, ny, nz, ot_n, ot_level, backend, n_parts, tol,
     cfg = RunConfig(
         solver=SolverConfig(tol=tol, max_iter=20000, dtype=dtype,
                             dot_dtype="float64", precision_mode=mode,
-                            pallas=os.environ.get("BENCH_PALLAS", "auto"),
                             # classic|fused|pipelined A/B knob for the
                             # hardware windows (fused = one collective/
                             # iteration; pipelined = that collective
@@ -621,43 +612,11 @@ def _solve_once(kind, nx, ny, nz, ot_n, ot_level, backend, n_parts, tol,
                        backend=backend, recorder=_REC)
         t_part = time.perf_counter() - t_part0
         _log(f"# partition+upload: {t_part:.2f}s (backend={s.backend}, "
-             f"dispatch_cap={s._dispatch_cap}, "
-             f"pallas={getattr(s.ops, 'use_pallas', False)})")
+             f"dispatch_cap={s._dispatch_cap})")
 
-        # Warm-up: compile + first solve.  If the Pallas kernel fails at
-        # bench scale (the init probe only validates lowering, not
-        # runtime), fall back to the XLA matvec rather than losing the
-        # round's perf number.
-        def pallas_fallback(why):
-            nonlocal s
-            _log(f"# pallas path {why}; retrying with pallas=off")
-            cfg.solver.pallas = "off"
-            del s   # free the failed solver's buffers before re-upload
-            # the rebuilt solver's programs recompile: reset cold/warm
-            # keying so the new compiles are booked as cold, not warm
-            _REC.reset_dispatch_attribution()
-            s = Solver(model, cfg, mesh=make_mesh(), n_parts=n_parts,
-                       backend=backend, recorder=_REC)
-            return s.step(1.0)
-
-        pallas_on = getattr(s.ops, "use_pallas", False)
-        try:
-            with _REC.span("warm_solve", emit=True):
-                r0 = s.step(1.0)
-        except Exception as e:                      # noqa: BLE001
-            if not pallas_on:
-                raise
-            r0 = pallas_fallback(
-                f"failed at scale ({type(e).__name__}: {e})")
-            pallas_on = False
-        else:
-            if r0.flag != 0 and pallas_on:
-                # a mis-lowered kernel cannot fake convergence (the f64
-                # true residual is computed on the XLA path) — a failed
-                # solve with pallas on warrants one XLA retry before
-                # reporting failure
-                r0 = pallas_fallback(f"solve flag={r0.flag}")
-                pallas_on = False
+        # Warm-up: compile + first solve.
+        with _REC.span("warm_solve", emit=True):
+            r0 = s.step(1.0)
     finally:
         # first dispatch seen (or never will be): detach the one-shot
         # sink on EVERY exit path — a leaked sink would latch a LATER
@@ -665,10 +624,7 @@ def _solve_once(kind, nx, ny, nz, ot_n, ot_level, backend, n_parts, tol,
         _REC.remove_sink(fd_sink)
     _log(f"# warm solve: flag={r0.flag} iters={r0.iters} "
          f"relres={r0.relres:.3e} wall={r0.wall_s:.2f}s (incl. compile)")
-    # Warm-path attribution for the bench line.  A pallas fallback
-    # rebuilt the solver, so read setup_s/setup_cache from the solver
-    # that SURVIVED; the first-dispatch anchor spans the whole attempt
-    # either way.
+    # Warm-path attribution for the bench line.
     setup_info = {
         "setup_s": round(s.setup_s, 3),
         "setup_cache": s.setup_cache,
@@ -682,8 +638,8 @@ def _solve_once(kind, nx, ny, nz, ot_n, ot_level, backend, n_parts, tol,
     # ONE run-config detail dict for the insurance line, the
     # failed-timed-solve salvage line, and (via the caller) the final
     # line — three consumers that must not drift in attribution.
-    run_extra = _run_config_extra(s, dtype, mode, pallas_on, n_parts,
-                                  t_part, plat, setup=setup_info)
+    run_extra = _run_config_extra(s, dtype, mode, n_parts, t_part, plat,
+                                  setup=setup_info)
     if emitter is not None and r0.flag == 0 and plat != "cpu":
         # Insurance against a device death DURING the timed solve: on
         # 2026-08-01 the device lost mid-timed-dispatch 29 SECONDS after
@@ -775,7 +731,7 @@ def _solve_once(kind, nx, ny, nz, ot_n, ot_level, backend, n_parts, tol,
     # salvage offers predate the capture and stay unstamped — absent,
     # not null, per obs/schema.py)
     setup_info.update(_capture_bench_profile(s, nrhs))
-    return model, s, r1, iters, t_part, pallas_on, setup_info
+    return model, s, r1, iters, t_part, setup_info
 
 
 def _capture_bench_profile(solver, nrhs):
@@ -1432,7 +1388,7 @@ def _run_bench(cpu_fallback, provisional=False, deadline=None, emitter=None):
         fl_seq = (fl.begin(f"rung:{rung_i}", nx=nx, ot_n=ot_n)
                   if fl is not None else None)
         try:
-            model, solver, r1, iters, t_part, pallas_on, setup_info = \
+            model, solver, r1, iters, t_part, setup_info = \
                 _solve_once(
                     kind, nx, ny, nz, ot_n, ot_level, backend, n_parts,
                     tol, mode, dtype, emitter=emitter)
@@ -1467,7 +1423,7 @@ def _run_bench(cpu_fallback, provisional=False, deadline=None, emitter=None):
         gc.collect()                                # free device buffers
 
     extra = _run_config_extra(
-        solver, dtype, mode, pallas_on, n_parts, t_part, _accel_platform() + (
+        solver, dtype, mode, n_parts, t_part, _accel_platform() + (
             " (CPU PROVISIONAL — fast fallback so the round artifact "
             "cannot be empty; not the TPU north-star number)"
             if provisional else

@@ -221,11 +221,9 @@ class SolverConfig:
     # to re-dispatch from; without one the failure escalates to the
     # recovery ladder's step restart.
     dispatch_retries: int = 2
-    # Fused Pallas matvec kernel for f32 structured-backend matvecs
-    # (ops/pallas_matvec.py): "auto" = on TPU devices, "on", "off",
-    # "interpret" = force the kernel through the Pallas interpreter on
-    # any backend (CI's way to exercise the real solver->kernel dispatch
-    # on CPU; far slower than the XLA path — testing only).
+    # Kept only because the benchmark configurations pass it: "auto" and
+    # "off" both run the XLA stencil, the one matvec each backend has.
+    # There is no kernel, so "on" and "interpret" are refused.
     pallas: str = "auto"
 
     def __post_init__(self):
@@ -237,6 +235,10 @@ class SolverConfig:
             raise ValueError(
                 f"SolverConfig.pcg_variant must be one of "
                 f"{PCG_VARIANTS}, got {self.pcg_variant!r}")
+        if self.pallas not in ("auto", "off"):
+            raise ValueError(
+                f"SolverConfig.pallas={self.pallas!r}: there is no Pallas "
+                f"matvec kernel; 'auto' and 'off' both run the XLA stencil")
 
 
 @dataclasses.dataclass

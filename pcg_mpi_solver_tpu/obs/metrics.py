@@ -302,19 +302,6 @@ class MetricsRecorder:
             return {k: {"calls": int(v[0]), "total_s": v[1]}
                     for k, v in self._spans.items()}
 
-    def reset_dispatch_attribution(self) -> None:
-        """Forget per-program cold/warm state.  Call when the programs
-        behind the dispatch names are REBUILT (e.g. a solver
-        reconstruction after a failed kernel path): the next call of each
-        name pays XLA compile again and must be booked as cold.  The
-        ``dispatch.<name>.calls`` counters reset too, so snapshot() stays
-        internally consistent."""
-        with self._lock:
-            self._dispatch.clear()
-            for k in [k for k in self.counters
-                      if k.startswith("dispatch.")]:
-                del self.counters[k]
-
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
             counters = dict(self.counters)

@@ -158,44 +158,6 @@ def can_hybrid(model: ModelData) -> bool:
             and model.octree.get("brick_type") is not None)
 
 
-# batched_structured_matvec launches the kernel once per leading-batch
-# entry (part*block); beyond this many launches per level the XLA
-# stencil wins on dispatch overhead alone.  ONE constant shared by the
-# probe/enable decision and the per-level trace-time dispatch.
-PALLAS_BATCH_CAP = 16
-
-
-def local_parts(n_parts: int, mesh) -> int:
-    """Parts resident per device (the stencil's leading batch is
-    local_parts * blocks under shard_map)."""
-    n_dev = int(mesh.devices.size) if mesh is not None else 1
-    return max(1, -(-int(n_parts) // n_dev))
-
-
-def hybrid_pallas_enabled(hp: "HybridPartition", pallas_mode: str,
-                          mesh) -> bool:
-    """Resolve the pallas knob with THIS partition's level-grid shapes —
-    the one shared probe call for every hybrid consumer (quasi-static
-    driver, dynamics).  Only levels whose part*block batch fits the
-    per-launch cap are probed (the others always run the XLA stencil);
-    if no level qualifies, "auto" declines the kernel and an explicit
-    request raises."""
-    from pcg_mpi_solver_tpu.solver.driver import _pallas_enabled
-
-    lp = local_parts(hp.pm.n_parts, mesh)
-    shapes = tuple(sorted(set(
-        ((3, lv.bx + 1, lv.by + 1, lv.bz + 1), (lv.bx, lv.by, lv.bz))
-        for lv in hp.levels if lp * lv.nb <= PALLAS_BATCH_CAP)))
-    if not shapes:
-        if pallas_mode in ("on", "interpret"):
-            raise ValueError(
-                f"pallas={pallas_mode!r} cannot engage: every hybrid "
-                f"level's part*block batch exceeds the "
-                f"{PALLAS_BATCH_CAP}-launch cap")
-        return False
-    return _pallas_enabled(pallas_mode, mesh, shapes=shapes)
-
-
 def partition_hybrid(model: ModelData, n_parts: int,
                      elem_part: Optional[np.ndarray] = None,
                      method: str = "rcb") -> HybridPartition:
@@ -386,7 +348,6 @@ def partition_env_knobs() -> Dict[str, object]:
         "block": int(os.environ.get("PCG_TPU_HYBRID_BLOCK", "8")),
         "merge": os.environ.get("PCG_TPU_HYBRID_MERGE", "0") == "1",
         "kd": combine_kd(),
-        "combine": hybrid_combine_mode(),
     }
 
 
@@ -428,17 +389,6 @@ def _pick_kd(lens: List[np.ndarray], N: int) -> int:
         return kd * N + ((lmax - kd) * H + N if H else 0)
 
     return min(range(1, max(lmax, 1) + 1), key=rows)
-
-
-def hybrid_combine_mode() -> str:
-    """The PCG_TPU_HYBRID_COMBINE knob, validated — ``gather`` (default:
-    partition-time-composed per-node source indices, scatter-free) or
-    ``scatter`` (the vmap'd at[].add row scatter)."""
-    mode = os.environ.get("PCG_TPU_HYBRID_COMBINE", "gather")
-    if mode not in ("gather", "scatter"):
-        raise ValueError("PCG_TPU_HYBRID_COMBINE must be gather|scatter, "
-                         f"got {mode!r}")
-    return mode
 
 
 def build_combine_maps(levels: List[LevelGrid], n_node_loc: int, P: int,
@@ -520,60 +470,19 @@ class HybridOps(Ops):
 
     # static (nb, bx, by, bz) per level — shapes must be trace-constants
     level_dims: tuple = ()
-    # run the f32 level stencils through the fused Pallas plane-march
-    # kernel (ops/pallas_matvec.py) — same kernel as the structured backend
-    use_pallas: bool = False
-    # per-level kernel eligibility (part*block batch within the launch
-    # cap), resolved at construction so the trace-time dispatch agrees
-    # with hybrid_pallas_enabled's probe
-    pallas_levels: tuple = ()
-    # run the kernel through the Pallas interpreter (CI on CPU exercises
-    # the real solver->kernel dispatch; SolverConfig.pallas='interpret')
-    pallas_interpret: bool = False
-    # XLA stencil formulation, PINNED at construction (checkpoint
-    # fingerprints record it — see parallel/structured.py)
-    form: str = "gse"
-    # level-combine strategy, PINNED at construction: "gather" (composed
-    # per-node source indices, scatter-free) or "scatter" (row scatter)
-    combine: str = "gather"
-
-    def __post_init__(self):
-        from pcg_mpi_solver_tpu.parallel.structured import VALID_FORMS
-
-        if self.form not in VALID_FORMS:
-            raise ValueError(
-                f"form must be one of {VALID_FORMS}, got {self.form!r}")
-        if self.combine not in ("gather", "scatter"):
-            raise ValueError("combine must be gather|scatter, "
-                             f"got {self.combine!r}")
 
     @classmethod
     def from_hybrid(cls, hp: HybridPartition, dot_dtype=jnp.float64,
                     axis_name=None,
-                    precision=jax.lax.Precision.HIGHEST,
-                    use_pallas=False, n_local_parts=1, form=None,
-                    combine=None, pallas_interpret=False):
-        from pcg_mpi_solver_tpu.parallel.structured import matvec_form
-
+                    precision=jax.lax.Precision.HIGHEST):
         pm = hp.pm
-        if combine is None:
-            combine = hybrid_combine_mode()
-        if hp.combine is None:
-            combine = "scatter"     # no maps built (no levels)
         return cls(n_loc=pm.n_loc, n_iface=pm.n_iface,
                    n_node_loc=pm.n_node_loc, n_node_iface=pm.n_node_iface,
                    dot_dtype=dot_dtype, axis_name=axis_name,
                    precision=precision,
                    use_node_ell=pm.ell is not None,
                    level_dims=tuple((lv.nb, lv.bx, lv.by, lv.bz)
-                                    for lv in hp.levels),
-                   use_pallas=use_pallas, pallas_interpret=pallas_interpret,
-                   pallas_levels=tuple(
-                       use_pallas
-                       and n_local_parts * lv.nb <= PALLAS_BATCH_CAP
-                       for lv in hp.levels),
-                   form=form if form is not None else matvec_form(),
-                   combine=combine)
+                                    for lv in hp.levels))
 
     # -- level-grid primitives -----------------------------------------
     def _rows_pad(self, x):
@@ -594,22 +503,13 @@ class HybridOps(Ops):
         g = g.reshape(Pn * nb, bx + 1, by + 1, bz + 1, 3)
         return g.transpose(0, 4, 1, 2, 3)
 
-    def _level_scatter_add(self, y, grid, lv, dims, Pn):
-        """Adds (P*nb, 3, bx+1, by+1, bz+1) block-batch node-grid values
-        into y (P, n_loc).  Block-boundary lattice nodes appear in every
-        adjacent block's lattice; the row scatter accumulates them."""
-        rows = self._grid_rows(grid, Pn)
-        y3 = y.reshape(Pn, self.n_node_loc, 3)
-        y3 = jax.vmap(
-            lambda yp, idx, r: yp.at[idx].add(r, mode="drop")
-        )(y3, lv["nidx"].reshape(Pn, -1), rows)
-        return y3.reshape(Pn, self.n_loc)
-
     def _trans_in_combine(self, data) -> bool:
         """Whether the transition blocks' rows are slots of the combine
         maps (partition_hybrid builds them in exactly for the node-ELL
-        layout), so the node-ELL sum is off the gather-combine path."""
-        return self._use_gather(data) and self.use_node_ell
+        layout), so the node-ELL sum is off the combine path.  A
+        partition with no level grids has no maps."""
+        return ("combine" in data and bool(data["levels"])
+                and self.use_node_ell)
 
     @staticmethod
     def _take_rows(src, idx):
@@ -659,12 +559,6 @@ class HybridOps(Ops):
             acc = acc + self._take_rows(hacc, cm["hpos"])
         return (y.reshape(Pn, self.n_node_loc, w) + acc).reshape(Pn, -1)
 
-    def _use_gather(self, data) -> bool:
-        """ONE eligibility rule for the gather-combine across every
-        consumer method (matvec, diag, node blocks, nodal averaging)."""
-        return (self.combine == "gather" and "combine" in data
-                and bool(data["levels"]))
-
     @staticmethod
     def _grid_rows(grid, Pn):
         """(P*nb, w, bx+1, by+1, bz+1) block-batch grid -> (P, slots, w)
@@ -672,39 +566,18 @@ class HybridOps(Ops):
         w = grid.shape[1]
         return grid.transpose(0, 2, 3, 4, 1).reshape(Pn, -1, w)
 
-    def _stencil(self, Ke, ck, xg, pallas_ok=False):
-        """Structured brick matvec on one level grid (same formulations
-        as parallel/structured.py: slice gather -> einsum -> sum of
-        padded translates, the fusion-friendly corner form when
-        ``self.form == "corner"`` — pinned at construction, the env knob
-        is not re-read — or the fused Pallas kernel when this level is
-        flagged eligible in ``pallas_levels``)."""
-        if pallas_ok and np.dtype(xg.dtype) == np.float32:
-            from pcg_mpi_solver_tpu.ops.pallas_matvec import (
-                batched_structured_matvec)
-
-            with jax.named_scope("mv/contract"):
-                return batched_structured_matvec(
-                    xg, ck, Ke, interpret=self.pallas_interpret)
-        if self.form == "corner":
-            from pcg_mpi_solver_tpu.parallel.structured import (
-                corner_matvec_grid)
-
-            return corner_matvec_grid(Ke, ck, xg)
+    def _stencil(self, Ke, ck, xg):
+        """Structured brick matvec on one level grid, as in
+        parallel/structured.py: slice gather -> einsum -> sum of padded
+        translates."""
         bx, by, bz = ck.shape[1], ck.shape[2], ck.shape[3]
-        if self.form == "gsplit":
-            from pcg_mpi_solver_tpu.parallel.structured import (
-                gsplit_matvec_grid)
-
-            v = gsplit_matvec_grid(Ke, ck, xg, self.precision)
-        else:
-            with jax.named_scope("mv/gather"):
-                slots = [xg[:, :, dx:dx + bx, dy:dy + by, dz:dz + bz]
-                         for dx, dy, dz in _CORNERS]
-                u = jnp.concatenate(slots, axis=1)     # (P, 24, cells)
-            with jax.named_scope("mv/contract"):
-                v = jnp.einsum("de,pexyz->pdxyz", Ke, ck[:, None] * u,
-                               precision=self.precision)
+        with jax.named_scope("mv/gather"):
+            slots = [xg[:, :, dx:dx + bx, dy:dy + by, dz:dz + bz]
+                     for dx, dy, dz in _CORNERS]
+            u = jnp.concatenate(slots, axis=1)         # (P, 24, cells)
+        with jax.named_scope("mv/contract"):
+            v = jnp.einsum("de,pexyz->pdxyz", Ke, ck[:, None] * u,
+                           precision=self.precision)
         with jax.named_scope("mv/scatter"):
             terms = []
             for a, (dx, dy, dz) in enumerate(_CORNERS):
@@ -723,10 +596,10 @@ class HybridOps(Ops):
         ``mv/gather`` (node rows to element and level lattices),
         ``mv/contract`` (the element and brick stencil products) and
         ``mv/scatter`` (pad-add corner terms, the node combine, the
-        springs) stages.  Under the gather combine the transition rows
-        and the live lattice slots reach their nodes through ONE
-        composed combine; otherwise the transitions go through
-        Ops.matvec_local's node-ELL sum."""
+        springs) stages.  The transition rows (node-ELL layout) and the
+        live lattice slots reach their nodes through ONE composed
+        combine; a partition with no level grids sums its transitions
+        through Ops.matvec_local's node-ELL path."""
         if x.ndim == 3:
             # RHS-block axis (Ops.matvec contract): the level-grid
             # gather/stencil/scatter machinery runs on flat vectors, so
@@ -745,24 +618,18 @@ class HybridOps(Ops):
         if data["levels"]:
             with jax.named_scope("mv/gather"):
                 x3p = self._rows_pad(x)
-            pal = self.pallas_levels or (False,) * len(data["levels"])
-            use_gather = self._use_gather(data)
             rows_levels = []
-            for lv, dims, pok in zip(data["levels"], self.level_dims, pal):
+            for lv, dims in zip(data["levels"], self.level_dims):
                 with jax.named_scope("mv/gather"):
                     xg = self._level_gather(x3p, lv, dims, Pn)
                     ck = lv["ck"].reshape((Pn * dims[0],)
                                           + lv["ck"].shape[2:])
-                yg = self._stencil(data["brick_Ke"], ck, xg, pallas_ok=pok)
+                yg = self._stencil(data["brick_Ke"], ck, xg)
                 with jax.named_scope("mv/scatter"):
-                    if use_gather:
-                        rows_levels.append(self._grid_rows(yg, Pn))
-                    else:
-                        y = self._level_scatter_add(y, yg, lv, dims, Pn)
-            if use_gather:
-                with jax.named_scope("mv/scatter"):
-                    y = self._combined_gather_add(y, rows_levels, data, Pn,
-                                                  rows_trans)
+                    rows_levels.append(self._grid_rows(yg, Pn))
+            with jax.named_scope("mv/scatter"):
+                y = self._combined_gather_add(y, rows_levels, data, Pn,
+                                              rows_trans)
         return y
 
     def diag_local(self, data):
@@ -772,7 +639,6 @@ class HybridOps(Ops):
         else:
             y = self._apply_springs_diag(
                 data, jnp.zeros((Pn, self.n_loc), data["weight"].dtype))
-        use_gather = self._use_gather(data)
         rows_levels = []
         for lv, dims in zip(data["levels"], self.level_dims):
             ck = lv["ck"].reshape((Pn * dims[0],) + lv["ck"].shape[2:])
@@ -788,11 +654,8 @@ class HybridOps(Ops):
             g = terms[0]
             for t in terms[1:]:
                 g = g + t
-            if use_gather:
-                rows_levels.append(self._grid_rows(g, Pn))
-            else:
-                y = self._level_scatter_add(y, g, lv, dims, Pn)
-        if use_gather:
+            rows_levels.append(self._grid_rows(g, Pn))
+        if rows_levels:
             y = self._combined_gather_add(y, rows_levels, data, Pn)
         return y
 
@@ -808,20 +671,13 @@ class HybridOps(Ops):
                                 data["weight"].dtype))
         from pcg_mpi_solver_tpu.ops.precond import corner_block_field
 
-        use_gather = self._use_gather(data)
         rows_levels = []
         for lv, dims in zip(data["levels"], self.level_dims):
             Pn = lv["ck"].shape[0]
             ck = lv["ck"].reshape((Pn * dims[0],) + lv["ck"].shape[2:])
             g = corner_block_field(data["brick_Ke"], ck, _CORNERS)
-            if use_gather:
-                rows_levels.append(self._grid_rows(g, Pn))
-            else:
-                rows = self._grid_rows(g, Pn)
-                y = jax.vmap(
-                    lambda yp, idx, r: yp.at[idx].add(r, mode="drop")
-                )(y, lv["nidx"].reshape(Pn, -1), rows)
-        if use_gather:
+            rows_levels.append(self._grid_rows(g, Pn))
+        if rows_levels:
             Pn = y.shape[0]
             y = self._combined_gather_add(
                 y.reshape(Pn, -1), rows_levels, data, Pn
@@ -884,16 +740,6 @@ class HybridOps(Ops):
             sums = jax.vmap(scat)(sums, ids, contrib)
             counts = jax.vmap(scat)(counts, ids, ones)
 
-        # ONE pack/unpack for the (sums, counts) <-> (P, n_node_loc, k+1)
-        # row layout, shared by the gather and scatter combine branches
-        def pack():
-            return jnp.concatenate([sums, counts], axis=1).transpose(0, 2, 1)
-
-        def unpack(joined):
-            j = joined.transpose(0, 2, 1)
-            return j[:, :k], j[:, k:]
-
-        use_gather = self._use_gather(data)
         rows_levels = []
         for lv, dims, vals in zip(data["levels"], self.level_dims,
                                   vals_list[nb:]):
@@ -912,19 +758,14 @@ class HybridOps(Ops):
             g = terms[0]
             for t in terms[1:]:
                 g = g + t                       # (P*nb, k+1, node grid)
-            rows = self._grid_rows(g, Pl)
-            if use_gather:
-                rows_levels.append(rows)
-                continue
-            joined = jax.vmap(
-                lambda jp, idx, r: jp.at[idx].add(r, mode="drop")
-            )(pack(), lv["nidx"].reshape(Pl, -1), rows)
-            sums, counts = unpack(joined)
-        if use_gather and rows_levels:
+            rows_levels.append(self._grid_rows(g, Pl))
+        if rows_levels:
+            # (sums, counts) as (P, n_node_loc, k+1) rows through the combine
+            joined = jnp.concatenate([sums, counts], axis=1).transpose(0, 2, 1)
             joined = self._combined_gather_add(
-                pack().reshape(Pl, -1), rows_levels, data, Pl
-            ).reshape(Pl, self.n_node_loc, k + 1)
-            sums, counts = unpack(joined)
+                joined.reshape(Pl, -1), rows_levels, data, Pl
+            ).reshape(Pl, self.n_node_loc, k + 1).transpose(0, 2, 1)
+            sums, counts = joined[:, :k], joined[:, k:]
 
         both = jnp.concatenate([sums, counts], axis=1)
         both = self.niface_assemble(data, both)

@@ -216,90 +216,11 @@ def device_data_structured(sp: StructuredPartition, dtype=jnp.float64) -> dict:
 _CORNERS = HEX_CORNERS.astype(np.int64)  # (8, 3)
 
 
-VALID_FORMS = ("gse", "gsplit", "corner")
-
 # Declared collective cost of one _halo exchange on a sharded multi-part
 # slab: one bidirectional plane swap = 2 ppermutes per matvec.  Part of
 # StructuredOps.body_collective_budget — the contract the analysis/
 # collective-budget rule proves against the traced PCG body jaxpr.
 STENCIL_HALO_PPERMUTES = 2
-
-
-def matvec_form() -> str:
-    """The PCG_TPU_MATVEC_FORM knob, validated — the ONE place its
-    name/default/valid values live (resolved once at stencil-ops
-    construction; reported by bench.py and checkpoint fingerprints)."""
-    import os
-
-    form = os.environ.get("PCG_TPU_MATVEC_FORM", "gse")
-    if form not in VALID_FORMS:
-        raise ValueError(
-            f"PCG_TPU_MATVEC_FORM must be one of {VALID_FORMS}, got {form!r}")
-    return form
-
-
-def corner_matvec_grid(Ke, ck, xg):
-    """Fusion-friendly brick-grid matvec: no (24, cells) intermediates.
-
-    y = sum_b pad_b(sum_a Ke[3b:3b+3, 3a:3a+3] @ (ck * x_a)) with each
-    3x3 block unrolled to scalar-broadcast FMAs (static unroll — XLA
-    fuses the whole thing into slice-read -> FMA -> pad-accumulate
-    chains), landing on the node grid as zero-padded translates.  Shared
-    by the structured slab backend (_gse corner form) and the hybrid
-    level-grid stencil.
-
-    Ke (24, 24); ck (P, cx, cy, cz); xg (P, 3, cx+1, cy+1, cz+1)."""
-    cx, cy, cz = ck.shape[1], ck.shape[2], ck.shape[3]
-    w = []
-    for a in range(8):
-        dx, dy, dz = _CORNERS[a]
-        with jax.named_scope("mv/gather"):
-            xa = xg[:, :, dx:dx + cx, dy:dy + cy, dz:dz + cz]
-        with jax.named_scope("mv/contract"):
-            w.append(ck[:, None] * xa)
-    y = None
-    for b in range(8):
-        ex, ey, ez = _CORNERS[b]
-        with jax.named_scope("mv/contract"):
-            comps = []
-            for d in range(3):
-                acc = None
-                for a in range(8):
-                    for c in range(3):
-                        t = Ke[3 * b + d, 3 * a + c] * w[a][:, c]
-                        acc = t if acc is None else acc + t
-                comps.append(acc)
-            vb = jnp.stack(comps, axis=1)              # (P, 3, cells)
-        with jax.named_scope("mv/scatter"):
-            term = jnp.pad(vb, ((0, 0), (0, 0), (ex, 1 - ex),
-                                (ey, 1 - ey), (ez, 1 - ez)))
-            y = term if y is None else y + term
-    return y
-
-
-def gsplit_matvec_grid(Ke, ck, xg, precision):
-    """gse minus the gather CONCAT (PCG_TPU_MATVEC_FORM=gsplit):
-    v = sum_a Ke[:, 3a:3a+3] @ (ck * x_a) accumulates eight
-    (24,3)@(3,cells) einsums whose inputs are contiguous grid slices —
-    the (24, cells) gathered array u never exists, saving one full HBM
-    round-trip of it (~650 MB at 10M dofs) against gse.  Keeps gse's
-    single (24, cells) product; the caller scatters it.  Shared by the
-    structured slab backend and the hybrid level-grid stencil (like
-    corner_matvec_grid).
-
-    Ke (24, 24); ck (P, cx, cy, cz); xg (P, 3, cx+1, cy+1, cz+1);
-    returns v (P, 24, cx, cy, cz) in 3*corner + comp dof order."""
-    cx, cy, cz = ck.shape[1], ck.shape[2], ck.shape[3]
-    v = None
-    for a in range(8):
-        dx, dy, dz = _CORNERS[a]
-        with jax.named_scope("mv/gather"):
-            xa = xg[:, :, dx:dx + cx, dy:dy + cy, dz:dz + cz]
-        with jax.named_scope("mv/contract"):
-            t = jnp.einsum("dc,pcxyz->pdxyz", Ke[:, 3 * a:3 * a + 3],
-                           ck[:, None] * xa, precision=precision)
-            v = t if v is None else v + t
-    return v
 
 
 @dataclasses.dataclass(frozen=True)
@@ -312,36 +233,15 @@ class StructuredOps(Ops):
     n_parts: int = 1
     # cells above which f64 matvecs run x-slab-chunked (see _chunk_planes)
     chunk_threshold: int = 500_000
-    # f32 matvecs through the fused Pallas plane-march kernel
-    # (ops/pallas_matvec.py) instead of the XLA gather/einsum/scatter
-    use_pallas: bool = False
-    # run the kernel through the Pallas interpreter (CI on CPU exercises
-    # the real solver->kernel dispatch; SolverConfig.pallas='interpret')
-    pallas_interpret: bool = False
-    # XLA stencil formulation, PINNED at construction (the checkpoint
-    # fingerprint records it; an env flip after construction must not
-    # silently change what a resume replays)
-    form: str = "gse"
-
-    def __post_init__(self):
-        # explicit pins (incl. dataclasses.replace) must not bypass the
-        # validation matvec_form() applies to the env path — a typo'd
-        # form would silently run gse while being recorded as itself
-        if self.form not in VALID_FORMS:
-            raise ValueError(
-                f"form must be one of {VALID_FORMS}, got {self.form!r}")
 
     @classmethod
     def from_partition(cls, sp: StructuredPartition, dot_dtype=jnp.float64,
-                       axis_name=None, precision=jax.lax.Precision.HIGHEST,
-                       use_pallas=False, form=None, pallas_interpret=False):
+                       axis_name=None, precision=jax.lax.Precision.HIGHEST):
         return cls(n_loc=sp.n_loc, n_iface=0,
                    n_node_loc=sp.n_node_loc, n_node_iface=0,
                    dot_dtype=dot_dtype,
                    axis_name=axis_name, precision=precision,
-                   nxc=sp.nxc, ny=sp.ny, nz=sp.nz, n_parts=sp.n_parts,
-                   use_pallas=use_pallas, pallas_interpret=pallas_interpret,
-                   form=form if form is not None else matvec_form())
+                   nxc=sp.nxc, ny=sp.ny, nz=sp.nz, n_parts=sp.n_parts)
 
     # -- grid helpers ---------------------------------------------------
     def _grid(self, x):
@@ -383,7 +283,9 @@ class StructuredOps(Ops):
 
     def _scatter_plane(self, v):
         """(Pl,24,cx,Lc) -> (Pl,3,cx+1,Ln): the sum of 8 zero-padded
-        translates of the plane, in _scatter_cells' order."""
+        translates of the plane (one fused output pass; an .at[].add
+        chain would serialize 8 read-modify-write sweeps of the node
+        grid)."""
         shifts, ln, lc = self._corner_shifts()
         y = None
         for a, ((dx, _dy, _dz), s) in enumerate(zip(_CORNERS, shifts)):
@@ -393,20 +295,16 @@ class StructuredOps(Ops):
         return y
 
     def stencil_lane_fill(self) -> float:
-        """Real cells over lane-padded slots of the XLA stencil's (24,
-        cells) arrays, whose minor axis the TPU pads to a multiple of 128
-        lanes: the flat plane (gse) pads ny*(nz+1) - 1 slots, of which the
-        ghost column is unused too; the 5D grid (corner, gsplit) pads each
-        z row of nz cells."""
-        lanes = lambda n: -(-n // 128) * 128
-        if self.form == "gse":
-            return self.ny * self.nz / lanes(self.ny * (self.nz + 1) - 1)
-        return self.nz / lanes(self.nz)
+        """Real cells over lane-padded slots of the stencil's (24, cells)
+        arrays, whose minor axis the TPU pads to a multiple of 128 lanes:
+        the flat plane pads ny*(nz+1) - 1 slots, of which the ghost
+        column is unused too."""
+        lanes = -(-(self.ny * (self.nz + 1) - 1) // 128) * 128
+        return self.ny * self.nz / lanes
 
     def _gather_cells(self, xg):
         """(Pl,3,cx+1,cy+1,cz+1) -> (Pl,24,cx,cy,cz) via 8 contiguous
-        slices (cell shape derived from the node grid, so x-slab chunks
-        work through the same code)."""
+        slices of the 5D node grid (the strain export's gather)."""
         cx, cy, cz = xg.shape[2] - 1, xg.shape[3] - 1, xg.shape[4] - 1
         slots = []
         for a in range(8):
@@ -414,21 +312,6 @@ class StructuredOps(Ops):
             s = xg[:, :, dx:dx + cx, dy:dy + cy, dz:dz + cz]
             slots.append(s)
         return jnp.concatenate(slots, axis=1)  # dof order: 3*corner + comp
-
-    def _scatter_cells(self, v):
-        """(Pl,24,cx,cy,cz) -> (Pl,3,cx+1,cy+1,cz+1) via a sum of 8
-        zero-padded translates (one fused output pass; an .at[].add chain
-        would serialize 8 read-modify-write sweeps of the node grid)."""
-        terms = []
-        for a in range(8):
-            dx, dy, dz = _CORNERS[a]
-            terms.append(jnp.pad(
-                v[:, 3 * a:3 * a + 3],
-                ((0, 0), (0, 0), (dx, 1 - dx), (dy, 1 - dy), (dz, 1 - dz))))
-        y = terms[0]
-        for t in terms[1:]:
-            y = y + t
-        return y
 
     def body_collective_budget(self, variant: str = "classic",
                                precond: str = "jacobi") -> dict:
@@ -509,34 +392,11 @@ class StructuredOps(Ops):
         """One slab matvec on the flat plane: xp (Pl, 3, cx+1, Ln) ->
         (Pl, 3, cx+1, Ln); ck (Pl, cx, 1, Lc) is the flat cell plane as a
         one-row (y, z) grid, so it keeps the cell grid's (P, x, y, z)
-        axes.  Three XLA formulations, env-selected.
-
-        - ``gse`` (default): gather -> one (24,24)@(24,cells) MXU einsum
-          -> scatter, all on the flat plane, whose (24, cells) arrays fill
-          ~99% of their lanes where a z row of 96 (150) cells fills 75%
-          (59%).  Materializes the gathered corner array and the
-          product — two (24, cells) HBM round-trips (~650 MB each way at
-          10M dofs).
-        - ``corner`` (PCG_TPU_MATVEC_FORM=corner): per-output-corner
-          accumulation y = sum_b pad_b(sum_a Ke[3b:3b+3, 3a:3a+3] @
-          (ck * x_a)), with each 3x3 block unrolled to scalar
-          multiply-adds so XLA fuses the whole thing into
-          slice-read -> FMA -> pad-accumulate chains and NO (24, cells)
-          intermediate ever exists.  Trades the single big MXU matmul
-          (arithmetic intensity ~12 flops/byte — far below the MXU
-          roofline anyway; the op is HBM-bound) for ~4x less HBM
-          traffic.  The knob is resolved ONCE at ops construction
-          (self.form) — toggling the env later does nothing.
-        - ``gsplit``: see gsplit_matvec_grid.
-
-        corner and gsplit run on the 5D grid view of xp and ck.
-        """
-        if self.form != "gse":
-            Pl, cx = xp.shape[0], xp.shape[2] - 1
-            xg = xp.reshape(Pl, 3, cx + 1, self.ny + 1, self.nz + 1)
-            ckg = self._ck_grid(ck)
-            fn = self._gse_corner if self.form == "corner" else self._gse_split
-            return fn(blk, xg, ckg).reshape(xp.shape)
+        axes.  Gather -> one (24,24)@(24,cells) MXU einsum -> scatter,
+        all on the flat plane, whose (24, cells) arrays fill ~99% of
+        their lanes where a z row of 96 (150) cells fills 75% (59%).
+        Materializes the gathered corner array and the product — two
+        (24, cells) HBM round-trips (~650 MB each way at 10M dofs)."""
         ck = ck.reshape(ck.shape[0], ck.shape[1], -1)  # (P, cx, Lc)
         with jax.named_scope("mv/gather"):
             u = self._gather_plane(xp)                 # (P, 24, cx, Lc)
@@ -546,18 +406,9 @@ class StructuredOps(Ops):
         with jax.named_scope("mv/scatter"):
             return self._scatter_plane(v)
 
-    def _gse_corner(self, blk, xg, ck):
-        return corner_matvec_grid(blk["Ke"], ck, xg)
-
-    def _gse_split(self, blk, xg, ck):
-        v = gsplit_matvec_grid(blk["Ke"], ck, xg, self.precision)
-        with jax.named_scope("mv/scatter"):
-            return self._scatter_cells(v)
-
     def matvec_local(self, data, x):
         """Part-local K.x on the slab grid, in the ``mv/gather``,
-        ``mv/contract`` and ``mv/scatter`` stages of Ops.matvec_local
-        (the fused Pallas kernel is one ``mv/contract``)."""
+        ``mv/contract`` and ``mv/scatter`` stages of Ops.matvec_local."""
         if x.ndim == 3:
             # RHS-block axis (Ops.matvec contract): the stencil is built
             # around grid reshapes of one flat vector, so the block is
@@ -574,16 +425,6 @@ class StructuredOps(Ops):
         with jax.named_scope("mv/gather"):
             xp = self._plane(x)                        # (P, 3, nxn, Ln)
         chunk = self._chunk_planes(x.dtype)
-        if (self.use_pallas and chunk == 0
-                and np.dtype(x.dtype) == np.float32):
-            from pcg_mpi_solver_tpu.ops.pallas_matvec import (
-                batched_structured_matvec)
-
-            with jax.named_scope("mv/contract"):
-                y = batched_structured_matvec(
-                    self._grid(x), self._ck_grid(blk["ck"]), blk["Ke"],
-                    interpret=self.pallas_interpret)
-            return y.reshape(xp.shape)
         if chunk == 0:
             # slice-gather + einsum: contiguous slices, MXU matmul, shifted
             # slice-adds — no vector gather/scatter anywhere.

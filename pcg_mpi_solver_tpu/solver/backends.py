@@ -17,12 +17,8 @@ from pcg_mpi_solver_tpu.parallel.partition import partition_model
 
 
 def select_time_backend(model: ModelData, n_parts: int, *,
-                        partition_method: str, pallas_mode: str, mesh,
-                        kernels_f32: bool, backend: str = "auto"):
+                        partition_method: str, backend: str = "auto"):
     """Resolve ``backend`` ("auto" | "hybrid" | "general") for a model.
-
-    ``kernels_f32``: whether this solver will ever run f32 matvecs (the
-    only place Pallas kernels dispatch) — gates the compile probe.
 
     Returns ``(name, pm, mk_ops, mk_data)`` with ``mk_ops(dot_dtype)`` an
     Ops factory and ``mk_data(dtype)`` the device-pytree factory.
@@ -54,17 +50,11 @@ def select_time_backend(model: ModelData, n_parts: int, *,
         backend = "general"
     if backend in ("auto", "hybrid") and can_hybrid(model):
         from pcg_mpi_solver_tpu.parallel.hybrid import (
-            HybridOps, device_data_hybrid, hybrid_pallas_enabled,
-            local_parts, partition_hybrid)
+            HybridOps, device_data_hybrid, partition_hybrid)
 
         pm = partition_hybrid(model, n_parts, method=partition_method)
-        use_pallas = kernels_f32 and hybrid_pallas_enabled(
-            pm, pallas_mode, mesh)
-        lp = local_parts(n_parts, mesh)
-        interp = pallas_mode == "interpret"
         mk_ops = lambda dd: HybridOps.from_hybrid(
-            pm, dot_dtype=dd, axis_name=PARTS_AXIS, use_pallas=use_pallas,
-            n_local_parts=lp, pallas_interpret=interp)
+            pm, dot_dtype=dd, axis_name=PARTS_AXIS)
         return "hybrid", pm, mk_ops, lambda dt: device_data_hybrid(pm, dt)
 
     pm = partition_model(model, n_parts, method=partition_method)

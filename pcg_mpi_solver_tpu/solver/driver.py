@@ -33,77 +33,6 @@ from pcg_mpi_solver_tpu.parallel.partition import (
 from pcg_mpi_solver_tpu.resilience.faultinject import FaultPlan
 from pcg_mpi_solver_tpu.solver.pcg import pcg, pcg_mixed, scoped
 
-#: (kernel variant, ``jax.Device.device_kind``) pairs on which the Pallas
-#: matvec has matched the XLA matvec AND solved the flagship on the chip.
-#: pallas="auto" engages only on these; an explicit pallas="on" on any
-#: other TPU kind raises instead of running an unverified kernel.
-PALLAS_VERIFIED: frozenset = frozenset()
-
-_PALLAS_PROBED: set = set()
-
-
-def _pallas_enabled(mode: str, mesh, shapes=()) -> bool:
-    """Resolve the SolverConfig.pallas knob.  "auto" engages the fused
-    Mosaic kernel from what it can see before compiling: a TPU device
-    whose (variant, ``device_kind``) is in :data:`PALLAS_VERIFIED` (CPU
-    runs use the XLA path; tests exercise the kernel via interpret=True).
-    "on" demands the kernel and raises on a TPU kind it is not verified
-    on.  The kernel is then lowered at the ACTUAL shapes up front
-    (``probe_shapes``) and compiled inside the solve program; a failure
-    of either RAISES — on every process of a multi-process run together
-    — and is never a silent switch to the XLA matvec."""
-    if mode == "interpret":
-        # run the kernel through the Pallas interpreter on any backend —
-        # CI's way to exercise the REAL solver->kernel dispatch (layout,
-        # batching, reshape order) without TPU hardware
-        return True
-    if mode == "off":
-        return False
-    if mode not in ("auto", "on"):
-        raise ValueError(f"SolverConfig.pallas must be "
-                         f"'auto'|'on'|'off'|'interpret', got {mode!r}")
-    d = mesh.devices.flat[0]
-    if d.platform != "tpu":
-        return mode == "on"     # Mosaic then refuses the CPU at compile
-    from pcg_mpi_solver_tpu.ops.pallas_matvec import (
-        pallas_planes, probe_shapes, selected_variant)
-
-    variant = selected_variant()[0]
-    if (variant, d.device_kind) not in PALLAS_VERIFIED:
-        if mode == "on":
-            raise ValueError(
-                f"pallas='on': the {variant} matvec kernel is not verified "
-                f"on {d.device_kind!r} (verified: {sorted(PALLAS_VERIFIED)})"
-                f"; use pallas='off' or 'auto'")
-        return False
-    # the planes knob changes what the kernel lowers to, so a probe
-    # passed under one value must not vouch for another
-    key = (d.device_kind, variant, pallas_planes(), tuple(shapes))
-    if key not in _PALLAS_PROBED:
-        err = None
-        try:
-            probe_shapes(list(shapes) or [((3, 3, 3, 3), (2, 2, 2))])
-        except Exception as e:                          # noqa: BLE001
-            err = e
-        if jax.process_count() > 1:
-            # one SPMD program, one kernel: a host whose lowering failed
-            # must not leave the others waiting in the first collective
-            from jax.experimental import multihost_utils
-
-            # consensus-exempt: unconditional data gather reached by
-            # every process (the AND below is itself the agreement)
-            oks = multihost_utils.process_allgather(
-                np.asarray([err is None], dtype=bool))
-            if err is None and not np.all(oks):
-                raise RuntimeError(
-                    f"the {variant} matvec kernel failed to lower on "
-                    f"another process; see its error")
-        if err is not None:
-            raise err
-        _PALLAS_PROBED.add(key)
-    return True
-
-
 @dataclasses.dataclass
 class StepResult:
     flag: int
@@ -288,10 +217,6 @@ class Solver:
         if setup_shard not in ("auto", "on", "off"):
             raise ValueError(f"RunConfig.setup_shard must be "
                              f"'auto'|'on'|'off', got {setup_shard!r}")
-        # Kernel variant is FIXED at construction (the env knob is read at
-        # trace time); the checkpoint fingerprint must record what this
-        # solver actually compiled, not the env at save() time.
-        self.pallas_variant = "off"
         # f64-refresh formulation (hybrid+mixed only; see the hybrid
         # branch below).  Recorded in the checkpoint fingerprint: the
         # general form's summation order differs, which can drift
@@ -378,7 +303,6 @@ class Solver:
                     "make_global_mesh, or n_parts divisible by the "
                     "device count)")
 
-        interp = solver_cfg.pallas == "interpret"
         if self.backend == "structured":
             from pcg_mpi_solver_tpu.parallel.structured import (
                 StructuredOps, device_data_structured, partition_structured)
@@ -388,27 +312,15 @@ class Solver:
                 lambda part_range=None: partition_structured(
                     model, n_parts, part_range=part_range),
                 n_parts=n_parts, shard=True)
-            sp = self.pm
-            use_pallas = _pallas_enabled(
-                solver_cfg.pallas, self.mesh,
-                shapes=(((3, sp.nxc + 1, sp.ny + 1, sp.nz + 1),
-                         (sp.nxc, sp.ny, sp.nz)),))
-            if use_pallas:
-                from pcg_mpi_solver_tpu.ops.pallas_matvec import (
-                    selected_variant)
-
-                self.pallas_variant = selected_variant()[0]
             self.ops = StructuredOps.from_partition(
-                self.pm, dot_dtype=dot_dtype, axis_name=PARTS_AXIS,
-                use_pallas=use_pallas, pallas_interpret=interp)
+                self.pm, dot_dtype=dot_dtype, axis_name=PARTS_AXIS)
             data = device_data_structured(self.pm, dtype)
             ops32_factory = lambda: StructuredOps.from_partition(
-                self.pm, dot_dtype=jnp.float32, axis_name=PARTS_AXIS,
-                use_pallas=use_pallas, pallas_interpret=interp)
+                self.pm, dot_dtype=jnp.float32, axis_name=PARTS_AXIS)
         elif self.backend == "hybrid":
             from pcg_mpi_solver_tpu.parallel.hybrid import (
-                HybridOps, device_data_hybrid, hybrid_pallas_enabled,
-                partition_env_knobs, partition_hybrid)
+                HybridOps, device_data_hybrid, partition_env_knobs,
+                partition_hybrid)
 
             # PCG_TPU_HYBRID_F64_REFRESH: formulation of the out-of-loop f64
             # matvecs (Dirichlet lifting, r0, refinement true-residual — ~4
@@ -442,7 +354,7 @@ class Solver:
                 n_parts=n_parts, method=method, elem_part=elem_part,
                 # every partition-time env knob keys the entry, resolved
                 # by the module that owns the defaults (block/merge
-                # reshape the level grids, combine/kd shape CombineMaps)
+                # reshape the level grids, kd shapes CombineMaps)
                 extra=partition_env_knobs())
             if self.f64_refresh in ("general", "bucketed") \
                     and elem_part is None:
@@ -451,25 +363,11 @@ class Solver:
                 # cache hit skipped make_elem_part entirely, so recover
                 # the map from the partition itself.
                 elem_part = np.asarray(self.pm.elem_part)
-            use_pallas = hybrid_pallas_enabled(
-                self.pm, solver_cfg.pallas, self.mesh)
-            if use_pallas:
-                from pcg_mpi_solver_tpu.ops.pallas_matvec import (
-                    selected_variant)
-
-                self.pallas_variant = selected_variant()[0]
-            from pcg_mpi_solver_tpu.parallel.hybrid import local_parts
-
-            lp = local_parts(n_parts, self.mesh)
             self.ops = HybridOps.from_hybrid(
-                self.pm, dot_dtype=dot_dtype, axis_name=PARTS_AXIS,
-                use_pallas=use_pallas, n_local_parts=lp,
-                pallas_interpret=interp)
+                self.pm, dot_dtype=dot_dtype, axis_name=PARTS_AXIS)
             data = device_data_hybrid(self.pm, dtype)
             ops32_factory = lambda: HybridOps.from_hybrid(
-                self.pm, dot_dtype=jnp.float32, axis_name=PARTS_AXIS,
-                use_pallas=use_pallas, n_local_parts=lp,
-                pallas_interpret=interp)
+                self.pm, dot_dtype=jnp.float32, axis_name=PARTS_AXIS)
             if self.f64_refresh in ("general", "bucketed"):
                 pm_full = self._partition_cached(
                     "general",
@@ -625,7 +523,7 @@ class Solver:
             self._rec.gauge("structured.stencil_lane_fill",
                             round(self.ops.stencil_lane_fill(), 4))
         cmaps = getattr(self.pm, "combine", None)
-        if cmaps is not None and getattr(self.ops, "combine", "") == "gather":
+        if cmaps is not None:
             for k, v in cmaps.gauges().items():
                 self._rec.gauge(k, v)
         if self.backend != "structured":
@@ -1106,7 +1004,6 @@ class Solver:
 
         from pcg_mpi_solver_tpu.cache import aot
         from pcg_mpi_solver_tpu.cache.keys import step_cache_key
-        from pcg_mpi_solver_tpu.ops.pallas_matvec import pallas_planes
 
         data_abs = aot.abstract_like(self.data)
         psh = jax.sharding.NamedSharding(self.mesh, self._part_spec)
@@ -1133,20 +1030,10 @@ class Solver:
             glob_n_dof_eff=int(self.pm.glob_n_dof_eff),
             donate=bool(donate_step),
             jax_version=jax.__version__,
-            # every trace-time env knob baked into the program must key
-            # it: the RESOLVED stencil form (StructuredOps pins it at
-            # construction so an env flip cannot silently change what a
-            # resume replays — the AOT layer must not reintroduce that
-            # substitution) and the pallas kernel shape knobs
-            extra={"pallas_variant": self.pallas_variant,
-                   "matvec_form": getattr(self.ops, "form", None),
-                   "pallas_planes": (pallas_planes()
-                                     if self.pallas_variant != "off"
-                                     else None),
-                   # MG-shape components (level count / smoothing
-                   # degree / lattice dims): they shape the traced
-                   # V-cycle beyond what the solver dict records
-                   "mg": self._mg_meta,
+            # MG-shape components (level count / smoothing degree /
+            # lattice dims): they shape the traced V-cycle beyond what
+            # the solver dict records
+            extra={"mg": self._mg_meta,
                    "x64": bool(jax.config.jax_enable_x64)})
         exported = aot.cached_step(
             self._cache_dir, key, jax.jit(shard_step), abstract_args,
@@ -1997,7 +1884,6 @@ class Solver:
 
         from pcg_mpi_solver_tpu.cache import aot
         from pcg_mpi_solver_tpu.cache.keys import step_cache_key
-        from pcg_mpi_solver_tpu.ops.pallas_matvec import pallas_planes
 
         data_abs = aot.abstract_like(self.data)
         psh = jax.sharding.NamedSharding(self.mesh, self._part_spec)
@@ -2019,11 +1905,6 @@ class Solver:
             donate=False,
             jax_version=jax.__version__,
             extra={"many": True,
-                   "pallas_variant": self.pallas_variant,
-                   "matvec_form": getattr(self.ops, "form", None),
-                   "pallas_planes": (pallas_planes()
-                                     if self.pallas_variant != "off"
-                                     else None),
                    "mg": self._mg_meta,
                    "x64": bool(jax.config.jax_enable_x64)})
         exported = aot.cached_step(

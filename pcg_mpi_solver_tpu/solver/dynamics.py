@@ -113,16 +113,13 @@ class DynamicsSolver:
 
         # Backend: the hybrid level-grid path serves octree models' matvec
         # (the per-step hot op) exactly as in the quasi-static driver;
-        # everything else stays on the general path.  Pallas only ever
-        # dispatches on f32 matvecs; dynamics has no mixed-precision f32
-        # shadow, so the probe is skipped in f64 runs.
+        # everything else stays on the general path.
         from pcg_mpi_solver_tpu.solver.backends import select_time_backend
 
         self.backend, self.pm, mk_ops, mk_data = select_time_backend(
             model, n_parts,
             partition_method=self.config.partition_method,
-            pallas_mode=self.config.solver.pallas, mesh=self.mesh,
-            kernels_f32=dtype == jnp.float32, backend=backend)
+            backend=backend)
         self.ops = mk_ops(dtype)
         data = mk_data(dtype)
         # Assembled lumped-mass diagonal: model.diag_M is already the global

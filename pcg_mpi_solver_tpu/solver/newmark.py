@@ -217,8 +217,6 @@ class NewmarkSolver:
         self.backend, self.pm, mk_ops, mk_data = select_time_backend(
             model, n_parts,
             partition_method=self.config.partition_method,
-            pallas_mode=scfg.pallas, mesh=self.mesh,
-            kernels_f32=self.mixed or dtype == jnp.float32,
             backend=backend)
         self._mg_meta = None
         self._mg_setup = None

@@ -155,26 +155,13 @@ def _fingerprint(solver) -> dict:
                    [int(f) for f in th.export_frames], th.export_vars],
         "plot": [bool(th.plot_flag), [int(d) for d in th.probe_dofs]],
         "backend": solver.backend,
-        # EFFECTIVE kernel choice, not the "auto" knob: each Pallas matvec
-        # variant has its own summation order (changes iteration counts,
-        # breaking exact resume), but kernels only ever execute on f32
-        # matvecs — a pure-f64 direct run is byte-identical either way.
-        "pallas": _effective_kernel(solver),
-        # same summation-order hazard for the stencil backends: the XLA
-        # formulation (gse vs corner) and the hybrid level-grid block
-        # layout both reorder the pad-accumulate sums.  Both are PINNED
-        # on the ops at construction (ops.form / ops.level_dims — the
-        # env knobs cannot drift between trace and save), and ops
-        # without a form attribute (general backend) never read the
-        # knob.
-        "matvec_form": getattr(solver.ops, "form", "n/a"),
+        # the hybrid level-grid block layout reorders the pad-accumulate
+        # sums (pinned on the ops at construction — the env knobs cannot
+        # drift between trace and save), and KD (the dense/heavy split of
+        # the combine maps) reorders the slot accumulation, frozen in the
+        # partition's built maps
         "level_dims": [list(d) for d in getattr(solver.ops, "level_dims",
                                                 ())],
-        # the hybrid level combine (gather vs scatter) also reorders the
-        # slot accumulation — pinned on the ops at construction; KD (the
-        # dense/heavy split of the gather maps) reorders it too and is
-        # frozen in the partition's built maps
-        "combine": getattr(solver.ops, "combine", "n/a"),
         "combine_kd": _combine_kd(solver),
         # the general-form f64 refresh (hybrid+mixed) reorders the
         # refresh-residual summation — pinned on the solver at
@@ -195,22 +182,42 @@ def _mg_shape(solver):
 
 
 def _combine_kd(solver) -> int | str:
-    # only meaningful when the gather combine is the engaged path (KD
-    # does not touch scatter-mode numerics)
-    if getattr(solver.ops, "combine", "n/a") != "gather":
-        return "n/a"
+    # only hybrid partitions with level grids have combine maps
     cm = getattr(getattr(solver, "pm", None), "combine", None)
     return cm.kd if cm is not None else "n/a"
 
 
-def _effective_kernel(solver) -> str:
-    """The variant this solver COMPILED (pinned at construction — the env
-    knob is read at trace time, so the env at save() time is irrelevant),
-    gated on an f32 matvec path actually existing."""
-    if not (getattr(solver.ops, "use_pallas", False)
-            and (solver.mixed or np.dtype(solver.dtype) == np.float32)):
-        return "off"
-    return getattr(solver, "pallas_variant", "off")
+#: fingerprint entries that older checkpoints recorded for matvec paths
+#: the program no longer has -> (the path's name, the recorded values
+#: that mean the path that stays).  False is the XLA stencil before
+#: kernel variants were named; "n/a" a backend without the knob.
+_REMOVED_PATHS = {
+    "pallas": ("the Pallas matvec kernel", ("off", False)),
+    "matvec_form": ("the corner/gsplit stencil forms", ("gse", "n/a")),
+    "combine": ("the hybrid scatter combine", ("gather", "n/a")),
+}
+
+
+def _refuse_removed_paths(saved: dict, want: dict) -> None:
+    """Drop the removed paths' entries from a saved fingerprint, and
+    refuse a checkpoint that one of them wrote: each summed in its own
+    order, so no exact resume exists.  A missing entry predates its
+    knob and is not checked (the level_dims precedent), except that
+    before the combine and its KD were recorded only the scatter combine
+    existed."""
+    if ("combine_kd" not in saved
+            and want.get("combine_kd", "n/a") != "n/a"):
+        saved["combine"] = "scatter"
+    if saved.get("combine") == "scatter" and not saved.get("level_dims"):
+        # no level grids: the scatter combine looped over nothing
+        saved["combine"] = "n/a"
+    for key, (path, kept) in _REMOVED_PATHS.items():
+        value = saved.pop(key, kept[0])
+        if value not in kept:
+            raise ValueError(
+                f"checkpoint was written by {path} ({key}={value!r}), "
+                f"which this program no longer has; it cannot resume "
+                f"exactly")
 
 
 def state_dict(solver) -> dict:
@@ -346,13 +353,6 @@ class CheckpointManager:
                 return None
         with np.load(self._ckpt_file(t)) as z:
             saved = json.loads(bytes(z["fingerprint"]).decode())
-            # Checkpoints written before the pallas field existed can only
-            # have come from the XLA matvec path; a bool False predates
-            # the variant-name format and also means the XLA path.  (A
-            # bool True is left as-is: the variant it ran is unknown, so
-            # the mismatch error is the correct outcome.)
-            if saved.get("pallas", False) is False:
-                saved["pallas"] = "off"
             # Checkpoints written before the precond field existed can only
             # have come from the scalar-Jacobi path.
             saved.setdefault("precond", "jacobi")
@@ -366,20 +366,13 @@ class CheckpointManager:
             # have come from the single-RHS paths.
             saved.setdefault("nrhs", 1)
             want = _fingerprint(solver)
-            # Checkpoints that predate the stencil-form/level-dims fields
-            # did not record which formulation/layout produced them (the
-            # corner form and block tiling existed briefly before the
-            # fields did), so their historical values are unknowable —
-            # skip BOTH checks for legacy checkpoints rather than guess.
-            saved.setdefault("matvec_form", want["matvec_form"])
+            # Checkpoints that predate the level-dims field did not
+            # record which layout produced them (block tiling existed
+            # briefly before the field did), so the historical value is
+            # unknowable — skip the check rather than guess.
             saved.setdefault("level_dims", want["level_dims"])
-            # pre-combine checkpoints are NOT ambiguous: only the scatter
-            # path existed, so a gather-mode resume must mismatch loudly
-            if "combine" not in saved:
-                saved["combine"] = ("scatter" if want["combine"] != "n/a"
-                                    else "n/a")
-                saved["combine_kd"] = "n/a" if saved["combine"] == "n/a" \
-                    else want["combine_kd"]
+            _refuse_removed_paths(saved, want)
+            saved.setdefault("combine_kd", want["combine_kd"])
             # pre-f64_refresh checkpoints can only have come from the
             # stencil formulation (the general form did not exist)
             saved.setdefault("f64_refresh", "stencil")
@@ -388,7 +381,7 @@ class CheckpointManager:
             # knobs although the knobs themselves already existed —
             # their historical values are unknowable, so skip the new
             # checks for legacy checkpoints rather than guess (the
-            # matvec_form precedent above).
+            # level_dims precedent above).
             for k in ("dot_dtype", "max_stag_steps", "inner_tol",
                       "mixed_knobs", "trace_len", "n_procs"):
                 saved.setdefault(k, want[k])
@@ -618,6 +611,8 @@ class SnapshotStore:
         ``resilience.distributed.GroupSnapshotStore``)."""
         if self.fingerprint is None:
             return
+        if "level_dims" in self.fingerprint:
+            _refuse_removed_paths(saved, self.fingerprint)
         # snapshots written before the nrhs field existed can only have
         # come from the width-1 scalar paths (same back-compat shim as
         # CheckpointManager.restore — without it every pre-existing

@@ -61,43 +61,6 @@ def test_ladder_cpu_fallback_is_small(monkeypatch):
     assert bench._ladder("octree", True) == [(0, 0, 0, 6, 4)]
 
 
-def test_matvec_form_pinned_on_stencil_ops(monkeypatch):
-    """The form attribute lives on the stencil ops (pinned at their
-    construction); the general Ops never carries one — the single rule
-    bench reporting and checkpoint fingerprints both read."""
-    _clear_bench_env(monkeypatch)
-    from pcg_mpi_solver_tpu.ops.matvec import Ops
-    from pcg_mpi_solver_tpu.parallel.hybrid import HybridOps
-    from pcg_mpi_solver_tpu.parallel.structured import StructuredOps
-
-    import dataclasses
-
-    import pytest as _pytest
-
-    from pcg_mpi_solver_tpu.models import make_cube_model
-    from pcg_mpi_solver_tpu.parallel.structured import partition_structured
-
-    base = dict(n_loc=3, n_iface=0)
-    sp = partition_structured(make_cube_model(4, 2, 2), 1)
-    # env fallback: the knob is resolved at construction...
-    monkeypatch.setenv("PCG_TPU_MATVEC_FORM", "corner")
-    ops = StructuredOps.from_partition(sp)
-    assert ops.form == "corner"
-    # ...and pinned: a later env flip does not move it
-    monkeypatch.setenv("PCG_TPU_MATVEC_FORM", "gse")
-    assert ops.form == "corner"
-    # explicit pin beats the env
-    assert StructuredOps.from_partition(sp, form="gse").form == "gse"
-    assert HybridOps(**base, form="gse").form == "gse"
-    # the general Ops never carries a form
-    assert getattr(Ops(**base), "form", "n/a") == "n/a"
-    # typo'd pins are rejected, not silently run as gse
-    with _pytest.raises(ValueError, match="form"):
-        StructuredOps.from_partition(sp, form="Corner")
-    with _pytest.raises(ValueError, match="form"):
-        dataclasses.replace(ops, form="croner")
-
-
 def test_probe_retry_waits_out_timeouts(monkeypatch):
     """Transient init timeouts are retried across the budget (one 180 s
     attempt would lose the round's artifact)."""

@@ -252,9 +252,12 @@ def test_kill_and_resume_mid_solve_parity(tmp_path, model):
 
 
 def test_resume_rejects_flipped_stencil_knobs(tmp_path):
-    """The matvec form and hybrid block layout change the stencil's
-    summation order (same exact-resume hazard as the Pallas variants):
-    a resume under a flipped knob must be refused, not silently drift."""
+    """The hybrid block layout changes the stencil's summation order: a
+    resume under a flipped layout must be refused, not silently drift.
+    A checkpoint recorded by a removed matvec path (a stencil form, the
+    Pallas kernel, the scatter combine) is refused by the path's name;
+    one whose record names the paths that stay restores."""
+    import json
     import os
 
     from pcg_mpi_solver_tpu.models.octree import make_octree_model
@@ -270,10 +273,19 @@ def test_resume_rejects_flipped_stencil_knobs(tmp_path):
         return Solver(model, cfg, mesh=make_mesh(1), n_parts=1,
                       backend="hybrid")
 
-    prev = {k: os.environ.get(k)
-            for k in ("PCG_TPU_MATVEC_FORM", "PCG_TPU_HYBRID_BLOCK")}
+    def record(**entries):
+        """Rewrite the saved fingerprint with ``entries`` added."""
+        path = mgr._ckpt_file(1)
+        with np.load(path) as z:
+            payload = {k: z[k] for k in z.files}
+        fp = json.loads(bytes(payload["fingerprint"]).decode())
+        fp.update(entries)
+        payload["fingerprint"] = np.frombuffer(
+            json.dumps(fp, sort_keys=True).encode(), dtype=np.uint8)
+        np.savez(path, **payload)
+
+    prev = os.environ.get("PCG_TPU_HYBRID_BLOCK")
     try:
-        os.environ.pop("PCG_TPU_MATVEC_FORM", None)
         os.environ["PCG_TPU_HYBRID_BLOCK"] = "2"
         s = build()
         s.step(1.0)
@@ -282,19 +294,25 @@ def test_resume_rejects_flipped_stencil_knobs(tmp_path):
         # same env: restores fine
         assert mgr.restore(build(), 1) == 1
 
-        # flipped form: refused
-        os.environ["PCG_TPU_MATVEC_FORM"] = "corner"
-        with pytest.raises(ValueError, match="matvec_form"):
-            mgr.restore(build(), 1)
-        os.environ.pop("PCG_TPU_MATVEC_FORM", None)
+        # the paths that stay, as older checkpoints recorded them
+        record(matvec_form="gse", pallas="off", combine="gather")
+        assert mgr.restore(build(), 1) == 1
+
+        # a removed stencil form, kernel or combine: refused by name
+        for entry, path in (({"matvec_form": "corner"}, "corner/gsplit"),
+                            ({"pallas": "v6"}, "Pallas"),
+                            ({"combine": "scatter"}, "scatter combine")):
+            record(**entry)
+            with pytest.raises(ValueError, match=path):
+                mgr.restore(build(), 1)
+            record(matvec_form="gse", pallas="off", combine="gather")
 
         # flipped block layout: refused
         os.environ["PCG_TPU_HYBRID_BLOCK"] = "1000000"
         with pytest.raises(ValueError, match="level_dims"):
             mgr.restore(build(), 1)
     finally:
-        for k, v in prev.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+        if prev is None:
+            os.environ.pop("PCG_TPU_HYBRID_BLOCK", None)
+        else:
+            os.environ["PCG_TPU_HYBRID_BLOCK"] = prev

@@ -6,7 +6,7 @@ made live: DiagM/Vd/Cm/Me/dt, partition_mesh.py:324-330, 172-175)."""
 import numpy as np
 import pytest
 
-from pcg_mpi_solver_tpu import RunConfig, SolverConfig
+from pcg_mpi_solver_tpu import RunConfig
 from pcg_mpi_solver_tpu.models import make_cube_model
 from pcg_mpi_solver_tpu.parallel.mesh import make_mesh
 from pcg_mpi_solver_tpu.solver.dynamics import DynamicsSolver, stable_dt
@@ -116,23 +116,3 @@ def test_dynamics_hybrid_matches_general():
     scale = max(np.abs(out["general"]).max(), 1e-30)
     np.testing.assert_allclose(out["hybrid"], out["general"],
                                rtol=0, atol=1e-11 * scale)
-
-
-def test_dynamics_pallas_interpret_routes_interpreter():
-    """pallas='interpret' must reach the HybridOps built by
-    select_time_backend with pallas_interpret=True — otherwise a CPU CI
-    run would attempt a real Mosaic lowering on the first step (the
-    regression this guards: the quasi-static driver was updated but the
-    dynamics backend factory was not)."""
-    from pcg_mpi_solver_tpu.models.octree import make_octree_model
-
-    model = make_octree_model(3, 3, 3, max_level=2, n_incl=2, seed=5,
-                              load="traction", load_value=1e6)
-    cfg = RunConfig(solver=SolverConfig(dtype="float32",
-                                        pallas="interpret"))
-    s = DynamicsSolver(model, cfg, mesh=make_mesh(1), n_parts=1,
-                       backend="hybrid")
-    assert s.ops.use_pallas and s.ops.pallas_interpret
-    assert any(s.ops.pallas_levels)
-    r = s.run(2)                    # two explicit steps through the kernel
-    assert np.all(np.isfinite(np.asarray(r.u)))

@@ -272,36 +272,124 @@ def test_merged_levels_match_unmerged(model):
     assert np.abs(b_m - b_u).max() / (np.abs(b_u).max() + 1e-30) < 1e-12
 
 
+def _np_level_grid(ck, corner_vals):
+    """numpy pad-translate of per-cell corner values onto a level's node
+    lattice: ck (nb, bx, by, bz); corner_vals(a) -> (nb, bx, by, bz, w)
+    for corner a.  Returns (nb*(bx+1)*(by+1)*(bz+1), w) rows in nidx
+    order."""
+    from pcg_mpi_solver_tpu.parallel.hybrid import _CORNERS
+
+    nb, bx, by, bz = ck.shape
+    g = None
+    for a, (dx, dy, dz) in enumerate(_CORNERS):
+        c = corner_vals(a)
+        if g is None:
+            g = np.zeros((nb, bx + 1, by + 1, bz + 1, c.shape[-1]))
+        g[:, dx:dx + bx, dy:dy + by, dz:dz + bz] += c
+    return g.reshape(-1, g.shape[-1])
+
+
+def _np_level_matvec(Ke, ck, xg):
+    """numpy element loop of the brick stencil on one block batch:
+    xg (nb, bx+1, by+1, bz+1, 3) node values -> node lattice rows."""
+    from pcg_mpi_solver_tpu.parallel.hybrid import _CORNERS
+
+    nb, bx, by, bz = ck.shape
+    u = np.concatenate([xg[:, dx:dx + bx, dy:dy + by, dz:dz + bz]
+                        for dx, dy, dz in _CORNERS], axis=-1)
+    v = (ck[..., None] * u) @ np.asarray(Ke).T         # (nb, ..., 24)
+    return _np_level_grid(ck, lambda a: v[..., 3 * a:3 * a + 3])
+
+
+def _np_add_rows(n_node, pairs):
+    """np.add.at of (node ids, rows) pairs into (n_node, w); ids at or
+    past n_node (padding) drop."""
+    w = pairs[0][1].shape[-1]
+    pad = max(n_node, *(int(ids.max()) + 1 for ids, _ in pairs))
+    y = np.zeros((pad, w))
+    for ids, rows in pairs:
+        np.add.at(y, ids, rows)
+    return y[:n_node]
+
+
 def test_combine_gather_matches_scatter(pair):
-    """The scatter-free gather-combine (default) vs the row scatter —
-    identical matvec and diag up to f64 summation-order noise."""
-    import dataclasses
+    """The gather combine against an independent numpy np.add.at sum of
+    the same level and transition rows into their nodes, for matvec,
+    diag, node blocks and nodal averaging."""
+    from pcg_mpi_solver_tpu.parallel.hybrid import _CORNERS
 
     _, (ops_h, data_h), pm_g, hp = pair
-    assert ops_h.combine == "gather" and "combine" in data_h
-    ops_s = dataclasses.replace(ops_h, combine="scatter")
-    P = pm_g.n_parts
+    assert "combine" in data_h and ops_h._trans_in_combine(data_h)
+    P, nn = hp.pm.n_parts, hp.pm.n_node_loc
+    Ke = np.asarray(data_h["brick_Ke"])
+    dk = np.asarray(data_h["brick_diag"])
     rng = np.random.default_rng(11)
-    x = jnp.asarray(rng.normal(size=(P, pm_g.n_loc)))
-    yg = np.asarray(ops_h.matvec(data_h, x))
-    ys = np.asarray(ops_s.matvec(data_h, x))
-    np.testing.assert_allclose(yg, ys, rtol=0,
-                               atol=1e-12 * np.abs(ys).max())
-    dg = np.asarray(ops_h.diag(data_h))
-    ds = np.asarray(ops_s.diag(data_h))
-    np.testing.assert_allclose(dg, ds, rtol=0,
-                               atol=1e-12 * np.abs(ds).max())
-    # node-block preconditioner assembly and nodal averaging share the
-    # combine; the scatter branches must stay live-equivalent too
-    bg = np.asarray(ops_h._node_block_local(data_h))
-    bs_ = np.asarray(ops_s._node_block_local(data_h))
-    np.testing.assert_allclose(bg, bs_, rtol=0,
-                               atol=1e-12 * np.abs(bs_).max())
-    eg = ops_h.elem_strain(data_h, x)
-    ag = np.asarray(ops_h.nodal_average(data_h, eg))
-    as_ = np.asarray(ops_s.nodal_average(data_h, ops_s.elem_strain(data_h, x)))
-    np.testing.assert_allclose(ag, as_, rtol=0,
-                               atol=1e-11 * max(np.abs(as_).max(), 1e-30))
+    x = rng.normal(size=(P, pm_g.n_loc))
+    xj = jnp.asarray(x)
+    rows_trans = [np.asarray(r) for r in ops_h._element_rows(data_h, xj)]
+    springs = np.asarray(ops_h._apply_springs(data_h, xj, jnp.zeros_like(xj)))
+    diag_t = np.asarray(Ops.diag_local(ops_h, data_h))
+    blocks_t = np.asarray(Ops._node_block_local(ops_h, data_h))
+    for p in range(P):
+        x3 = np.concatenate([x[p].reshape(nn, 3), np.zeros((1, 3))])
+        mv, dg, nbk = [], [], []
+        for lv in hp.levels:
+            ck = lv.ck[p]
+            nb, bx, by, bz = ck.shape
+            nid = lv.nidx[p].reshape(-1)
+            xg = x3[np.minimum(nid, nn)].reshape(nb, bx + 1, by + 1, bz + 1,
+                                                  3)
+            mv.append((nid, _np_level_matvec(Ke, ck, xg)))
+            dg.append((nid, _np_level_grid(
+                ck, lambda a: ck[..., None] * dk[3 * a:3 * a + 3])))
+            nbk.append((nid, _np_level_grid(
+                ck, lambda a: ck[..., None]
+                * Ke[3 * a:3 * a + 3, 3 * a:3 * a + 3].reshape(-1))))
+        trans = [(np.asarray(b["node"][p]).reshape(-1), r[p])
+                 for b, r in zip(data_h["blocks"], rows_trans)]
+        y_ref = _np_add_rows(nn, mv + trans).reshape(-1) + springs[p]
+        y = np.asarray(ops_h.matvec_local(data_h, xj))[p]
+        np.testing.assert_allclose(y, y_ref, rtol=0,
+                                   atol=1e-12 * np.abs(y_ref).max())
+        d_ref = _np_add_rows(nn, dg).reshape(-1) + diag_t[p]
+        d = np.asarray(ops_h.diag_local(data_h))[p]
+        np.testing.assert_allclose(d, d_ref, rtol=0,
+                                   atol=1e-12 * np.abs(d_ref).max())
+        b_ref = _np_add_rows(nn, nbk).reshape(nn, 3, 3) \
+            + blocks_t[p].reshape(nn, 3, 3)
+        b = np.asarray(ops_h._node_block_local(data_h))[p].reshape(nn, 3, 3)
+        np.testing.assert_allclose(b, b_ref, rtol=0,
+                                   atol=1e-12 * np.abs(b_ref).max())
+
+    # nodal averaging: per-cell values summed with counts onto nodes
+    eps = ops_h.elem_strain(data_h, xj)
+    k = eps[0].shape[1]
+    nbl = len(data_h["blocks"])
+    joined = np.zeros((P, nn, k + 1))
+    for p in range(P):
+        pairs = []
+        for b, v in zip(data_h["blocks"], eps[:nbl]):
+            node = np.asarray(b["node"][p])                 # (nnb, N)
+            vals = np.asarray(v[p]).T                       # (N, k)
+            for a in range(node.shape[0]):
+                pairs.append((node[a], np.concatenate(
+                    [vals, np.ones((len(vals), 1))], axis=1)))
+        for lv, v in zip(hp.levels, eps[nbl:]):
+            ck = lv.ck[p]
+            nb, bx, by, bz = ck.shape
+            valid = (ck != 0)[..., None].astype(float)
+            vals = np.asarray(v[p]).reshape(k, nb, bx, by, bz) \
+                .transpose(1, 2, 3, 4, 0)
+            cell = np.concatenate([vals * valid, valid], axis=-1)
+            pairs.append((lv.nidx[p].reshape(-1),
+                          _np_level_grid(ck, lambda a: cell)))
+        joined[p] = _np_add_rows(nn, pairs)
+    both = np.asarray(ops_h.niface_assemble(
+        data_h, jnp.asarray(joined.transpose(0, 2, 1))))
+    avg_ref = both[:, :k] / (both[:, k:] + 1e-15)
+    avg = np.asarray(ops_h.nodal_average(data_h, eps))
+    np.testing.assert_allclose(avg, avg_ref, rtol=0,
+                               atol=1e-11 * max(np.abs(avg_ref).max(), 1e-30))
 
 
 def test_combine_maps_cover_every_slot_once(pair):
@@ -372,7 +460,7 @@ def test_gather_combine_matvec_has_no_scatter(pair, nrhs):
     gathers fewer rows than the node-ELL sum over every node plus the
     all-slot level combine it replaces (KD = 2, heavy rows scattered)."""
     _, (ops_h, data_h), pm_g, hp = pair
-    assert ops_h.combine == "gather" and ops_h._trans_in_combine(data_h)
+    assert ops_h._trans_in_combine(data_h)
     shape = (hp.pm.n_parts, hp.pm.n_loc) + ((nrhs,) if nrhs else ())
     x = jnp.zeros(shape)
     jaxpr = str(jax.make_jaxpr(ops_h.matvec_local)(data_h, x))
@@ -409,28 +497,22 @@ def test_auto_backend_hybrid_gate_defaults_general(model, monkeypatch):
     assert s2.backend == "hybrid"
 
 
-def test_level_stencil_matches_pallas_kernel(pair):
-    """The hybrid level stencil and the Pallas kernel share corner order:
-    identical results on a real level grid (interpret mode)."""
-    from pcg_mpi_solver_tpu.ops.pallas_matvec import structured_matvec_pallas
-
+def test_level_stencil_matches_numpy(pair):
+    """Each level grid's stencil (the part*block batch the matvec runs)
+    against a numpy element loop over that level's cells."""
     _, (ops_h, data_h), _, hp = pair
-    lv = data_h["levels"][-1]
-    nb, bx, by, bz = ops_h.level_dims[-1]
     rng = np.random.default_rng(2)
-    P = lv["ck"].shape[0]
-    B = P * nb   # the stencil operates on the part*block batch
-    xg = jnp.asarray(rng.normal(
-        size=(B, 3, bx + 1, by + 1, bz + 1)), jnp.float32)
-    Ke32 = data_h["brick_Ke"].astype(jnp.float32)
-    ck32 = lv["ck"].astype(jnp.float32).reshape(B, bx, by, bz)
-    y_xla = np.asarray(ops_h._stencil(Ke32, ck32, xg))
-    y_pal = np.stack([
-        np.asarray(structured_matvec_pallas(xg[b], ck32[b], Ke32,
-                                            interpret=True))
-        for b in range(B)])
-    np.testing.assert_allclose(y_pal, y_xla, rtol=2e-5,
-                               atol=2e-5 * max(np.abs(y_xla).max(), 1))
+    Ke = data_h["brick_Ke"]
+    assert ops_h.level_dims
+    for lv, (nb, bx, by, bz) in zip(data_h["levels"], ops_h.level_dims):
+        B = lv["ck"].shape[0] * nb
+        ck = np.asarray(lv["ck"]).reshape(B, bx, by, bz)
+        xg = rng.normal(size=(B, 3, bx + 1, by + 1, bz + 1))
+        y = np.asarray(ops_h._stencil(Ke, jnp.asarray(ck), jnp.asarray(xg)))
+        y_ref = _np_level_matvec(Ke, ck, xg.transpose(0, 2, 3, 4, 1))
+        y = y.transpose(0, 2, 3, 4, 1).reshape(-1, 3)
+        np.testing.assert_allclose(y, y_ref, rtol=0,
+                                   atol=1e-12 * np.abs(y_ref).max())
 
 
 def test_general_f64_refresh_matches_stencil(model, monkeypatch):
@@ -551,18 +633,3 @@ def test_tiled_blocks_match_dense(model):
     a_d = np.asarray(ops_d.nodal_average(data_d, e_d))
     a_t = np.asarray(ops_t.nodal_average(data_t, e_t))
     assert np.abs(a_t - a_d).max() / (np.abs(a_d).max() + 1e-30) < 1e-10
-
-
-def test_hybrid_forms_match(pair):
-    """Every stencil formulation (gse / gsplit / corner) must produce the
-    same hybrid matvec — form is pinned per-ops at construction."""
-    _, (ops_h, data_h), _, hp = pair
-    rng = np.random.default_rng(11)
-    P = data_h["eff"].shape[0]
-    x = jnp.asarray(rng.standard_normal((P, ops_h.n_loc)))
-    y_ref = np.asarray(ops_h.matvec(data_h, x))
-    scale = np.abs(y_ref).max()
-    for form in ("gsplit", "corner"):
-        ops_f = HybridOps.from_hybrid(hp, form=form)
-        y_f = np.asarray(ops_f.matvec(data_h, x))
-        assert np.abs(y_f - y_ref).max() / scale < 1e-13, form

@@ -276,8 +276,7 @@ def test_bench_detail_reports_variant():
         backend="general", ops=SimpleNamespace(),
         config=SimpleNamespace(solver=SimpleNamespace(
             pcg_variant="fused")))
-    extra = _run_config_extra(solver, "float32", "mixed", False, 1, 0.1,
-                              "cpu")
+    extra = _run_config_extra(solver, "float32", "mixed", 1, 0.1, "cpu")
     assert extra["pcg_variant"] == "fused"
 
 

@@ -128,15 +128,30 @@ def test_chunked_f64_matvec_matches_unchunked():
 def _gse_5d_local(ops, data, x):
     """The gse stencil on the 5D cell grid (z the minor axis), built from
     the ops' grid helpers: what the flat (y, z) plane must reproduce."""
+    from pcg_mpi_solver_tpu.models.element import HEX_CORNERS
+
     blk = data["blocks"][0]
     u = ops._gather_cells(ops._grid(x))
     ck = ops._ck_grid(blk["ck"])
     v = jnp.einsum("de,pexyz->pdxyz", blk["Ke"], ck[:, None] * u,
                    precision=ops.precision)
-    return ops._scatter_cells(v)
+    y = None
+    for a, (dx, dy, dz) in enumerate(HEX_CORNERS.astype(int)):
+        t = jnp.pad(v[:, 3 * a:3 * a + 3],
+                    ((0, 0), (0, 0), (dx, 1 - dx), (dy, 1 - dy),
+                     (dz, 1 - dz)))
+        y = t if y is None else y + t
+    return y
 
 
-@pytest.mark.parametrize("dims,n_parts,dtype,chunked,nrhs", [
+# the shapes the flat plane is checked at: odd, non-cubic grids, the
+# fused-kernel tests' former shapes, and (4, 11, 12), whose cell plane
+# ny*(nz+1) - 1 = 142 slots crosses a 128-lane tile
+_PATHS = {"f32": ("float32", False, 1), "f64": ("float64", False, 1),
+          "f64_chunked": ("float64", True, 1),
+          "f32_nrhs3": ("float32", False, 3),
+          "f64_nrhs3": ("float64", False, 3)}
+_FLAT_CASES = [
     ((5, 4, 3), 1, "float32", False, 1),
     ((5, 4, 3), 1, "float64", False, 1),
     ((6, 5, 3), 2, "float32", False, 1),
@@ -144,9 +159,22 @@ def _gse_5d_local(ops, data, x):
     ((6, 5, 3), 2, "float64", True, 1),
     ((5, 4, 3), 1, "float64", False, 3),
     ((6, 5, 3), 2, "float32", False, 3),
-], ids=["5x4x3_f32", "5x4x3_f64", "6x5x3_2parts_f32", "6x5x3_2parts_f64",
-        "6x5x3_2parts_f64_chunked", "5x4x3_f64_nrhs3",
-        "6x5x3_2parts_f32_nrhs3"])
+]
+_FLAT_IDS = ["5x4x3_f32", "5x4x3_f64", "6x5x3_2parts_f32",
+             "6x5x3_2parts_f64", "6x5x3_2parts_f64_chunked",
+             "5x4x3_f64_nrhs3", "6x5x3_2parts_f32_nrhs3"]
+for _dims, _parts in [((6, 5, 4), 1), ((6, 5, 4), 2), ((4, 4, 4), 1),
+                      ((4, 4, 4), 2), ((7, 3, 5), 1), ((4, 11, 12), 1),
+                      ((4, 11, 12), 2)]:
+    for _name, (_dt, _chunked, _nrhs) in _PATHS.items():
+        _FLAT_CASES.append((_dims, _parts, _dt, _chunked, _nrhs))
+        _FLAT_IDS.append("x".join(map(str, _dims))
+                         + (f"_{_parts}parts" if _parts > 1 else "")
+                         + f"_{_name}")
+
+
+@pytest.mark.parametrize("dims,n_parts,dtype,chunked,nrhs", _FLAT_CASES,
+                         ids=_FLAT_IDS)
 def test_flat_plane_gse_matches_5d_and_numpy(dims, n_parts, dtype, chunked,
                                              nrhs):
     """The gse matvec on the flattened (y, z) node plane against the
@@ -200,48 +228,20 @@ def test_flat_plane_gse_matches_5d_and_numpy(dims, n_parts, dtype, chunked,
                                    atol=(10 * rtol) * scale)
 
 
-@pytest.mark.parametrize("form,fill", [
-    ("gse", 4 * 5 / 128),                 # ny*nz over ceil(ny*(nz+1)-1, 128)
-    ("corner", 5 / 128),                  # nz over ceil(nz, 128)
-])
-def test_stencil_lane_fill_gauge(monkeypatch, form, fill):
-    """Structured solves record which stencil layout their matvec runs;
+@pytest.mark.parametrize("fill", [
+    4 * 5 / 128,                          # ny*nz over ceil(ny*(nz+1)-1, 128)
+], ids=["gse"])
+def test_stencil_lane_fill_gauge(fill):
+    """Structured solves record the lane fill of their stencil layout;
     the general backend records none."""
     from pcg_mpi_solver_tpu.obs.metrics import MetricsRecorder
 
-    monkeypatch.setenv("PCG_TPU_MATVEC_FORM", form)
     model = make_cube_model(8, 4, 5)
     rec = MetricsRecorder()
-    s = Solver(model, RunConfig(), mesh=make_mesh(2), n_parts=2,
-               backend="structured", recorder=rec)
-    assert s.ops.form == form
+    Solver(model, RunConfig(), mesh=make_mesh(2), n_parts=2,
+           backend="structured", recorder=rec)
     assert rec.gauges["structured.stencil_lane_fill"] == round(fill, 4)
     gen = MetricsRecorder()
     Solver(model, RunConfig(), mesh=make_mesh(2), n_parts=2,
            backend="general", recorder=gen)
     assert "structured.stencil_lane_fill" not in gen.gauges
-
-
-def test_alt_forms_match_gse():
-    """The alternative formulations (corner: fusion-friendly, no
-    (24, cells) intermediates; gsplit: concat-free accumulating einsums)
-    must produce the same matvec as the default gather/einsum/scatter
-    form to float tolerance.  The form is pinned per-ops at
-    construction, so both formulations are explicit instances."""
-    import jax.numpy as jnp
-
-    from pcg_mpi_solver_tpu.parallel.structured import (
-        StructuredOps, device_data_structured, partition_structured)
-
-    model = make_cube_model(8, 6, 4, heterogeneous=True)
-    sp = partition_structured(model, 2)
-    data = device_data_structured(sp, jnp.float64)
-    ops_gse = StructuredOps.from_partition(sp, form="gse")
-    rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.standard_normal((2, sp.n_loc)))
-    y_gse = np.asarray(ops_gse.matvec(data, x))
-    scale = np.abs(y_gse).max()
-    for form in ("corner", "gsplit"):
-        ops_f = StructuredOps.from_partition(sp, form=form)
-        y_f = np.asarray(ops_f.matvec(data, x))
-        assert np.abs(y_f - y_gse).max() / scale < 1e-13, form

@@ -1,22 +1,19 @@
 """Chipless compiles of the main path's kernels for a described TPU v5e.
 
 The installed libtpu compiles for a chip that is described, not
-attached (``jax.experimental.topologies``): what Mosaic or XLA would
+attached (``jax.experimental.topologies``): what XLA would
 refuse on the chip is refused here, at no chip time.  The topology is
 described only inside the module fixture — never at import, in a
 ``skipif``/``parametrize`` or in conftest.py — so that under xdist only
 the worker given this file loads libtpu, and every worker collects the
 same tests.
 
-Shapes: the v6 kernel's compile time grows with its cell plane (ny*nz),
-and more steeply since its dots request f32 precision (six MXU passes):
-a 64x64 plane takes ~15 s, a 40x40 plane ~4 s, a 150x150 plane over
-14 min (tools/pallas_parity.py --chipless).  The slab shapes here are
-therefore those of a 40^3 cube, on one chip and per chip of four (the
-largest near a few seconds); the hybrid level shapes are the
-single-brick levels of the bench octree at n0=12 and n0=8 (chip_smoke.py's
-phase (b)), level 4, and the 8^3 block; the XLA f32 matvec runs at the
-150^3 flagship size.
+Shapes: the slab matvec at a 40^3 cube, on one chip and per chip of
+four, and the hybrid level stencil at the single-brick levels of the
+bench octree at n0=12 and n0=8 (chip_smoke.py's phase (b)), level 4, and
+the 8^3 block; the f32 slab matvec also runs at the 150^3 flagship size.
+A fused Pallas matvec kernel was compiled here at the same shapes until
+it was deleted, having never won a measurement on the chip.
 """
 
 import os
@@ -50,57 +47,69 @@ def _sds(shape, sharding, dtype=np.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _compile_v6(cells, sharding, monkeypatch):
-    """Compile the default Pallas kernel, as the solver dispatches it
-    (under x64, as the mixed solve runs), at one (nx, ny, nz) cell grid;
-    returns the compiled text."""
-    import jax
-
-    from pcg_mpi_solver_tpu.ops.pallas_matvec import selected_variant
-
-    monkeypatch.delenv("PCG_TPU_PALLAS_V", raising=False)
-    monkeypatch.delenv("PCG_TPU_PALLAS_PLANES", raising=False)
-    name, kernel = selected_variant()
-    assert name == "v6"
-    nodes = (3,) + tuple(c + 1 for c in cells)
-    compiled = jax.jit(lambda x, ck, ke: kernel(x, ck, ke)).lower(
-        _sds(nodes, sharding), _sds(cells, sharding),
-        _sds((24, 24), sharding)).compile()
-    return compiled.as_text()
-
-
-@pytest.mark.parametrize("cells", [(40, 40, 40), (10, 40, 40)],
-                         ids=["cube40_one_chip", "cube40_slab_of_four"])
-def test_pallas_v6_slab_compiles_for_v5e(one_chip, cells, monkeypatch):
-    text = _compile_v6(cells, one_chip, monkeypatch)
-    assert "tpu_custom_call" in text
-
-
-@pytest.mark.parametrize("cells", [(22, 24, 24), (16, 16, 16), (8, 8, 8)],
-                         ids=["level_22x24x24", "level_16", "block_8"])
-def test_pallas_v6_hybrid_level_compiles_for_v5e(one_chip, cells,
-                                                 monkeypatch):
-    text = _compile_v6(cells, one_chip, monkeypatch)
-    assert "tpu_custom_call" in text
-
-
-def test_structured_f32_matvec_program_compiles_for_v5e(one_chip):
-    """The XLA gse slab matvec in f32 at the 150^3 flagship, the form
-    every structured f32 iteration runs when the kernel is off."""
+def _slab_ops(cells):
+    """StructuredOps of an (nx, ny, nz) one-part slab, for shapes only."""
     import dataclasses
 
-    import jax
     import jax.numpy as jnp
 
     from pcg_mpi_solver_tpu.models import make_cube_model
     from pcg_mpi_solver_tpu.parallel.structured import (
         StructuredOps, partition_structured)
 
-    n = 150
+    nx, ny, nz = cells
     sp = partition_structured(make_cube_model(4, 4, 4), 1)
-    ops = dataclasses.replace(
+    return dataclasses.replace(
         StructuredOps.from_partition(sp, dot_dtype=jnp.float32),
-        nxc=n, ny=n, nz=n)
+        nxc=nx, ny=ny, nz=nz)
+
+
+@pytest.mark.parametrize("cells", [(40, 40, 40), (10, 40, 40)],
+                         ids=["cube40_one_chip", "cube40_slab_of_four"])
+def test_f32_slab_matvec_compiles_for_v5e(one_chip, cells):
+    """The XLA f32 slab matvec (flat (y, z) plane) at a 40^3 cube."""
+    import jax
+
+    nx, ny, nz = cells
+    ops = _slab_ops(cells)
+    n_loc = 3 * (nx + 1) * (ny + 1) * (nz + 1)
+
+    def fn(x, ck, ke):
+        return ops.matvec_local({"blocks": [{"ck": ck, "Ke": ke}]}, x)
+
+    text = jax.jit(fn).lower(
+        _sds((1, n_loc), one_chip), _sds((1, nx, ny * (nz + 1) - 1), one_chip),
+        _sds((24, 24), one_chip)).compile().as_text()
+    assert "dot(" in text or "convolution(" in text
+    assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("cells", [(22, 24, 24), (16, 16, 16), (8, 8, 8)],
+                         ids=["level_22x24x24", "level_16", "block_8"])
+def test_f32_level_stencil_compiles_for_v5e(one_chip, cells):
+    """The hybrid level stencil in f32 on one level grid, as the matvec
+    runs it on the part*block batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from pcg_mpi_solver_tpu.parallel.hybrid import HybridOps
+
+    ops = HybridOps(n_loc=3, n_iface=0, dot_dtype=jnp.float32)
+    nodes = (1, 3) + tuple(c + 1 for c in cells)
+    text = jax.jit(ops._stencil).lower(
+        _sds((24, 24), one_chip), _sds((1,) + cells, one_chip),
+        _sds(nodes, one_chip)).compile().as_text()
+    assert "dot(" in text or "convolution(" in text
+    assert "tpu_custom_call" not in text
+
+
+def test_structured_f32_matvec_program_compiles_for_v5e(one_chip):
+    """The XLA gse slab matvec in f32 at the 150^3 flagship, the form
+    every structured f32 iteration runs."""
+    import jax
+
+    n = 150
+    ops = _slab_ops((n, n, n))
     n_loc = 3 * (n + 1) ** 3
 
     def fn(x, ck, ke, diag_ke, eff, weight):
@@ -176,7 +185,6 @@ def test_hybrid_f32_matvec_is_scatter_free_for_v5e(one_chip):
                               seed=2)
     hp = partition_hybrid(model, 1)
     ops = HybridOps.from_hybrid(hp, dot_dtype=jnp.float32)
-    assert ops.combine == "gather"
     data = jax.tree.map(
         lambda a: _sds(a.shape, one_chip, a.dtype),
         device_data_hybrid(hp, jnp.float32))

@@ -1,17 +1,15 @@
-"""CHIPLESS TPU compile checks: run the full Mosaic/XLA v5e compile
+"""CHIPLESS TPU compile checks: run the full XLA v5e compile
 locally, with no device.
 
 ``jax.experimental.topologies`` + the locally installed libtpu give the
 chip's own compile pipeline for a described (not attached) v5e — so
-Pallas lowering rejections and XLA buffer-assignment failures reproduce
-here instead of costing chip time.  tests/test_tpu_compile.py keeps the
-main path's kernels compiled this way in the test suite.
+XLA buffer-assignment failures reproduce here instead of costing chip
+time.  tests/test_tpu_compile.py keeps the main path's matvecs compiled
+this way in the test suite.
 
 Usage:
-    python tools/aot_compile_check.py kernel [--variants 6,7] [--nx 150]
     python tools/aot_compile_check.py f64matvec [--nx 150]
 
-``kernel``    — Pallas matvec variants at small + given shape.
 ``f64matvec`` — the XLA chunked f64 matvec at the given shape (the
                 remote-compile failure mode of the f64-direct anchor).
 ``pcg``       — the FULL f64 PCG while_loop program at the given shape.
@@ -60,27 +58,6 @@ def _compile(fn, shapes_dtypes, sharding, label):
     return _compile_structs(
         fn, [jax.ShapeDtypeStruct(s, d, sharding=sharding)
              for s, d in shapes_dtypes], label)
-
-
-def check_kernel(args):
-    import jax.numpy as jnp
-
-    from pcg_mpi_solver_tpu.ops import pallas_matvec as pm
-
-    s = _topo_sharding()
-    nx = args.nx
-    ok = True
-    for v in [int(x) for x in args.variants.split(",")]:
-        fn = getattr(pm, "structured_matvec_pallas_v%d" % v
-                     if v > 1 else "structured_matvec_pallas")
-        for dims in [(8, 6, 5), (nx, nx, nx)]:
-            nxn = tuple(d + 1 for d in dims)
-            ok &= _compile(
-                lambda xg, ck, Ke, f=fn: f(xg, ck, Ke),
-                [((3,) + nxn, jnp.float32),
-                 (dims, jnp.float32), ((24, 24), jnp.float32)],
-                s, f"v{v} {dims}")
-    return ok
 
 
 def check_f64matvec(args):
@@ -136,8 +113,7 @@ def _hybrid_setup(args):
                          load="traction", load_value=1e6)
     t0 = time.perf_counter()
     hp = partition_hybrid(model, 1)
-    ops = HybridOps.from_hybrid(hp, dot_dtype=jnp.float64,
-                                use_pallas=args.pallas == "on")
+    ops = HybridOps.from_hybrid(hp, dot_dtype=jnp.float64)
     data = device_data_hybrid(hp, jnp.float32)
     print(f"# octree {model.n_dof} dofs, {len(hp.levels)} levels "
           f"(partition {time.perf_counter()-t0:.0f}s)", flush=True)
@@ -285,10 +261,7 @@ def check_genamul64(args):
 def check_cubecycle(args):
     """Chunked inner-cycle program for the STRUCTURED (cube) flagship —
     the program bench.py compiles at 150^3 (10.33M dofs > 4M engages the
-    chunked path): warm resumable pcg over the slab stencil.  With
-    ``--dtype float32 --pallas on`` this is the v6-FUSED chunked cycle,
-    which has never been compiled anywhere (round 3 verified the fused
-    ONE-SHOT program only)."""
+    chunked path): warm resumable pcg over the slab stencil."""
     import dataclasses
 
     import jax
@@ -308,8 +281,7 @@ def check_cubecycle(args):
     model = make_cube_model(4, 4, 4)
     sp = partition_structured(model, 1)
     ops = dataclasses.replace(
-        StructuredOps.from_partition(sp, dot_dtype=jnp.float64,
-                                     use_pallas=args.pallas == "on"),
+        StructuredOps.from_partition(sp, dot_dtype=jnp.float64),
         nxc=n, ny=n, nz=n)
     nn = n + 1
     n_loc = 3 * nn * nn * nn
@@ -334,31 +306,22 @@ def check_cubecycle(args):
               sds((24, 24), dt), sds((24,), dt), sds((1, n_loc), dt),
               sds((1, n_loc), dt), sds((1, n_loc), dt), sds((1, n_loc), dt),
               carry, sds((), jnp.int32)]
-    label = (f"{args.dtype} CHUNKED cycle"
-             + (" +pallas" if args.pallas == "on" else "") + f" {n}^3")
+    label = f"{args.dtype} CHUNKED cycle {n}^3"
     return _compile_structs(fn, shapes, label)
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("what", choices=["kernel", "f64matvec", "pcg",
+    ap.add_argument("what", choices=["f64matvec", "pcg",
                                      "hybridpcg", "hybridcycle",
                                      "hybridamul64", "genamul64",
                                      "bktamul64", "cubecycle"])
-    ap.add_argument("--variants", default="6,7")
     ap.add_argument("--nx", type=int, default=None,
                     help="cells per edge (default: 150; hybridpcg: 22 "
                          "octree base cells)")
     ap.add_argument("--dtype", default="float64",
                     help="f64matvec/pcg input dtype")
-    ap.add_argument("--pallas", default="off", choices=["off", "on"],
-                    help="pcg mode: engage the fused Pallas matvec")
     args = ap.parse_args()
-    if args.what in ("pcg", "cubecycle") and args.pallas == "on" \
-            and args.dtype != "float32":
-        # the pallas dispatch is f32-gated (structured.matvec_local);
-        # with f64 inputs the flag would silently validate the XLA path
-        ap.error("--pallas on requires --dtype float32")
     if args.nx is None and args.what not in ("hybridpcg", "hybridcycle",
                                              "hybridamul64", "genamul64",
                                              "bktamul64"):
@@ -374,7 +337,7 @@ def main():
         import jax
 
         jax.config.update("jax_enable_x64", True)
-    ok = {"kernel": check_kernel, "f64matvec": check_f64matvec,
+    ok = {"f64matvec": check_f64matvec,
           "pcg": check_pcg, "hybridpcg": check_hybridpcg,
           "hybridcycle": check_hybridcycle,
           "hybridamul64": check_hybridamul64,
@@ -390,8 +353,7 @@ def check_pcg(args):
     """Compile the FULL PCG while_loop program (matvec + fused dots +
     preconditioner + convergence control) at the given size — the actual
     program whose REMOTE compile failed UNAVAILABLE at 150^3/128^3 f64
-    in waves 2-3.  With --dtype float32 --pallas on this is the HEADLINE
-    mixed-mode inner program with the fused v6 kernel engaged."""
+    in waves 2-3."""
     import jax.numpy as jnp
 
     from pcg_mpi_solver_tpu.models import make_cube_model
@@ -407,8 +369,7 @@ def check_pcg(args):
     import dataclasses
 
     ops = dataclasses.replace(
-        StructuredOps.from_partition(sp, dot_dtype=jnp.float64,
-                                     use_pallas=args.pallas == "on"),
+        StructuredOps.from_partition(sp, dot_dtype=jnp.float64),
         nxc=n, ny=n, nz=n)
     nn = n + 1
     n_loc = 3 * nn * nn * nn
@@ -423,8 +384,7 @@ def check_pcg(args):
     shapes = [((1, n_loc), dt), ((1, n, n * nn - 1), dt), ((24, 24), dt),
               ((24,), dt), ((1, n_loc), dt), ((1, n_loc), dt),
               ((1, n_loc), dt), ((1, n_loc), dt)]
-    label = (f"{args.dtype} PCG program"
-             + (" +pallas" if args.pallas == "on" else "") + f" {n}^3")
+    label = f"{args.dtype} PCG program {n}^3"
     return _compile(fn, shapes, s, label)
 
 
@@ -433,8 +393,8 @@ def check_pcg(args):
 def check_hybridpcg(args):
     """Compile the hybrid (octree) f32 PCG program at a REAL graded-octree
     flagship partition — the program whose REMOTE compile failed
-    UNAVAILABLE in wave 1 (then under the scatter combine; the gather
-    combine is now default).  Builds the real partition (cached model),
+    UNAVAILABLE in wave 1 (then under a scatter combine the gather
+    combine has since replaced).  Builds the real partition (cached model),
     converts the device-data pytree to ShapeDtypeStructs, compiles
     chiplessly."""
     import jax
@@ -457,8 +417,7 @@ def check_hybridpcg(args):
                          load="traction", load_value=1e6)
     t0 = time.perf_counter()
     hp = partition_hybrid(model, 1)
-    ops = HybridOps.from_hybrid(hp, dot_dtype=jnp.float64,
-                                use_pallas=args.pallas == "on")
+    ops = HybridOps.from_hybrid(hp, dot_dtype=jnp.float64)
     data = device_data_hybrid(hp, jnp.float32)
     print(f"# octree {model.n_dof} dofs, {len(hp.levels)} levels "
           f"(partition {time.perf_counter()-t0:.0f}s)", flush=True)
@@ -473,8 +432,7 @@ def check_hybridpcg(args):
         return r.x, r.flag, r.relres, r.iters
 
     vec = jax.ShapeDtypeStruct((1, n_loc), jnp.float32, sharding=s)
-    label = (f"hybrid f32 PCG octree {n0}^3/L4"
-             + (" +pallas" if args.pallas == "on" else ""))
+    label = f"hybrid f32 PCG octree {n0}^3/L4"
     return _compile_structs(fn, [structs, vec, vec, vec], label)
 
 
