@@ -41,7 +41,11 @@ from pcg_mpi_solver_tpu.config import PCG_VARIANTS
 # 5: the structured gse stencil runs on the flattened (y, z) node plane
 #    and its device ``ck`` is the flat cell plane; an AOT step program
 #    exported for the 5D stencil must not be replayed.
-CACHE_SCHEMA = 5
+# 6: the hybrid CombineMaps' heavy residual became banded (flat
+#    (P, sum(bands)) slot ids over the heavy nodes sorted by count, and
+#    the bands' lengths); a cached partition holding the rectangular
+#    (P, KE, H) maps must not reach the banded matvec.
+CACHE_SCHEMA = 6
 
 # Monkeypatchable in tests to simulate a package-version bump without
 # editing the package.

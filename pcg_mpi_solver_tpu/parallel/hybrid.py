@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -92,8 +92,15 @@ class CombineMaps:
     PARTITION time and composed into direct per-node source-slot indices.
     At solve time the element->node accumulation (reference
     pcg_solver.py:300's bincount) is gathers only: KD dense row gathers
-    for every node, KE row gathers for the H heavy nodes, and one gather
-    that adds each heavy node's sum back by its ``hpos``.
+    for every node, one gather per heavy band, and one gather that adds
+    each heavy node's sum back by its ``hpos``.
+
+    Heavy nodes (more than KD slots) are listed by descending slot
+    count, ties by ascending node id, so the nodes with more than
+    KD + k slots are a prefix of the list: band k gathers only that
+    prefix, ``bands[k]`` rows (the most any part has), and each heavy
+    node gathers as many rows as it has slots past KD, not as many as
+    the heaviest node has.
 
     Slot numbering: levels in list order, each level flat over
     (block, lattice pos) exactly as its ``nidx``; then, when the
@@ -107,8 +114,10 @@ class CombineMaps:
 
     n_slots: int                # level slots + transition rows (zero row = pad)
     gidx: np.ndarray            # (P, KD, n_node_loc) int32 slot ids
-    hgidx: np.ndarray           # (P, KE, H) int32 slot ids of the heavy rest
+    hgidx: np.ndarray           # (P, sum(bands)) int32 slot ids of the heavy
+                                # rest, band after band
     hpos: np.ndarray            # (P, n_node_loc) int32 heavy row (H = none)
+    bands: Tuple[int, ...]      # heavy band lengths, non-increasing; H = bands[0]
     n_dropped: int              # lattice slots of real nodes left out, all parts
 
     @property
@@ -117,16 +126,18 @@ class CombineMaps:
 
     @property
     def rows_per_matvec(self) -> int:
-        """Rows one combine gathers per part: KD*N dense, KE*H heavy,
-        and N for the heavy add-back (none without heavy nodes)."""
-        KE, H = self.hgidx.shape[1:]
+        """Rows one combine gathers per part: KD*N dense, sum(bands)
+        heavy, and N for the heavy add-back (none without heavy
+        nodes)."""
         N = self.gidx.shape[2]
-        return self.kd * N + (KE * H + N if H else 0)
+        return self.kd * N + (sum(self.bands) + N if self.bands else 0)
 
     def gauges(self) -> Dict[str, int]:
         return {"hybrid.combine_rows_per_matvec": self.rows_per_matvec,
                 "hybrid.combine_kd": self.kd,
-                "hybrid.combine_heavy_nodes": int(self.hgidx.shape[2]),
+                "hybrid.combine_heavy_nodes":
+                    self.bands[0] if self.bands else 0,
+                "hybrid.combine_heavy_rows": sum(self.bands),
                 "hybrid.combine_slots_dropped": int(self.n_dropped)}
 
 
@@ -378,17 +389,25 @@ def _live_slots(ck: np.ndarray) -> np.ndarray:
     return live.reshape(P, nb, -1)
 
 
+def _band_lengths(lens: List[np.ndarray], kd: int) -> Tuple[int, ...]:
+    """Heavy band lengths at dense width ``kd``: band k holds the nodes
+    with more than kd + k slots, as many rows as the part with the most
+    such nodes has.  ``lens``: the parts' per-node slot counts."""
+    lmax = max(int(ln.max(initial=0)) for ln in lens)
+    return tuple(max(int((ln > j).sum()) for ln in lens)
+                 for j in range(kd, lmax))
+
+
 def _pick_kd(lens: List[np.ndarray], N: int) -> int:
     """The dense width that gathers the fewest rows per combine,
-    ``KD*N + KE*H + N`` (``KD*N`` when no node is heavy), over the
+    ``KD*N + sum(bands) + N`` (``KD*N`` when no node is heavy), over the
     parts' per-node slot counts ``lens``."""
-    lmax = max(int(ln.max(initial=0)) for ln in lens)
+    h = _band_lengths(lens, 0)      # h[j]: most nodes of a part past j slots
 
     def rows(kd):
-        H = max(int((ln > kd).sum()) for ln in lens)
-        return kd * N + ((lmax - kd) * H + N if H else 0)
+        return kd * N + (sum(h[kd:]) + N if h[kd:] else 0)
 
-    return min(range(1, max(lmax, 1) + 1), key=rows)
+    return min(range(1, max(len(h), 1) + 1), key=rows)
 
 
 def build_combine_maps(levels: List[LevelGrid], n_node_loc: int, P: int,
@@ -418,23 +437,28 @@ def build_combine_maps(levels: List[LevelGrid], n_node_loc: int, P: int,
         sorted_l.append((s_s, np.cumsum(lens) - lens))
         lens_l.append(lens)
     KD = combine_kd() or _pick_kd(lens_l, n_node_loc)
-    KE = max(max(int(ln.max(initial=0)) for ln in lens_l) - KD, 0)
-    H = max(int((ln > KD).sum()) for ln in lens_l)
+    bands = _band_lengths(lens_l, KD)
+    H = bands[0] if bands else 0
+    band_off = np.cumsum((0,) + bands)
     gidx = np.full((P, KD, n_node_loc), Ns, dtype=np.int32)
-    hgidx = np.full((P, KE, H), Ns, dtype=np.int32)
+    hgidx = np.full((P, int(band_off[-1])), Ns, dtype=np.int32)
     hpos = np.full((P, n_node_loc), H, dtype=np.int32)
     for p, ((s_s, starts), lens) in enumerate(zip(sorted_l, lens_l)):
         for k in range(KD):
             sel = lens > k
             gidx[p, k, sel] = s_s[starts[sel] + k]
+        # descending count, ascending node id among equal counts: the
+        # nodes of band k are a prefix, and hpos rises with the node
+        # inside each run of equal counts
         heavy = np.flatnonzero(lens > KD)
+        heavy = heavy[np.argsort(-lens[heavy], kind="stable")]
         hpos[p, heavy] = np.arange(len(heavy))
-        for k in range(KE):
-            sel = lens[heavy] > KD + k
-            hgidx[p, k, np.flatnonzero(sel)] = \
-                s_s[starts[heavy[sel]] + KD + k]
+        for k in range(len(bands)):
+            sel = heavy[:int((lens[heavy] > KD + k).sum())]
+            hgidx[p, band_off[k]:band_off[k] + len(sel)] = \
+                s_s[starts[sel] + KD + k]
     return CombineMaps(n_slots=Ns, gidx=gidx, hgidx=hgidx, hpos=hpos,
-                       n_dropped=n_dropped)
+                       bands=bands, n_dropped=n_dropped)
 
 
 def device_data_hybrid(hp: HybridPartition, dtype=jnp.float64) -> dict:
@@ -470,6 +494,8 @@ class HybridOps(Ops):
 
     # static (nb, bx, by, bz) per level — shapes must be trace-constants
     level_dims: tuple = ()
+    # static heavy band lengths of the combine maps (CombineMaps.bands)
+    combine_bands: tuple = ()
 
     @classmethod
     def from_hybrid(cls, hp: HybridPartition, dot_dtype=jnp.float64,
@@ -482,7 +508,9 @@ class HybridOps(Ops):
                    precision=precision,
                    use_node_ell=pm.ell is not None,
                    level_dims=tuple((lv.nb, lv.bx, lv.by, lv.bz)
-                                    for lv in hp.levels))
+                                    for lv in hp.levels),
+                   combine_bands=(hp.combine.bands
+                                  if hp.combine is not None else ()))
 
     # -- level-grid primitives -----------------------------------------
     def _rows_pad(self, x):
@@ -547,11 +575,17 @@ class HybridOps(Ops):
         for k in range(cm["gidx"].shape[1]):
             t = self._take_rows(rows, cm["gidx"][:, k])
             acc = t if acc is None else acc + t
-        if cm["hgidx"].shape[2]:
-            hacc = None
-            for k in range(cm["hgidx"].shape[1]):
-                t = self._take_rows(rows, cm["hgidx"][:, k])
-                hacc = t if hacc is None else hacc + t
+        assert sum(self.combine_bands) == cm["hgidx"].shape[1], \
+            "ops built for other combine maps (HybridOps.from_hybrid)"
+        if self.combine_bands:
+            # band k adds into the first bands[k] heavy sums (static
+            # slices and zero pads: no index-driven update)
+            H, off, hacc = self.combine_bands[0], 0, None
+            for h in self.combine_bands:
+                t = self._take_rows(rows, cm["hgidx"][:, off:off + h])
+                off += h
+                hacc = t if hacc is None else hacc + jnp.pad(
+                    t, ((0, 0), (0, H - h), (0, 0)))
             # each heavy node's rest comes back by a gather: a zero row
             # after the heavy sums is every other node's hpos
             hacc = jnp.concatenate([hacc, jnp.zeros((Pn, 1, w), dt)],

@@ -159,10 +159,11 @@ def _fingerprint(solver) -> dict:
         # sums (pinned on the ops at construction — the env knobs cannot
         # drift between trace and save), and KD (the dense/heavy split of
         # the combine maps) reorders the slot accumulation, frozen in the
-        # partition's built maps
+        # partition's built maps, as are its heavy band lengths
         "level_dims": [list(d) for d in getattr(solver.ops, "level_dims",
                                                 ())],
         "combine_kd": _combine_kd(solver),
+        "combine_bands": _combine_bands(solver),
         # the general-form f64 refresh (hybrid+mixed) reorders the
         # refresh-residual summation — pinned on the solver at
         # construction like the kernel variant
@@ -181,10 +182,19 @@ def _mg_shape(solver):
             [int(v) for v in meta["dims"]]]
 
 
-def _combine_kd(solver) -> int | str:
+def _combine_maps(solver):
     # only hybrid partitions with level grids have combine maps
-    cm = getattr(getattr(solver, "pm", None), "combine", None)
+    return getattr(getattr(solver, "pm", None), "combine", None)
+
+
+def _combine_kd(solver) -> int | str:
+    cm = _combine_maps(solver)
     return cm.kd if cm is not None else "n/a"
+
+
+def _combine_bands(solver) -> list | str:
+    cm = _combine_maps(solver)
+    return [int(h) for h in cm.bands] if cm is not None else "n/a"
 
 
 #: fingerprint entries that older checkpoints recorded for matvec paths
@@ -195,6 +205,8 @@ _REMOVED_PATHS = {
     "pallas": ("the Pallas matvec kernel", ("off", False)),
     "matvec_form": ("the corner/gsplit stencil forms", ("gse", "n/a")),
     "combine": ("the hybrid scatter combine", ("gather", "n/a")),
+    "combine_heavy": ("the rectangular heavy-node combine",
+                      ("banded", "n/a")),
 }
 
 
@@ -204,13 +216,19 @@ def _refuse_removed_paths(saved: dict, want: dict) -> None:
     order, so no exact resume exists.  A missing entry predates its
     knob and is not checked (the level_dims precedent), except that
     before the combine and its KD were recorded only the scatter combine
-    existed."""
+    existed, and before its band lengths were recorded a gather
+    combine's heavy residual was one rectangle."""
     if ("combine_kd" not in saved
             and want.get("combine_kd", "n/a") != "n/a"):
         saved["combine"] = "scatter"
     if saved.get("combine") == "scatter" and not saved.get("level_dims"):
         # no level grids: the scatter combine looped over nothing
         saved["combine"] = "n/a"
+    if "combine_bands" not in saved:
+        saved["combine_heavy"] = (
+            "rectangular" if saved.get("combine_kd", "n/a") != "n/a"
+            else "n/a")
+        saved["combine_bands"] = want.get("combine_bands", "n/a")
     for key, (path, kept) in _REMOVED_PATHS.items():
         value = saved.pop(key, kept[0])
         if value not in kept:

@@ -255,8 +255,9 @@ def test_resume_rejects_flipped_stencil_knobs(tmp_path):
     """The hybrid block layout changes the stencil's summation order: a
     resume under a flipped layout must be refused, not silently drift.
     A checkpoint recorded by a removed matvec path (a stencil form, the
-    Pallas kernel, the scatter combine) is refused by the path's name;
-    one whose record names the paths that stay restores."""
+    Pallas kernel, the scatter combine, the rectangular heavy combine
+    recorded without band lengths) is refused by the path's name; one
+    whose record names the paths that stay restores."""
     import json
     import os
 
@@ -280,6 +281,9 @@ def test_resume_rejects_flipped_stencil_knobs(tmp_path):
             payload = {k: z[k] for k in z.files}
         fp = json.loads(bytes(payload["fingerprint"]).decode())
         fp.update(entries)
+        # None: the entry was not recorded
+        for k in [k for k, v in entries.items() if v is None]:
+            del fp[k]
         payload["fingerprint"] = np.frombuffer(
             json.dumps(fp, sort_keys=True).encode(), dtype=np.uint8)
         np.savez(path, **payload)
@@ -306,6 +310,15 @@ def test_resume_rejects_flipped_stencil_knobs(tmp_path):
             with pytest.raises(ValueError, match=path):
                 mgr.restore(build(), 1)
             record(matvec_form="gse", pallas="off", combine="gather")
+
+        # no band lengths recorded: the rectangular heavy residual
+        bands = [int(h) for h in build().pm.combine.bands]
+        assert bands
+        record(combine_bands=None)
+        with pytest.raises(ValueError, match="rectangular heavy-node"):
+            mgr.restore(build(), 1)
+        record(combine_bands=bands)
+        assert mgr.restore(build(), 1) == 1
 
         # flipped block layout: refused
         os.environ["PCG_TPU_HYBRID_BLOCK"] = "1000000"

@@ -312,13 +312,32 @@ def _np_add_rows(n_node, pairs):
     return y[:n_node]
 
 
-def test_combine_gather_matches_scatter(pair):
+def _pin_kd(model, pair, kd, monkeypatch):
+    """The pair's hybrid (ops, data, partition), rebuilt on the same
+    element partition with PCG_TPU_HYBRID_KD pinned to ``kd`` (None:
+    the fixture's own, the width picked from the histogram)."""
+    _, (ops_h, data_h), pm_g, hp = pair
+    if kd is None:
+        return ops_h, data_h, hp
+    monkeypatch.setenv("PCG_TPU_HYBRID_KD", str(kd))
+    P = pm_g.n_parts
+    hp = partition_hybrid(model, P,
+                          elem_part=make_elem_part(model, P, method="rcb"))
+    assert hp.combine.kd == kd
+    return HybridOps.from_hybrid(hp), device_data_hybrid(hp), hp
+
+
+@pytest.mark.parametrize("kd", [None, 1, 3], ids=["auto", "kd1", "kd3"])
+def test_combine_gather_matches_scatter(model, pair, kd, monkeypatch):
     """The gather combine against an independent numpy np.add.at sum of
     the same level and transition rows into their nodes, for matvec,
-    diag, node blocks and nodal averaging."""
+    diag, node blocks and nodal averaging, at the picked dense width
+    and at pinned ones."""
     from pcg_mpi_solver_tpu.parallel.hybrid import _CORNERS
 
-    _, (ops_h, data_h), pm_g, hp = pair
+    pm_g = pair[2]
+    ops_h, data_h, hp = _pin_kd(model, pair, kd, monkeypatch)
+    assert hp.combine.bands
     assert "combine" in data_h and ops_h._trans_in_combine(data_h)
     P, nn = hp.pm.n_parts, hp.pm.n_node_loc
     Ke = np.asarray(data_h["brick_Ke"])
@@ -392,48 +411,78 @@ def test_combine_gather_matches_scatter(pair):
                                atol=1e-11 * max(np.abs(avg_ref).max(), 1e-30))
 
 
-def test_combine_maps_cover_every_slot_once(pair):
-    """Every lattice slot that touches a ck != 0 cell of its block and
-    every real transition row appears in exactly one gidx/hgidx cell,
-    under the node it belongs to; every dropped slot of a real node
-    touches no such cell; pad cells all point at the zero row."""
+def _slot_targets(hp, p):
+    """Part p's lattice slots and transition rows as the combine sees
+    them, from the level grids and type blocks alone: (lattice node ids,
+    per-slot count of ck != 0 cells touched, every slot's target node,
+    live mask: lattice slots of real nodes that touch such a cell, and
+    real transition rows)."""
     from pcg_mpi_solver_tpu.parallel.hybrid import _CORNERS
 
+    nn = hp.pm.n_node_loc
+    touch = []
+    for lv in hp.levels:
+        ck = lv.ck[p] != 0                             # (nb, bx, by, bz)
+        nb, bx, by, bz = ck.shape
+        cnt = np.zeros((nb, bx + 1, by + 1, bz + 1), np.int64)
+        for dx, dy, dz in _CORNERS:
+            cnt[:, dx:dx + bx, dy:dy + by, dz:dz + bz] += ck
+        touch.append(cnt.reshape(-1))
+    touch = np.concatenate(touch)
+    lvl = np.concatenate([lv.nidx[p].reshape(-1) for lv in hp.levels])
+    trans = np.concatenate(
+        [tb.node[p].reshape(-1) for tb in hp.pm.type_blocks]
+        or [np.zeros(0, np.int64)])
+    tgt = np.concatenate([lvl, trans]).astype(np.int64)
+    live = np.concatenate([(lvl < nn) & (touch > 0), trans < nn])
+    return lvl, touch, tgt, live
+
+
+def test_combine_maps_cover_every_slot_once(pair):
+    """Every lattice slot that touches a ck != 0 cell of its block and
+    every real transition row appears in exactly one dense or heavy-band
+    cell, under the node it belongs to; every dropped slot of a real
+    node touches no such cell; pad cells all point at the zero row.  The
+    heavy list runs by descending slot count (node ids rising among
+    equal counts), band k is its prefix of nodes with more than KD + k
+    slots, band lengths never increase, and every hpos points into the
+    list or at the zero row after it."""
     _, (ops_h, data_h), pm_g, hp = pair
     cm = hp.combine
     nn = hp.pm.n_node_loc
     assert cm.n_slots == sum(lv.nidx[0].size for lv in hp.levels) + sum(
         tb.node.shape[1] * tb.node.shape[2] for tb in hp.pm.type_blocks)
+    bands = cm.bands
+    H = bands[0] if bands else 0
+    assert list(bands) == sorted(bands, reverse=True)
+    assert cm.hgidx.shape == (hp.pm.n_parts, sum(bands))
+    assert ops_h.combine_bands == bands
+    band_fill = np.zeros((hp.pm.n_parts, len(bands)), np.int64)
     n_dropped = 0
     for p in range(hp.pm.n_parts):
-        touch = []
-        for lv in hp.levels:
-            ck = lv.ck[p] != 0                         # (nb, bx, by, bz)
-            nb, bx, by, bz = ck.shape
-            cnt = np.zeros((nb, bx + 1, by + 1, bz + 1), np.int64)
-            for dx, dy, dz in _CORNERS:
-                cnt[:, dx:dx + bx, dy:dy + by, dz:dz + bz] += ck
-            touch.append(cnt.reshape(-1))
-        touch = np.concatenate(touch)
-        lvl = np.concatenate([lv.nidx[p].reshape(-1) for lv in hp.levels])
-        trans = np.concatenate(
-            [tb.node[p].reshape(-1) for tb in hp.pm.type_blocks]
-            or [np.zeros(0, np.int64)])
-        tgt = np.concatenate([lvl, trans]).astype(np.int64)
-        live = np.concatenate([(lvl < nn) & (touch > 0), trans < nn])
+        lvl, touch, tgt, live = _slot_targets(hp, p)
+        lens = np.bincount(tgt[live], minlength=nn)
         # a ck != 0 brick's corners are all real local nodes
         assert (lvl[touch > 0] < nn).all()
-        # the node each map cell adds into: gidx by its column, hgidx by
-        # the heavy row that hpos points that node at
-        H = cm.hgidx.shape[2]
-        heavy_node = np.full(H, nn)
+        # the heavy list, from hpos: exactly the nodes past KD
+        assert (cm.hpos[p] <= H).all()
         is_heavy = cm.hpos[p] < H
+        np.testing.assert_array_equal(is_heavy, lens > cm.kd)
+        assert (cm.hpos[p][~is_heavy] == H).all()
+        heavy_node = np.full(H, nn)
         heavy_node[cm.hpos[p][is_heavy]] = np.flatnonzero(is_heavy)
-        slot_node = np.concatenate([
-            np.broadcast_to(np.arange(nn), cm.gidx[p].shape).reshape(-1),
-            np.broadcast_to(heavy_node, cm.hgidx[p].shape).reshape(-1)])
-        used = np.concatenate([cm.gidx[p].reshape(-1),
-                               cm.hgidx[p].reshape(-1)])
+        n_heavy = int(is_heavy.sum())
+        assert (heavy_node[:n_heavy] < nn).all()
+        order = heavy_node[:n_heavy]
+        np.testing.assert_array_equal(
+            np.lexsort((order, -lens[order])), np.arange(n_heavy))
+        band_fill[p] = [(lens > cm.kd + k).sum() for k in range(len(bands))]
+        # the node each map cell adds into: gidx by its column, band k
+        # by the first bands[k] rows of the heavy list
+        slot_node = np.concatenate(
+            [np.broadcast_to(np.arange(nn), cm.gidx[p].shape).reshape(-1)]
+            + [heavy_node[:h] for h in bands])
+        used = np.concatenate([cm.gidx[p].reshape(-1), cm.hgidx[p]])
         real = used < cm.n_slots
         # exactly the live slots and real transition rows, each once
         np.testing.assert_array_equal(np.sort(used[real]),
@@ -447,10 +496,34 @@ def test_combine_maps_cover_every_slot_once(pair):
         dropped = (lvl < nn) & ~in_map[:len(lvl)]
         assert (touch[dropped] == 0).all()
         n_dropped += int(dropped.sum())
-        # heavy rows are real or the zero row after them
-        assert (cm.hpos[p] <= cm.hgidx.shape[2]).all()
+    # each band is as long as the part with the most such nodes needs
+    np.testing.assert_array_equal(band_fill.max(axis=0), bands)
     assert n_dropped == cm.n_dropped
     assert cm.n_dropped > 0
+
+
+def test_banded_rows_never_exceed_rectangular(pair, monkeypatch):
+    """At every dense width, the banded combine gathers no more rows
+    than a rectangular heavy residual padded to the heaviest node
+    (``KD*N + KE*H + N``), and the width it picks gathers strictly fewer
+    rows than the best rectangular layout on this partition."""
+    from pcg_mpi_solver_tpu.parallel.hybrid import build_combine_maps
+
+    hp = pair[3]
+    nn, P = hp.pm.n_node_loc, hp.pm.n_parts
+    lens = [np.bincount(tgt[live], minlength=nn)
+            for _, _, tgt, live in (_slot_targets(hp, p) for p in range(P))]
+    lmax = max(int(ln.max()) for ln in lens)
+    trans = [tb.node for tb in hp.pm.type_blocks]
+    rect = {}
+    for kd in range(1, lmax + 1):
+        H = max(int((ln > kd).sum()) for ln in lens)
+        rect[kd] = kd * nn + ((lmax - kd) * H + nn if H else 0)
+        monkeypatch.setenv("PCG_TPU_HYBRID_KD", str(kd))
+        cm = build_combine_maps(hp.levels, nn, P, trans)
+        assert cm.kd == kd
+        assert cm.rows_per_matvec <= rect[kd]
+    assert hp.combine.rows_per_matvec < min(rect.values())
 
 
 @pytest.mark.parametrize("nrhs", [0, 2], ids=["scalar", "nrhs2"])
@@ -476,6 +549,11 @@ def test_gather_combine_matvec_has_no_scatter(pair, nrhs):
     gauges = cm.gauges()
     assert gauges["hybrid.combine_rows_per_matvec"] == cm.rows_per_matvec
     assert gauges["hybrid.combine_kd"] == cm.gidx.shape[1]
+    assert gauges["hybrid.combine_heavy_nodes"] == cm.bands[0]
+    assert gauges["hybrid.combine_heavy_rows"] == sum(cm.bands) \
+        == cm.hgidx.shape[1]
+    assert cm.rows_per_matvec == (cm.kd * nn
+                                  + gauges["hybrid.combine_heavy_rows"] + nn)
 
 
 def test_auto_backend_prefers_hybrid(model, monkeypatch):
