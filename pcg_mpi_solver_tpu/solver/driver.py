@@ -88,6 +88,12 @@ class ManySolveResult:
     recoveries: int = 0
     drift: int = 0
 
+    # inner iterations the block ran in lockstep: per Krylov cycle the
+    # most any column ran, summed over the cycles (a converged column
+    # rides the rest of a cycle as a masked lane); sum(iters) /
+    # (nrhs * lockstep_iters) is the share of lanes that did live work
+    lockstep_iters: int = 0
+
     @property
     def nrhs(self) -> int:
         return int(len(self.flags))
@@ -1566,26 +1572,28 @@ class Solver:
 
         t0 = time.perf_counter()
         rdt = np.dtype(np.float64 if self.mixed else self.dtype)
-        fb = normalize_rhs_block(fexts, self._model.n_dof, rdt)
-        checks = check_rhs_block(fb, self._model.n_dof)
-        bad = [c for c in checks if c.status == "fail"]
-        if bad:
-            raise PreflightError(
-                "solve_many rejected the rhs block: " + "; ".join(
-                    f"[{c.name}] {c.detail}" for c in bad))
-        R = fb.shape[1]
-        self._rec.gauge("many.nrhs", R)
+        with self._rec.span("many.stage"):
+            fb = normalize_rhs_block(fexts, self._model.n_dof, rdt)
+            checks = check_rhs_block(fb, self._model.n_dof)
+            bad = [c for c in checks if c.status == "fail"]
+            if bad:
+                raise PreflightError(
+                    "solve_many rejected the rhs block: " + "; ".join(
+                        f"[{c.name}] {c.detail}" for c in bad))
+            R = fb.shape[1]
+            self._rec.gauge("many.nrhs", R)
 
-        # global columns -> part-local blocked (n_parts, n_loc, nrhs);
-        # shared interface dofs replicate their value on every part that
-        # carries them (the assembled-operator convention), padded local
-        # slots (dof_gid < 0) read 0
-        from pcg_mpi_solver_tpu.parallel.distributed import put_sharded
+            # global columns -> part-local blocked (n_parts, n_loc,
+            # nrhs); shared interface dofs replicate their value on
+            # every part that carries them (the assembled-operator
+            # convention), padded local slots (dof_gid < 0) read 0
+            from pcg_mpi_solver_tpu.parallel.distributed import put_sharded
 
-        gid = np.asarray(self.pm.dof_gid)
-        loc = fb[np.clip(gid, 0, None), :] * (gid >= 0)[..., None]
-        fb_dev = put_sharded(np.ascontiguousarray(loc, dtype=rdt),
-                             self.mesh, self._part_spec)
+            gid = np.asarray(self.pm.dof_gid)
+            loc = fb[np.clip(gid, 0, None), :] * (gid >= 0)[..., None]
+            fb_dev = put_sharded(np.ascontiguousarray(loc, dtype=rdt),
+                                 self.mesh, self._part_spec)
+            jax.block_until_ready(fb_dev)
 
         progs = self._ensure_many_programs(R)
         t_solve0 = time.perf_counter()      # staging done: Krylov wall
@@ -1602,19 +1610,19 @@ class Solver:
                     "mid-solve snapshots exist on this path")
 
             def _one_shot():
-                x, flags, relres, iters = progs["solve"](self.data,
-                                                         fb_dev)
+                x, flags, relres, iters, lock = progs["solve"](self.data,
+                                                               fb_dev)
                 # blocking fetches INSIDE the retry guard: a dispatch
                 # that dies mid-execution must count as a failed attempt
                 return (x, np.asarray(flags),
                         np.asarray(relres, dtype=np.float64),
-                        np.asarray(iters))
+                        np.asarray(iters), int(lock))
 
             # retry-guarded one-shot dispatch: the blocked program
             # donates nothing, so a device-loss failure re-dispatches
             # the identical stateless program instead of failing the
             # whole block request
-            x, flags, relres, iters = self._dispatch_with_retry(
+            x, flags, relres, iters, lockstep = self._dispatch_with_retry(
                 "solve_many", _one_shot)
             # one-shot quarantine semantics (recovery-exempt from the
             # ladder: a single stateless dispatch has no resumable carry
@@ -1648,7 +1656,7 @@ class Solver:
 
                 rhs_hash = array_hash(fb)
             (x, flags, relres, iters, quarantined, recoveries,
-             drift) = self._solve_many_chunked(
+             drift, lockstep) = self._solve_many_chunked(
                 fb_dev, R, progs, resume, rhs_hash=rhs_hash)
         wall = time.perf_counter() - t0
         res = ManySolveResult(flags=flags, relres=relres, iters=iters,
@@ -1656,7 +1664,11 @@ class Solver:
                               solve_wall_s=time.perf_counter() - t_solve0,
                               quarantined=tuple(quarantined),
                               recoveries=int(recoveries),
-                              drift=int(drift))
+                              drift=int(drift), lockstep_iters=lockstep)
+        self._rec.inc("many.lockstep_iters", lockstep)
+        if lockstep:
+            self._rec.gauge("many.live_lane_frac",
+                            float(np.sum(iters)) / (R * lockstep))
         self._rec.event("solve_many", nrhs=R, wall_s=round(wall, 6),
                         flags=[int(f) for f in flags],
                         iters_max=int(iters.max()) if R else 0,
@@ -1711,13 +1723,17 @@ class Solver:
     def displacement_global_many(self, x) -> np.ndarray:
         """Blocked device solution (n_parts, n_loc, nrhs) -> global host
         (n_dof, nrhs) array: ONE fetch of the whole block (one DCN
-        all-gather on multi-host) + one owner-masked scatter, via the
-        same :func:`gather_owned_global` every scalar global view uses
-        (it carries the trailing block axis natively)."""
-        from pcg_mpi_solver_tpu.parallel.distributed import gather_owned_global
+        all-gather on multi-host) + one owner-masked scatter, the scalar
+        :meth:`displacement_global`'s two steps and spans
+        (``fetch.transfer``, ``fetch.assemble``) with the trailing block
+        axis carried through."""
+        from pcg_mpi_solver_tpu.parallel.distributed import (
+            fetch_global, scatter_owned_global)
 
-        return gather_owned_global(self.pm, x, self.mesh,
-                                   np.dtype(self.dtype))
+        with self._rec.span("fetch.transfer"):
+            xh = fetch_global(x, self.mesh)
+        with self._rec.span("fetch.assemble"):
+            return scatter_owned_global(self.pm, xh, np.dtype(self.dtype))
 
     def _ensure_many_programs(self, R: int) -> dict:
         """Build (once per block width) the jitted blocked programs.
@@ -1765,7 +1781,7 @@ class Solver:
                 x0 = jnp.zeros_like(fext)
                 if mixed:
                     inv32 = self._make_prec(self.ops32, data["f32"])
-                    res = pcg_mixed_many(
+                    res, lockstep = pcg_mixed_many(
                         self.ops32, data["f32"], self.ops, d64, fext, x0,
                         inv32, tol=scfg.tol, max_iter=scfg.max_iter,
                         glob_n_dof_eff=glob_n_eff,
@@ -1784,11 +1800,14 @@ class Solver:
                         glob_n_dof_eff=glob_n_eff,
                         max_stag_steps=scfg.max_stag_steps,
                         x0_zero=True, variant=variant)
-                return res.x, res.flag, res.relres, res.iters
+                    # one Krylov cycle: the lockstep loop ran as long as
+                    # its longest column
+                    lockstep = jnp.max(res.iters)
+                return res.x, res.flag, res.relres, res.iters, lockstep
 
             shard = jax.shard_map(
                 _solve_blk, mesh=self.mesh, in_specs=(self._specs, P),
-                out_specs=(P, Rsp, Rsp, Rsp), check_vma=False)
+                out_specs=(P, Rsp, Rsp, Rsp, Rsp), check_vma=False)
             fn = jax.jit(shard)
             if self._cache_dir:
                 aot_fn = self._build_aot_many(shard, R)
@@ -1904,7 +1923,7 @@ class Solver:
             glob_n_dof_eff=int(self.pm.glob_n_dof_eff),
             donate=False,
             jax_version=jax.__version__,
-            extra={"many": True,
+            extra={"many": True, "lockstep": True,
                    "mg": self._mg_meta,
                    "x64": bool(jax.config.jax_enable_x64)})
         exported = aot.cached_step(
@@ -2029,7 +2048,7 @@ class Solver:
                 jax.block_until_ready(c2["flag"])
             return c2
 
-        (x_fin, carry, flags, _total, iters_cols, quarantined,
+        (x_fin, carry, flags, lockstep, iters_cols, quarantined,
          recoveries, drift_cols) = run_many_with_recovery(
             carry, scfg=scfg, nrhs=R, recorder=rec,
             hooks=ManyRecoveryHooks(cycle=_cycle, recover=_recover,
@@ -2045,7 +2064,7 @@ class Solver:
             # _make_many_resilience never returns None with one)
             ctx.discard()
         return (x_fin, flags, relres, iters_cols, quarantined,
-                recoveries, int(drift_cols.sum()))
+                recoveries, int(drift_cols.sum()), int(lockstep))
 
     def step(self, delta: float) -> StepResult:
         # recovery-exempt: the one-shot step DONATES the previous

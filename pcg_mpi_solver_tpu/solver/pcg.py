@@ -2229,6 +2229,19 @@ def pcg_many(
     return result
 
 
+def _pow2_scale(v):
+    """A power of two within a factor of two of each positive ``v``
+    (R,): ``v``'s float32 exponent with its mantissa cleared, by bit
+    operations on float32 only (the TPU has no 64-bit bitcast).  Where
+    ``v`` is not a normal float32, ``v`` itself."""
+    v32 = v.astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(v32, jnp.uint32) & jnp.uint32(
+        0x7F800000)
+    p = jax.lax.bitcast_convert_type(bits, jnp.float32).astype(v.dtype)
+    normal = (v32 >= np.finfo(np.float32).tiny) & jnp.isfinite(v32)
+    return jnp.where(normal, p, v)
+
+
 @scoped("solve/start")
 def pcg_mixed_many(
     ops32: Ops,
@@ -2249,13 +2262,19 @@ def pcg_mixed_many(
     progress_ratio: float = 0.7,
     progress_min_gain: float = 30.0,
     variant: str = "classic",
-) -> PCGResult:
+):
     """Blocked mixed-precision PCG by iterative refinement: the blocked
     twin of :func:`pcg_mixed`.  The f32 inner Krylov cycles run
     :func:`pcg_many` on the per-column normalized residuals (a finished
     column's inner rhs is zeroed, so its inner solve early-exits and
     costs nothing but a masked lane), and the f64 refresh is one blocked
-    matvec per cycle.  Per-column flags follow pcg_mixed's taxonomy."""
+    matvec per cycle.  Per-column flags follow pcg_mixed's taxonomy.
+
+    Returns ``(result, lockstep)``: the per-column :class:`PCGResult`
+    and the () int32 count of inner iterations the block ran in
+    lockstep — per refinement cycle the most any column ran, summed over
+    the cycles.  ``sum(result.iters) / (R * lockstep)`` is the share of
+    the block's lanes that did live work."""
     eff64 = data64["eff"]
     w64 = data64["weight"] * eff64
     R = fext.shape[-1]
@@ -2274,6 +2293,7 @@ def pcg_mixed_many(
         total=jnp.zeros((R,), jnp.int32),
         flag=jnp.where(n2b == 0, 0, -1).astype(jnp.int32),
         fatal2=jnp.zeros((R,), bool),
+        lockstep=jnp.zeros((), jnp.int32),
     )
 
     def cond(c):
@@ -2291,8 +2311,14 @@ def pcg_mixed_many(
 
         # normalized inner rhs per column; columns NOT running this
         # cycle get a zero rhs, which pcg_many's per-column zero-rhs
-        # early exit freezes at flag 0 / 0 iterations immediately
-        denom = jnp.where(normr > 0, normr, jnp.ones_like(normr))
+        # early exit freezes at flag 0 / 0 iterations immediately.  The
+        # scale is a power of two near the norm: dividing by it and
+        # multiplying the inner solution back are exact, so the update
+        # x + xbest * scale rounds once whether or not the backend fuses
+        # it into one FMA, which it may do at one block width and not at
+        # another — a column rounds the same at every width
+        denom = jnp.where(normr > 0, _pow2_scale(normr),
+                          jnp.ones_like(normr))
         rhat32 = jnp.where(run_inner[None, None, :],
                            r / denom[None, None, :], 0.0
                            ).astype(jnp.float32)
@@ -2323,7 +2349,7 @@ def pcg_mixed_many(
         use_min = (inner.flag != 0) & (icarry["normrmin"]
                                        < icarry["normr_act"])
         xbest = _colsel(use_min, icarry["xmin"], inner.x)
-        xinc = xbest.astype(fext.dtype) * normr[None, None, :]
+        xinc = xbest.astype(fext.dtype) * denom[None, None, :]
         xinc = jnp.where(run_inner[None, None, :], xinc,
                          jnp.zeros_like(xinc))
         exec_n = jnp.where(run_inner, jnp.maximum(icarry["exec"], 1), 0)
@@ -2340,11 +2366,13 @@ def pcg_mixed_many(
                     outer=c["outer"] + run_inner.astype(jnp.int32),
                     total=c["total"] + exec_n,
                     flag=flag,
-                    fatal2=inner_flag == 2)
+                    fatal2=inner_flag == 2,
+                    lockstep=c["lockstep"] + jnp.max(exec_n))
 
     c = jax.lax.while_loop(cond, body, carry0)
     zero_rhs = n2b == 0
     relres = jnp.where(zero_rhs, 0.0, c["normr"] / n2b)
     x = jnp.where(zero_rhs[None, None, :], jnp.zeros_like(c["x"]), c["x"])
-    return PCGResult(x=x, flag=c["flag"], relres=relres.astype(jnp.float32),
-                     iters=c["total"])
+    return (PCGResult(x=x, flag=c["flag"],
+                      relres=relres.astype(jnp.float32), iters=c["total"]),
+            c["lockstep"])

@@ -180,6 +180,128 @@ def test_structured_backend_block(model):
 
 
 # ----------------------------------------------------------------------
+# A design load set as one block: the six unit stress resultants of the
+# loaded x = L face and two combinations, on the heterogeneous 8^3 cube
+# ----------------------------------------------------------------------
+
+def _load_set(m):
+    """Eight load columns on the x = L face, nodal forces w * t(y, z)
+    with area-consistent weights w: N, Vy, Vz, T, My, Mz (linear over
+    the face's centroid and half-widths), C1 = N + 0.25 Vy - 0.15 Vz,
+    C2 = 0.5 My + 0.5 Mz + 0.3 T."""
+    nx, ny, nz, h = m.grid
+    xyz = np.asarray(m.node_coords)
+    face = np.flatnonzero(np.isclose(xyz[:, 0], nx * h))
+    y, z = xyz[face, 1], xyz[face, 2]
+    j, k = np.rint(y / h), np.rint(z / h)
+    w = (np.where((j == 0) | (j == ny), 0.5, 1.0)
+         * np.where((k == 0) | (k == nz), 0.5, 1.0) * h * h)
+    eta = (y - 0.5 * ny * h) / (0.5 * ny * h)
+    zeta = (z - 0.5 * nz * h) / (0.5 * nz * h)
+    one, zero = np.ones_like(y), np.zeros_like(y)
+    t = {"N": (one, zero, zero), "Vy": (zero, one, zero),
+         "Vz": (zero, zero, one), "T": (zero, -zeta, eta),
+         "My": (zeta, zero, zero), "Mz": (eta, zero, zero)}
+    f = {}
+    for name, comps in t.items():
+        F = np.zeros(m.n_dof)
+        for c in range(3):
+            F[3 * face + c] = 1e6 * w * comps[c]
+        f[name] = F
+    f["C1"] = f["N"] + 0.25 * f["Vy"] - 0.15 * f["Vz"]
+    f["C2"] = 0.5 * f["My"] + 0.5 * f["Mz"] + 0.3 * f["T"]
+    return np.stack(list(f.values()), axis=-1)
+
+
+@pytest.fixture(scope="module")
+def loadcase_solver():
+    m = make_cube_model(8, 8, 8, heterogeneous=True)
+    s = Solver(m, _cfg(mode="mixed", tol=1e-7), mesh=make_mesh(1),
+               n_parts=1)
+    assert s.backend == "structured"
+    return m, s, _load_set(m)
+
+
+def test_load_set_block_converges_against_the_f64_operator(loadcase_solver):
+    """Every column of the width-8 mixed block: flag 0 and an f64
+    residual within tol under the numpy reference operator."""
+    from pcg_mpi_solver_tpu.solver.numpy_ref import NumpyRefSolver
+
+    m, s, fb = loadcase_solver
+    blk = s.solve_many(fb)
+    assert list(blk.flags) == [0] * 8
+    u = s.displacement_global_many(blk.x)
+    K = NumpyRefSolver(m)
+    eff = np.asarray(m.dof_eff)
+    for j in range(8):
+        r = (fb[:, j] - K.matvec(u[:, j]))[eff]
+        assert np.linalg.norm(r) / np.linalg.norm(fb[eff, j]) <= 1e-7, j
+
+
+def test_load_set_columns_match_width1_bit_for_bit(loadcase_solver):
+    """Column j of the width-8 block is the width-1 solve of column j,
+    bit for bit (the lockstep merge never touches a column's
+    arithmetic), and the block reports its lockstep iterations."""
+    m, s, fb = loadcase_solver
+    blk = s.solve_many(fb)
+    xb = np.asarray(blk.x)
+    assert len(set(int(i) for i in blk.iters)) > 1, \
+        "the load set's columns should converge at different counts"
+    for j in range(8):
+        solo = s.solve_many(fb[:, j])
+        assert int(solo.flags[0]) == int(blk.flags[j])
+        assert int(solo.iters[0]) == int(blk.iters[j])
+        # a lone column's lockstep loop is its own iterations
+        assert solo.lockstep_iters == int(solo.iters[0])
+        np.testing.assert_array_equal(np.asarray(solo.x)[..., 0],
+                                      xb[..., j])
+    assert int(np.max(blk.iters)) <= blk.lockstep_iters \
+        <= int(np.sum(blk.iters))
+
+
+def test_lockstep_count_and_live_lanes_by_hand(loadcase_solver):
+    """Hand counts of the lockstep iterations and the live-lane share.
+
+    A block (F, 2F, 0): 2F repeats F's arithmetic scaled by an exact
+    power of two, so in every refinement cycle both run the same inner
+    iterations and the zero column none; lockstep = iters(F), live share
+    2/3.  A block of the eight load cases at a tolerance one refinement
+    cycle reaches (its inner tolerance is half the outer one):
+    lockstep = the most iterations any column ran."""
+    m, s, fb = loadcase_solver
+    F = fb[:, 6]
+    blk = s.solve_many(np.stack([F, 2.0 * F, np.zeros_like(F)], axis=-1))
+    n = int(blk.iters[0])
+    assert [int(i) for i in blk.iters] == [n, n, 0]
+    assert blk.lockstep_iters == n
+    assert s.recorder.gauges["many.live_lane_frac"] == pytest.approx(2 / 3)
+
+    one = Solver(m, _cfg(mode="mixed", tol=1e-4), mesh=make_mesh(1),
+                 n_parts=1)
+    blk = one.solve_many(fb)
+    iters = [int(i) for i in blk.iters]
+    assert list(blk.flags) == [0] * 8 and len(set(iters)) > 1
+    assert blk.lockstep_iters == max(iters)
+    assert one.recorder.counters["many.lockstep_iters"] == max(iters)
+    assert one.recorder.gauges["many.live_lane_frac"] == pytest.approx(
+        sum(iters) / (8 * max(iters)))
+
+
+def test_blocked_staging_and_fetch_are_spans(loadcase_solver):
+    """The block's host staging and its answer's fetch are recorder
+    spans, with the scalar fetch's names."""
+    m, s, fb = loadcase_solver
+    before = s.recorder.span_stats()
+    blk = s.solve_many(fb[:, :2])
+    s.displacement_global_many(blk.x)
+    after = s.recorder.span_stats()
+    for name in ("many.stage", "fetch.transfer", "fetch.assemble"):
+        n0 = before.get(name, {}).get("calls", 0)
+        assert after[name]["calls"] == n0 + 1, name
+    assert "many.lockstep_iters" in s.recorder.snapshot()["counters"]
+
+
+# ----------------------------------------------------------------------
 # Chunked dispatch: kill-and-resume, cross-nrhs rejection
 # ----------------------------------------------------------------------
 
